@@ -77,6 +77,23 @@ def new_scheme(name: str, **kwargs):
     return entry[1](**kwargs)
 
 
+# device factory -> the host scheme it is a keygen facade over
+_HOST_OF = {_bn254_jax: _bn254, _bls12_381_jax: _bls12_381}
+
+
+def new_keygen_scheme(name: str):
+    """The scheme an orchestrating PARENT uses for keygen and registry I/O.
+
+    Device schemes are facades over their host scheme (same keys, same wire
+    formats; only `constructor` differs), and building one initialises a
+    JAX backend — which takes the chip from the child process that is meant
+    to own it. The host scheme never imports jax."""
+    entry = _TABLE.get(name.lower())
+    if entry is None:
+        raise ValueError(f"unknown signature scheme: {name!r}")
+    return _HOST_OF.get(entry[1], entry[1])()
+
+
 def is_device_scheme(name: str) -> bool:
     """True when `name` selects a device-verification scheme (one whose
     constructor accepts batch_size and exposes a Device class)."""

@@ -2,8 +2,8 @@
 
 The production CIOS kernel (ops/fp.py) is a VPU workload: a 254-bit limb
 product is an outer product (contraction depth 1), so the 128x128 systolic
-array contributes nothing and the measured 16.7 T int8-ops/s MXU ceiling
-(results/fp_microbench.json "mxu_lab") sits idle through every pairing.
+array contributes nothing and the MXU (its int8 ceiling is not measured on
+this machine; scripts/mxu_limb_lab.py does) sits idle through every pairing.
 RNS restructures the same arithmetic so the heavy steps ARE deep matmul
 contractions against constant matrices — the shape the AI-ASIC ZKP
 literature targets (PAPERS.md, arxiv 2604.17808; ROADMAP item 1):
@@ -562,27 +562,42 @@ class RnsField(Field):
         while bsz % tile != 0:
             tile //= 2
         key = (bsz, tile)
-        fn = self._fused_fns.get(key)
-        if fn is None:
+        cached = self._fused_fns.get(key)
+        if cached is None:
+            # a Pallas kernel may not capture array constants: trace the
+            # body once, hoist its constants (moduli, reciprocals, the
+            # base-extension matrices) and hand them in as whole-array
+            # VMEM inputs
+            blk = jax.ShapeDtypeStruct((k, tile), jnp.int32)
+            closed = jax.make_jaxpr(self._mul_resident_core)(blk, blk)
+            consts = closed.consts
 
-            def kernel(a_ref, b_ref, o_ref):
-                o_ref[:] = self._mul_resident_core(a_ref[:], b_ref[:])
+            def kernel(a_ref, b_ref, *refs):
+                *c_refs, o_ref = refs
+                (out,) = jax.core.eval_jaxpr(
+                    closed.jaxpr, [c[...] for c in c_refs],
+                    a_ref[...], b_ref[...],
+                )
+                o_ref[...] = out
+
+            def tile_spec():
+                return pl.BlockSpec((k, tile), lambda i: (0, i),
+                                    memory_space=pltpu.VMEM)
 
             fn = pl.pallas_call(
                 kernel,
                 out_shape=jax.ShapeDtypeStruct((k, bsz), jnp.int32),
                 grid=(bsz // tile,),
-                in_specs=[
-                    pl.BlockSpec((k, tile), lambda i: (0, i),
-                                 memory_space=pltpu.VMEM),
-                    pl.BlockSpec((k, tile), lambda i: (0, i),
-                                 memory_space=pltpu.VMEM),
+                in_specs=[tile_spec(), tile_spec()] + [
+                    pl.BlockSpec(c.shape, lambda i, nd=c.ndim: (0,) * nd,
+                                 memory_space=pltpu.VMEM)
+                    for c in consts
                 ],
-                out_specs=pl.BlockSpec((k, tile), lambda i: (0, i),
-                                       memory_space=pltpu.VMEM),
+                out_specs=tile_spec(),
             )
-            self._fused_fns[key] = fn
-        return fn(ra, rb)
+            cached = self._fused_fns[key] = (fn, consts)
+        fn, consts = cached
+        return fn(ra, rb, *consts)
 
     def add_resident(self, ra, rb):
         """Residue-wise modular add; represented-value bound grows to

@@ -87,16 +87,21 @@ async def run_node_process(args) -> int:
         print(f"metrics: serving on http://{mserver.address}", flush=True)
 
     if is_device_scheme(cfg.scheme):
-        # select the JAX backend BEFORE the scheme module imports jax;
+        # select the JAX platform BEFORE the scheme module imports jax;
         # fake/host schemes never touch jax at all. mesh_devices > 1 on a
-        # chip-less host needs that many virtual CPU devices
-        from handel_tpu.utils.jaxenv import apply_platform_env
+        # chip-less host needs that many virtual CPU devices. This process
+        # is the chip's one owner (the platform parent stays off jax).
+        from handel_tpu.utils.jaxenv import (
+            apply_platform_env,
+            enable_compile_cache,
+        )
 
         apply_platform_env(
             force_host_device_count=(
                 cfg.mesh_devices if cfg.mesh_devices > 1 else None
             )
         )
+        enable_compile_cache()
     scheme = new_scheme(
         cfg.scheme,
         **(

@@ -45,12 +45,13 @@ import sys
 import tarfile
 import tempfile
 
-from handel_tpu.models.registry import is_device_scheme, new_scheme
+from handel_tpu.models.registry import is_device_scheme, new_keygen_scheme
 from handel_tpu.sim import keys as simkeys
 from handel_tpu.sim.allocator import new_allocator
 from handel_tpu.sim.config import HostSpec, SimConfig, dump_config
 from handel_tpu.sim.monitor import Monitor
 from handel_tpu.sim.sync import STATE_END, STATE_START, SyncMaster
+from handel_tpu.utils.jaxenv import check_one_chip_owner
 
 
 class HostConnector:
@@ -259,11 +260,9 @@ class RemotePlatform:
         cfg = self.cfg
         run = cfg.runs[run_index]
         hosts = cfg.hosts
-        if is_device_scheme(cfg.scheme):
-            from handel_tpu.utils.jaxenv import apply_platform_env
-
-            apply_platform_env()
-        scheme = new_scheme(cfg.scheme)
+        # keygen is host math: the orchestrator never initialises a JAX
+        # backend (on a shared host the chip belongs to a node process)
+        scheme = new_keygen_scheme(cfg.scheme)
 
         # allocation: logical nodes round-robin over hosts ("instances"),
         # then over each host's processes (allocator.go:52-86)
@@ -330,6 +329,13 @@ class RemotePlatform:
         active = sum(
             len(ids) for procs in by_host_proc.values() for ids in procs.values()
         )
+        if is_device_scheme(cfg.scheme):
+            # every node process builds the device scheme (RPC verifier
+            # clients included), so each one takes its host's chip
+            check_one_chip_owner(
+                max(map(len, by_host_proc.values()), default=0),
+                "remote platform (per host)",
+            )
         # both bind 0.0.0.0 (sim/sync.py, sim/monitor.py) so off-host nodes
         # can reach them at master_ip. Declared keys keep the CSV schema
         # stable when a degraded run records no samples (NaN + warning).

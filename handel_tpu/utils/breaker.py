@@ -16,7 +16,7 @@ class CircuitBreaker:
     """Device-health gate: closed → (N consecutive failures) → open →
     (cooldown elapses) → half-open probe → closed on success.
 
-    A dead accelerator (device lost, XLA runtime error, tunnel down) would
+    A dead accelerator (device lost, XLA runtime error) would
     otherwise fail EVERY batch after a full dispatch attempt; once the
     breaker opens, batches skip the device entirely and take the host
     fallback until one probe launch after the cooldown proves it back.

@@ -5,7 +5,7 @@ Mirror of bench.py's headline measurement for the `bls12-381-jax` scheme
 the SAME `bench.build_problem` candidate generator, parameterized with the
 BLS12-381 oracle and pure-Python host keygen (the native C++ path is
 BN254-only), a device-resident registry, one fused multi-pairing launch,
-p50 over trials. Persists results/bench_bls12.json. Registry is smaller
+p50 over trials. Persists chiprun_out/bench_bls12.json. Registry is smaller
 than the BN254 headline's (pure-Python keygen cost; launch cost is
 registry-size independent on the range path).
 
@@ -83,11 +83,12 @@ def main() -> int:
     print(json.dumps(out))
     path = os.path.normpath(
         os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), "..", "results",
+            os.path.dirname(os.path.abspath(__file__)), "..", "chiprun_out",
             "bench_bls12.json",
         )
     )
     if out["backend"] != "cpu":
+        os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "w") as f:
             json.dump(out, f, indent=1)
             f.write("\n")

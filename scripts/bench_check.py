@@ -1,24 +1,21 @@
 """Bench-regression gate: compare the fresh bench artifact to its history.
 
 The driver persists one BENCH_r<NN>.json per round (repo root) and bench.py
-keeps the latest accelerator capture in results/bench_tpu.json — but until
+keeps the latest chip capture in chiprun_out/bench_device.json — but until
 now nobody READ them, so a regression like PR 1's 22.5 -> 6.3 ms pack win
 could silently un-happen. This script loads the whole history, compares the
-fresh artifact like-for-like — same metric AND same backend, so a
-TPU-persisted p50 is never judged against a CPU-fallback smoke — and exits
-nonzero with a named report when any metric degrades more than
+fresh artifact like-for-like — same metric AND same backend, so a chip p50
+is never judged against a CPU number — and exits nonzero with a named report when any metric degrades more than
 `--threshold` (default 20%) against the trailing median.
 
 Usage:
     python scripts/bench_check.py                 # gate (exit 1 on regression)
     python scripts/bench_check.py --dry-run       # CI self-test: report only
     python scripts/bench_check.py --history 'BENCH_*.json' \
-        --fresh results/bench_tpu.json --threshold 0.2 --min-history 2
+        --fresh chiprun_out/bench_device.json --threshold 0.2 --min-history 2
 
 History records come in two shapes, both accepted: the driver wrapper
 ({"n": .., "parsed": {<line>}}) and a raw bench line / persisted artifact.
-Persisted re-emits (source == "persisted") are deduped by captured_at so an
-outage round doesn't multiply one capture into fake history weight.
 """
 
 from __future__ import annotations
@@ -188,9 +185,8 @@ def direction(metric: str) -> str:
 
 
 def load_history(pattern: str) -> list[dict]:
-    """Chronologically ordered, deduped history records."""
+    """Chronologically ordered history records."""
     recs: list[dict] = []
-    seen_capture: set[str] = set()
     for path in sorted(glob.glob(pattern)):
         try:
             with open(path) as f:
@@ -199,13 +195,6 @@ def load_history(pattern: str) -> list[dict]:
             continue
         if rec is None:
             continue
-        cap = rec.get("captured_at")
-        if rec.get("source") == "persisted" and cap:
-            if cap in seen_capture:
-                continue  # same capture re-emitted across outage rounds
-            seen_capture.add(cap)
-        elif cap:
-            seen_capture.add(cap)
         recs.append(rec)
     return recs
 
@@ -280,7 +269,8 @@ def main(argv=None) -> int:
         help="glob of historical bench records (driver wrapper or raw line)",
     )
     ap.add_argument(
-        "--fresh", default=os.path.join(REPO, "results", "bench_tpu.json"),
+        "--fresh",
+        default=os.path.join(REPO, "chiprun_out", "bench_device.json"),
         help="the artifact under judgment",
     )
     ap.add_argument("--threshold", type=float, default=0.20,

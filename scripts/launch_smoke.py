@@ -31,7 +31,10 @@ sys.path.insert(0, REPO)
 # 8 virtual host devices — must land before jax initializes, so the
 # devices-in-{1,8} parametrization below runs on a real multi-device
 # topology (the same one conftest/multichip_smoke force)
-from handel_tpu.utils.jaxenv import apply_platform_env  # noqa: E402
+from handel_tpu.utils.jaxenv import (  # noqa: E402
+    apply_platform_env,
+    enable_compile_cache,
+)
 
 os.environ.setdefault("HANDEL_TPU_PLATFORM", "cpu")
 apply_platform_env(force_host_device_count=8)
@@ -65,10 +68,7 @@ def host_agg(pks, bs):
 def main() -> int:
     # share the persistent compile cache CI restores across runs (same dir
     # as bench.py / the slow tier): warm pushes skip the XLA compiles
-    jax.config.update(
-        "jax_compilation_cache_dir", "/tmp/handel_tpu_jax_cache"
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    enable_compile_cache()
     rng = random.Random(99)
     sks = [rng.randrange(1, 1 << 20) for _ in range(N)]
     pks = [BN254PublicKey(p) for p in nat.g2_mul_batch([bn.G2_GEN] * N, sks)]

@@ -51,7 +51,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 # 8 virtual host devices — must land before jax initializes its backends
-from handel_tpu.utils.jaxenv import apply_platform_env  # noqa: E402
+from handel_tpu.utils.jaxenv import (  # noqa: E402
+    apply_platform_env,
+    enable_compile_cache,
+)
 
 os.environ.setdefault("HANDEL_TPU_PLATFORM", "cpu")
 apply_platform_env(force_host_device_count=8)
@@ -79,10 +82,7 @@ def kernel_fleet_smoke() -> None:
     keys vs the host oracle, every device dispatched."""
     from handel_tpu.parallel.plane import bn254_plane
 
-    jax.config.update(
-        "jax_compilation_cache_dir", "/tmp/handel_tpu_jax_cache"
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    enable_compile_cache()
     rng = random.Random(99)
     sks = [rng.randrange(1, 1 << 20) for _ in range(N)]
     pks = [BN254PublicKey(p) for p in nat.g2_mul_batch([bn.G2_GEN] * N, sks)]

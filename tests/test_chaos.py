@@ -386,11 +386,21 @@ def test_localhost_platform_adversarial_chaos_run(tmp_path):
     from handel_tpu.sim.config import AdversaryParams, RunConfig, SimConfig
     from handel_tpu.sim.platform import run_simulation
 
+    # The chaos RNG of a link is seeded by (seed, source node, destination
+    # ADDRESS), so with probed ports the fault pattern changed every run,
+    # and a 5-of-8 round is only ~47 packets: at drop_rate 0.05 about one
+    # run in eleven dropped nothing and failed the counter assertion below.
+    # Fixed ports make the pattern a function of the seed, and seed 2199 is
+    # one whose FIRST draw on three honest level-1 links (0->1, 2->3, 4->5
+    # side) is a drop: every node's first packet goes to its level-1 peer,
+    # so at least one packet is dropped however fast the round finishes.
+    base = 13600  # beside test_sim's 13500 block, below the probed range
     cfg = SimConfig(
         network="udp",
         scheme="fake",
+        base_port=base,
         max_timeout_s=120.0,  # generous: CI cores are shared and slow
-        chaos=ChaosConfig(drop_rate=0.05, seed=11),
+        chaos=ChaosConfig(drop_rate=0.05, seed=2199),
         runs=[
             RunConfig(
                 nodes=8,
@@ -400,6 +410,13 @@ def test_localhost_platform_adversarial_chaos_run(tmp_path):
             )
         ],
     )
+    first_draw_drops = sum(
+        random.Random(
+            f"{cfg.chaos.for_node(src).seed}|127.0.0.1:{base + (src ^ 1)}"
+        ).random() < cfg.chaos.drop_rate
+        for src in range(6)
+    )
+    assert first_draw_drops == 3  # the seed still means what it says above
     results = asyncio.run(run_simulation(cfg, str(tmp_path)))
     res = results[0]
     if not res.ok:

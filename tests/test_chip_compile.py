@@ -1,0 +1,194 @@
+"""The served path's kernels, compiled for a described TPU v5e — no chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (`topologies.get_topology_desc`). These cases hand
+it the kernels of the verify path at the widths the full-width program
+really contains (4096-key bank, 128 lanes) and check the Mosaic call is in
+the result: what the chip's compiler refuses fails here, at no chip time.
+
+Under JAX_PLATFORMS=cpu the code would take its CPU branches, so the
+`chip_choices` fixture forces what the chip picks (Pallas on, pow window 4)
+by steering `ops/fp.device_platform` — in the test, not through an option.
+
+Rules of this file (the libtpu lock is per process): the topology is
+described inside a module-scoped fixture that skips when it cannot be —
+never at import, in a `skipif` or in `parametrize` — everything compiles in
+the test's own process, and all such tests live in this one file.
+"""
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from handel_tpu.ops import bls12_381_ref as bls
+from handel_tpu.ops import bn254_ref as bn
+from handel_tpu.ops import fp
+
+N_KEYS = 4096
+LANES = 128
+U32, I32, BOOL = jnp.uint32, jnp.int32, jnp.bool_
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    """ShapeDtypeStruct factory placed on the described chip 0."""
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+
+
+@pytest.fixture(scope="module")
+def chip_choices():
+    """What the code picks on the chip: Pallas kernels, pow window 4."""
+    mp = pytest.MonkeyPatch()
+    mp.setattr(fp, "device_platform", lambda: "tpu")
+    yield
+    mp.undo()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one (every rerun would warn and
+    recompile): switch the cache off around this file."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def mosaic_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# lane widths of stacked Field.mul calls read out of the lowered full-width
+# launches: 128 = one per-lane mul, 6912 = the Fp12 mul stacked 54x (the
+# most frequent width in both launches), 13824 = the widest mul of the
+# range launch, 4718592 = the dense launch's widest (4096 keys x 128 lanes
+# x 9 stacked muls of a G2 add)
+@pytest.mark.parametrize("width", [128, 6912, 13824, 4718592])
+def test_cios_mul_bn254(shape, chip_choices, width):
+    F = fp.Field(bn.P)
+    assert F.use_pallas and F.nlimbs == 16
+    x = shape((F.nlimbs, width), U32)
+    compiled = jax.jit(F.mul).lower(x, x).compile()
+    assert mosaic_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("width", [128, 6912])
+def test_cios_mul_bls12_381(shape, chip_choices, width):
+    F = fp.Field(bls.P)
+    assert F.use_pallas and F.nlimbs == 24
+    x = shape((F.nlimbs, width), U32)
+    compiled = jax.jit(F.mul).lower(x, x).compile()
+    assert mosaic_calls(compiled) == 1
+
+
+def test_rns_resident_mul(shape, chip_choices):
+    """The fused resident kernel behind fp_backend="rns" (ops/rns.py): the
+    residue product, both base extensions and the reductions in one body."""
+    F = fp.Field(bn.P, backend="rns")
+    assert F.fused_resident and F.int8_dots
+    r = shape((F.k_all, 6912), I32)
+    compiled = jax.jit(F.mul_resident).lower(r, r).compile()
+    assert mosaic_calls(compiled) == 1
+
+
+def _device(n_keys: int):
+    from handel_tpu.models.bn254 import BN254PublicKey
+    from handel_tpu.models.bn254_jax import BN254Device
+
+    dev = BN254Device([BN254PublicKey(bn.G2_GEN)] * n_keys, batch_size=LANES)
+    assert dev.curves.F.use_pallas and fp.default_pow_window() == 4
+    return dev
+
+
+def _bank(shape, n_keys: int):
+    """Shapes of a registry bank and its prefix table (jit arguments)."""
+    f2 = lambda n: (shape((16, n), U32), shape((16, n), U32))
+    prefix = (f2(n_keys + 1), f2(n_keys + 1), shape((n_keys + 1,), BOOL))
+    return prefix, f2(n_keys), f2(n_keys)
+
+
+def _range_args(shape, miss_k: int):
+    return (
+        shape((LANES,), I32),
+        shape((LANES,), I32),
+        shape((miss_k * LANES,), I32),
+        shape((miss_k * LANES,), BOOL),
+    )
+
+
+def test_range_aggregate(shape, chip_choices):
+    """The aggregation stage of the range launch at full width: prefix-table
+    gathers plus the 8-wide hole patch (point adds only, no pairing). The
+    bank is a jit argument, so a 2-key engine lowers the 4096-key program."""
+    dev = _device(2)
+    fn = jax.jit(partial(dev._range_aggregate, miss_k=8))
+    compiled = fn.lower(*_range_args(shape, 8), *_bank(shape, N_KEYS)).compile()
+    assert mosaic_calls(compiled) > 0
+
+
+def _report(name, compiled):
+    ma = compiled.memory_analysis()
+    print(
+        f"{name}: mosaic_calls={mosaic_calls(compiled)} "
+        f"temp_bytes={ma.temp_size_in_bytes} "
+        f"code_bytes={ma.generated_code_size_in_bytes}"
+    )
+
+
+# The two full pairing launches are minutes each (range ~4.5 min, dense
+# ~5.5 min on this sandbox): run by hand before a chip call,
+#   pytest tests/test_chip_compile.py -m slow -s
+@pytest.mark.slow
+def test_full_range_launch(shape, chip_choices):
+    dev = _device(2)
+    sig = shape((16, LANES), U32)
+    h = shape((16, 1), U32)
+    fn = jax.jit(
+        partial(dev._verify_batch_range, miss_k=8),
+        donate_argnums=(0, 1, 2, 3, 4, 5, 8),
+    )
+    compiled = fn.lower(
+        *_range_args(shape, 8), sig, sig, h, h, shape((LANES,), BOOL),
+        *_bank(shape, N_KEYS),
+    ).compile()
+    _report("range launch", compiled)
+    assert mosaic_calls(compiled) > 100
+
+
+@pytest.mark.slow
+def test_full_dense_launch(shape, chip_choices):
+    dev = _device(N_KEYS)  # the dense launch reads self.n
+    _, reg_x, reg_y = _bank(shape, N_KEYS)
+    sig = shape((16, LANES), U32)
+    h = shape((16, 1), U32)
+    fn = jax.jit(dev._verify_batch, donate_argnums=(2, 3, 4, 7))
+    compiled = fn.lower(
+        reg_x, reg_y, shape((LANES, 2 * (N_KEYS // 64)), U32), sig, sig, h, h,
+        shape((LANES,), BOOL),
+    ).compile()
+    _report("dense launch", compiled)
+    assert mosaic_calls(compiled) > 100
