@@ -26,6 +26,13 @@ chain — cross-process causality is recorded, not guessed. Multi-host runs
 additionally carry a per-process `clock_offset` (estimated over the sync
 barrier handshake, sim/sync.py) in the export; `merge_traces` applies it so
 node timelines align within the handshake's RTT bound.
+
+Launch stages (ISSUE 26): `StageClock` is the one clock a device engine
+times the host stages of its launches with. Each stage adds its wall time to
+a counter (always), becomes a `launch/<stage>` span when a recorder is
+listening, and lies as a `handel/<stage>` annotation on the host plane of
+any JAX profiler session, on the profiler's clock beside the device lines —
+all three carrying the launch's `seq`.
 """
 
 from __future__ import annotations
@@ -33,6 +40,7 @@ from __future__ import annotations
 import json
 import math
 import time
+from contextlib import contextmanager
 from typing import Iterable, Mapping
 
 #: epoch-seconds trace clock shared by every process on a host
@@ -211,6 +219,78 @@ class FlightRecorder:
     def gauge_keys(self) -> set[str]:
         """Explicit gauge declaration (core/metrics.py is_gauge_key)."""
         return {"traceSpanRate"}
+
+
+#: the host stages of one device launch, in the order they run: the first
+#: four build and enqueue it (dispatch side), the last two pull its verdicts
+LAUNCH_STAGES = (
+    "fence_wait", "pack", "stage", "enqueue", "fetch_wait", "fetch_copy",
+)
+
+
+class StageClock:
+    """Per-engine clock over the named host stages of its launches.
+
+    `with clock.stage(name, seq):` around a stage of launch `seq`
+      - adds the stage's wall ms to `ms[name]` (with `cpu=True` also the
+        calling thread's CPU ms to `cpu_ms[name]`: wall minus CPU is time
+        the stage's thread did not run — blocked, or waiting for the
+        interpreter lock);
+      - emits `rec.span("launch/<name>", ..., args={"seq", "lane"})` on the
+        lane's trace thread when a recorder was bound and is enabled;
+      - wraps the stage in `jax.profiler.TraceAnnotation("handel/<name>",
+        seq=, lane=)`: under a profiler session the stage lies on the host
+        plane of the same trace as the device lines, on the profiler's
+        clock. No session: an inactive TraceMe, a flag check.
+
+    Stages are synchronous code in one thread (an executor thread, for the
+    service); never wrap anything that awaits — the annotation would nest
+    wrongly. All cost is per launch: a few clock reads, nothing per
+    candidate; with no recorder no args dict is built and no recorder
+    method is called. jax is imported here, at construction, so that
+    processes that never build a device engine never load it.
+    """
+
+    __slots__ = ("ms", "cpu_ms", "rec", "lane", "tid", "_annotation")
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self.ms = dict.fromkeys(LAUNCH_STAGES, 0.0)
+        self.cpu_ms = dict.fromkeys(LAUNCH_STAGES, 0.0)
+        self.rec = None
+        self.lane = 0
+        self.tid = 0
+
+    def bind(self, recorder, lane: int, tid: int) -> None:
+        """The service's hand-over: which recorder (None: none) and which
+        lane and trace thread this engine's launches belong to."""
+        self.rec, self.lane, self.tid = recorder, lane, tid
+
+    def reset(self) -> None:
+        for k in self.ms:
+            self.ms[k] = self.cpu_ms[k] = 0.0
+
+    @contextmanager
+    def stage(self, name: str, seq: int | None, cpu: bool = False):
+        rec = self.rec
+        w0 = trace_now() if rec is not None and rec.enabled else 0.0
+        with self._annotation(f"handel/{name}", seq=seq, lane=self.lane):
+            c0 = time.thread_time() if cpu else 0.0
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                self.ms[name] += dt * 1e3
+                if cpu:
+                    self.cpu_ms[name] += (time.thread_time() - c0) * 1e3
+        if w0:
+            rec.span(
+                f"launch/{name}", w0, w0 + dt, tid=self.tid, cat="device",
+                args={"seq": seq, "lane": self.lane},
+            )
 
 
 class LogHistogram:

@@ -35,6 +35,7 @@ from jax._src.core import trace_state_clean
 
 from handel_tpu.core.bitset import BitSet
 from handel_tpu.core.logging import DEFAULT_LOGGER
+from handel_tpu.core.trace import StageClock
 from handel_tpu.models import rlc
 from handel_tpu.models.bn254 import (
     BN254Constructor,
@@ -65,6 +66,19 @@ LaunchPlan = namedtuple(
     "LaunchPlan",
     "kind miss_k lo hi miss_idx miss_ok words mask sig_x sig_y valid",
 )
+
+
+def _named(fn, name: str):
+    """`fn` under a stable `__name__`. jit names the compiled program after
+    the function it was given ("jit_<name>" on the profiler's "XLA Modules"
+    line); a `partial` or a bound method has none of its own to set, and
+    reads as `jit__unknown`."""
+
+    def call(*args):
+        return fn(*args)
+
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 class _StagingSet:
@@ -256,7 +270,7 @@ class BN254Device:
         # would let XLA scribble over our staging memory.
         donate = device_platform() != "cpu"
         self._kernel = jax.jit(
-            self._verify_batch,
+            _named(self._verify_batch, "verify_dense"),
             donate_argnums=(2, 3, 4, 7) if donate else (),
         )
         self._donate = donate
@@ -279,14 +293,21 @@ class BN254Device:
             for _ in range(self.stage_sets)
         ]
         self._stage_idx = 0
-        # host-cost counters (bench.py host_pack_ms/host_dispatch_ms;
-        # monitor plane via BatchVerifierService.values): pack = building
-        # the launch plan in staging, dispatch = the device handoff + async
-        # kernel enqueue that follows it
-        self.host_pack_ms = 0.0
+        # host cost per launch, by stage (core/trace.py StageClock; monitor
+        # plane via BatchVerifierService.values): fence_wait + pack build
+        # the launch plan in staging (`host_pack_ms`), stage + enqueue are
+        # the device handoff and the async kernel call that follow
+        # (`host_dispatch_ms`), fetch_wait + fetch_copy pull the verdicts.
+        # `_launch` and `_pull` are the only places a stage is timed.
+        self.stage_clock = StageClock()
         self.host_pack_launches = 0
-        self.host_dispatch_ms = 0.0
         self.host_dispatch_launches = 0
+        self.host_fetch_launches = 0
+        # launches are numbered per engine, from 0, in dispatch order; the
+        # handle carries the number to `fetch`, and the service reads it
+        # through `launch_seq`, so a launch's stages, spans and (dispatch
+        # order = execution order) its run on the device are one chain
+        self._next_seq = 0
         # epoch-based registry rotation (lifecycle/epoch.py): a second
         # device-resident bank is staged via `stage_registry` while this
         # one keeps serving; `activate_staged` is the pointer flip between
@@ -295,6 +316,16 @@ class BN254Device:
         self._staged: dict | None = None
         self.registry_stagings = 0
         self.registry_staged_ms = 0.0
+
+    @property
+    def host_pack_ms(self) -> float:
+        ms = self.stage_clock.ms
+        return ms["fence_wait"] + ms["pack"]
+
+    @property
+    def host_dispatch_ms(self) -> float:
+        ms = self.stage_clock.ms
+        return ms["stage"] + ms["enqueue"]
 
     @property
     def _prefix(self):
@@ -314,12 +345,12 @@ class BN254Device:
         g2 = self.curves.g2
 
         @jax.jit  # one executable for the whole scan + batch affine convert
-        def build(reg_x, reg_y):
+        def prefix_table(reg_x, reg_y):
             P = g2.from_affine(reg_x, reg_y)
             pref = g2.prefix_scan(P)  # inclusive prefix sums, projective
             return g2.to_affine(pref)
 
-        x, y, inf = build(
+        x, y, inf = prefix_table(
             self._reg_x if reg_x is None else reg_x,
             self._reg_y if reg_y is None else reg_y,
         )
@@ -411,8 +442,9 @@ class BN254Device:
         g2 = self.curves.g2
         T = self.curves.T
         F = self.curves.F
-        agg_inf = g2.is_infinity(agg)
-        qx, qy, _ = g2.to_affine(agg)
+        with jax.named_scope("to_affine"):
+            agg_inf = g2.is_infinity(agg)
+            qx, qy, _ = g2.to_affine(agg)
 
         b2 = (
             T.f2_pack([self.ref.G2_GEN[0]] * 1),
@@ -462,17 +494,22 @@ class BN254Device:
         valid (C,) bool. Returns (C,) verdicts. The fallback for arbitrary
         signer sets — contiguous-range candidates take `_verify_batch_range`.
         """
+        agg = self._dense_aggregate(reg_x, reg_y, words32, valid)
+        return self._pairing_tail(agg, sig_x, sig_y, h_x, h_y, valid)
+
+    @jax.named_scope("agg")
+    def _dense_aggregate(self, reg_x, reg_y, words32, valid):
+        """Per-candidate aggregate key (projective, batch C) of arbitrary
+        signer sets: the registry tiled block-major across candidates,
+        masked by the bitset words, tree-summed."""
         C = self.batch_size
         g2 = self.curves.g2
         mask = self._unpack_words(words32, valid)
-
-        # registry tiled block-major across candidates, masked, tree-summed
         tile = lambda a: jnp.repeat(a, C, axis=1)  # (L, N) -> (L, N*C)
         P2 = g2.from_affine(
             (tile(reg_x[0]), tile(reg_x[1])), (tile(reg_y[0]), tile(reg_y[1]))
         )
-        agg = g2.masked_sum(P2, mask, self.n)  # projective, batch C
-        return self._pairing_tail(agg, sig_x, sig_y, h_x, h_y, valid)
+        return g2.masked_sum(P2, mask, self.n)
 
     def _gather_prefix(self, prefix, idx):
         """(C,) int32 -> projective G2 batch from the prefix table."""
@@ -482,6 +519,7 @@ class BN254Device:
         P = g2.from_affine((take(x0), take(x1)), (take(y0), take(y1)))
         return g2.select(jnp.take(inf, idx), g2.infinity(idx.shape[0]), P)
 
+    @jax.named_scope("agg")
     def _range_aggregate(
         self, lo, hi, miss_idx, miss_ok, prefix, reg_x, reg_y, miss_k
     ):
@@ -542,7 +580,10 @@ class BN254Device:
         fn = self._range_agg_kernels.get(miss_k)
         if fn is None:
             jitted = jax.jit(
-                partial(self._range_aggregate, miss_k=miss_k),
+                _named(
+                    partial(self._range_aggregate, miss_k=miss_k),
+                    f"range_agg{miss_k}",
+                ),
                 # donate only the per-launch staging inputs; the bank args
                 # (4, 5, 6) are device residents and must survive launches
                 donate_argnums=(0, 1, 2, 3) if self._donate else (),
@@ -592,7 +633,10 @@ class BN254Device:
             # the cached H(m) and the bank args (9, 10, 11) are the
             # device-resident prefix/registry — all must survive launches
             jitted = jax.jit(
-                partial(self._verify_batch_range, miss_k=miss_k),
+                _named(
+                    partial(self._verify_batch_range, miss_k=miss_k),
+                    f"verify_range{miss_k}",
+                ),
                 donate_argnums=(0, 1, 2, 3, 4, 5, 8) if self._donate else (),
             )
 
@@ -660,14 +704,7 @@ class BN254Device:
     def _rlc_msm_dense(
         self, words32, sig_x, sig_y, r_bits, group_oh, valid, reg_x, reg_y
     ):
-        C = self.batch_size
-        g2 = self.curves.g2
-        mask = self._unpack_words(words32, valid)
-        tile = lambda a: jnp.repeat(a, C, axis=1)
-        P2 = g2.from_affine(
-            (tile(reg_x[0]), tile(reg_x[1])), (tile(reg_y[0]), tile(reg_y[1]))
-        )
-        agg = g2.masked_sum(P2, mask, self.n)
+        agg = self._dense_aggregate(reg_x, reg_y, words32, valid)
         return self._rlc_msm_tail(agg, sig_x, sig_y, r_bits, group_oh, valid)
 
     def _rlc_check(self, sx, sy, s_inf, xx, xy, x_inf, h_gx, h_gy, g_occ):
@@ -705,7 +742,10 @@ class BN254Device:
             if kind == "range":
                 _ = self._prefix
                 jitted = jax.jit(
-                    partial(self._rlc_msm_range, miss_k=miss_k),
+                    _named(
+                        partial(self._rlc_msm_range, miss_k=miss_k),
+                        f"rlc_msm_range{miss_k}",
+                    ),
                     # per-launch staging + scalar operands donate; the bank
                     # args (9, 10, 11) are device residents
                     donate_argnums=tuple(range(9)) if self._donate else (),
@@ -723,7 +763,7 @@ class BN254Device:
 
             else:
                 jitted = jax.jit(
-                    self._rlc_msm_dense,
+                    _named(self._rlc_msm_dense, "rlc_msm_dense"),
                     donate_argnums=tuple(range(6)) if self._donate else (),
                 )
 
@@ -742,7 +782,7 @@ class BN254Device:
     def _rlc_check_kernel(self, G: int):
         fn = self._rlc_check_kernels.get(G)
         if fn is None:
-            fn = jax.jit(self._rlc_check)
+            fn = jax.jit(_named(self._rlc_check, f"rlc_check{G}"))
             self._rlc_check_kernels[G] = fn
         return fn
 
@@ -751,11 +791,9 @@ class BN254Device:
         ((msg, bitset, sig) triples, pre-screened valid): fresh 64-bit
         scalars, message-grouped G2 MSM (n_groups quantized to the next
         power of two), (G+1)-lane pairing tail. Returns the (1,) device
-        verdict — async like every dispatch; staging reuse and fencing
-        follow the ordinary launch contract."""
-        t0 = time.perf_counter()
+        verdict and the launch's seq — async like every dispatch; staging
+        reuse and fencing follow the ordinary launch contract (`_launch`)."""
         C = self.batch_size
-        plan = self._pack_requests([(items[j][1], items[j][2]) for j in sub])
         msgs = [items[j][0] for j in sub]
         uniq: dict[bytes, int] = {}
         gid = [uniq.setdefault(m, len(uniq)) for m in msgs]
@@ -763,45 +801,41 @@ class BN254Device:
         G = 1
         while G < M:
             G *= 2
-        rs = rlc.draw_scalars(len(sub), self._rlc_rng)
-        r_bits = np.zeros((rlc.SCALAR_BITS, C), np.uint32)
-        r_bits[:, : len(sub)] = np.asarray(self.curves.scalar_bits64(rs))
-        group_oh = np.zeros((G, C), bool)
-        group_oh[gid, np.arange(len(sub))] = True
-        g_occ = np.arange(G) < M
-        # per-group H(m) columns; padded groups repeat the last real column
-        # (masked out by g_occ, any finite h keeps the math well-defined)
-        order = [None] * M
-        for m, g in uniq.items():
-            order[g] = m
-        cols = [self._h_cols(m) for m in order]
-        hx = np.concatenate([c[0] for c in cols] + [cols[-1][0]] * (G - M), axis=1)
-        hy = np.concatenate([c[1] for c in cols] + [cols[-1][1]] * (G - M), axis=1)
-        t1 = time.perf_counter()
-        self.host_pack_ms += (t1 - t0) * 1000.0
-        self.host_pack_launches += 1
-        dp = self._dput
-        staged = self._stage_plan(plan)
-        if plan.kind == "range":
-            lo, hi, mi, mo, sig_x, sig_y, valid = staged
-            outs = self._rlc_msm_kernel("range", plan.miss_k, G)(
-                lo, hi, mi, mo, sig_x, sig_y,
-                dp(r_bits), dp(group_oh), valid,
+
+        def operands():
+            rs = rlc.draw_scalars(len(sub), self._rlc_rng)
+            r_bits = np.zeros((rlc.SCALAR_BITS, C), np.uint32)
+            r_bits[:, : len(sub)] = np.asarray(self.curves.scalar_bits64(rs))
+            group_oh = np.zeros((G, C), bool)
+            group_oh[gid, np.arange(len(sub))] = True
+            # per-group H(m) columns; padded groups repeat the last real
+            # column (masked out by g_occ, any finite h keeps the math
+            # well-defined)
+            cols = [self._h_cols(m) for m in uniq]  # insertion order = gid
+            hx = np.concatenate(
+                [c[0] for c in cols] + [cols[-1][0]] * (G - M), axis=1)
+            hy = np.concatenate(
+                [c[1] for c in cols] + [cols[-1][1]] * (G - M), axis=1)
+            return tuple(
+                self._dput(a)
+                for a in (r_bits, group_oh, hx, hy, np.arange(G) < M)
             )
-        else:
-            words32, sig_x, sig_y, valid = staged
-            outs = self._rlc_msm_kernel("dense", 0, G)(
-                words32, sig_x, sig_y, dp(r_bits), dp(group_oh), valid
+
+        def run(plan, staged, r_bits, group_oh, hx, hy, g_occ):
+            *bank, sig_x, sig_y, valid = staged
+            outs = self._rlc_msm_kernel(plan.kind, plan.miss_k, G)(
+                *bank, sig_x, sig_y, r_bits, group_oh, valid
             )
-        verdict = self._rlc_check_kernel(G)(*outs, dp(hx), dp(hy), dp(g_occ))
-        self._stage[self._stage_idx].fence = verdict
+            return self._rlc_check_kernel(G)(*outs, hx, hy, g_occ)
+
+        launched = self._launch(
+            [(items[j][1], items[j][2]) for j in sub], operands, run
+        )
         self.rlc_stats.miller_lanes += G + 1
         self.rlc_stats.final_exp_lanes += 1
         if M > 1:
             self.multi_msg_launches += 1
-        self.host_dispatch_ms += (time.perf_counter() - t1) * 1000.0
-        self.host_dispatch_launches += 1
-        return verdict
+        return launched
 
     def _dispatch_rlc(self, items):
         """RLC-mode dispatch: pre-screen validity host-side (the same
@@ -814,12 +848,12 @@ class BN254Device:
             for j, (_m, bs, sig) in enumerate(items)
             if bs.cardinality() > 0 and getattr(sig, "point", None) is not None
         ]
-        vdev = (
+        vdev, seq = (
             self._rlc_combined_launch(items, valid_j)
             if len(valid_j) > 1
-            else None
+            else (None, None)
         )
-        return ("rlc", items, valid_j, vdev, k)
+        return ("rlc", items, valid_j, vdev, k, seq)
 
     def _fetch_rlc(self, handle):
         """Resolve an RLC handle: a passing combined check accepts every
@@ -827,21 +861,20 @@ class BN254Device:
         per-candidate oracle (`_dispatch_one` on the single candidate), so
         culprits are isolated and attributed exactly as per_candidate mode
         would. Invalid lanes are False without any device work."""
-        _, items, valid_j, vdev, k = handle
+        _, items, valid_j, vdev, k, seq = handle
         verdicts = [False] * k
-        top = [vdev]
+        top = [None if vdev is None else (vdev, seq)]
 
         def combined(sub):
-            v = top[0]
+            launched = top[0]
             top[0] = None
-            if v is None or len(sub) != len(valid_j):
-                v = self._rlc_combined_launch(items, sub)
-            return bool(np.asarray(v)[0])
+            if launched is None or len(sub) != len(valid_j):
+                launched = self._rlc_combined_launch(items, sub)
+            return self._pull(*launched, 1)[0]
 
         def oracle(j):
             msg, bs, sig = items[j]
-            v = self._dispatch_one(msg, [(bs, sig)])
-            return bool(np.asarray(v)[0])
+            return self._pull(*self._dispatch_one(msg, [(bs, sig)]), 1)[0]
 
         for j, ok in rlc.bisect_verify(
             valid_j, combined, oracle, self.rlc_stats
@@ -905,14 +938,35 @@ class BN254Device:
         the in-flight combined check; bisection (if any) runs at fetch."""
         if self.batch_check == "rlc":
             return self._dispatch_rlc([(msg, bs, sig) for bs, sig in requests])
-        return (self._dispatch_one(msg, requests), len(requests))
+        verdicts, seq = self._dispatch_one(msg, requests)
+        return (verdicts, len(requests), seq)
 
     def fetch(self, handle) -> list[bool]:
         """Block until a dispatched launch's verdicts arrive; host-ordered."""
-        if len(handle) == 5 and handle[0] == "rlc":
+        if isinstance(handle[0], str):  # ("rlc", ...)
             return self._fetch_rlc(handle)
-        verdicts, k = handle
-        return [bool(v) for v in np.asarray(verdicts)[:k]]
+        verdicts, k, seq = handle
+        return self._pull(verdicts, seq, k)
+
+    @staticmethod
+    def launch_seq(handle) -> int | None:
+        """The number of the launch a `dispatch*` handle stands for (None:
+        an RLC handle that launched nothing yet) — what the service puts
+        in its launch spans beside the engine's own stages."""
+        return handle[-1]
+
+    def _pull(self, verdicts, seq, k: int) -> list[bool]:
+        """The fetch side of ONE launch: wait for its verdicts
+        (fetch_wait), copy them to the host and make the first `k` a list
+        of booleans (fetch_copy)."""
+        clock = self.stage_clock
+        with clock.stage("fetch_wait", seq):
+            if isinstance(verdicts, jax.Array):
+                verdicts.block_until_ready()
+        with clock.stage("fetch_copy", seq):
+            out = np.asarray(verdicts)[:k].tolist()
+        self.host_fetch_launches += 1
+        return out
 
     # -- batched aggregate combine (store.py merge path) --------------------
 
@@ -928,6 +982,7 @@ class BN254Device:
             def kern(px, py, pz, mask):
                 return g1.to_affine(g1.masked_sum((px, py, pz), mask, k))
 
+            kern.__name__ = f"combine{k}"
             fn = jax.jit(kern)
             self._combine_kernels[k] = fn
         return fn
@@ -1064,13 +1119,13 @@ class BN254Device:
         return launches
 
     def reset_host_counters(self) -> None:
-        """Zero the host pack/dispatch cost counters (warmup and bench
-        phase boundaries: accumulation must start at the phase, not at
-        construction)."""
-        self.host_pack_ms = 0.0
+        """Zero the host-stage cost counters (warmup and bench phase
+        boundaries: accumulation must start at the phase, not at
+        construction). Launch numbering goes on: a seq is an identity."""
+        self.stage_clock.reset()
         self.host_pack_launches = 0
-        self.host_dispatch_ms = 0.0
         self.host_dispatch_launches = 0
+        self.host_fetch_launches = 0
         self.rlc_stats = rlc.RlcStats()
 
     # missing-signer patch width cap: candidates whose range hull has more
@@ -1110,8 +1165,11 @@ class BN254Device:
             c >= 64, cls._U64_ONES, (np.uint64(1) << shift) - np.uint64(1)
         )
 
-    def _pack_requests(self, requests) -> "LaunchPlan":
-        """Vectorized launch packing: requests -> device-input arrays.
+    def _pack_requests(self, requests, seq: int | None = None) -> "LaunchPlan":
+        """Vectorized launch packing: requests -> device-input arrays, as
+        two timed stages of launch `seq`: the wait on the staging set's
+        fence (fence_wait — under saturation about one device launch), then
+        the packing itself (pack).
 
         Bitsets hand over their packed uint64 words (BitSet.words, zero
         copy) straight into the rotated staging set — the same words array
@@ -1130,16 +1188,23 @@ class BN254Device:
         rotation boundaries), which keeps the old per-candidate construction
         as the readable oracle.
         """
+        self._stage_idx = (self._stage_idx + 1) % len(self._stage)
+        st = self._stage[self._stage_idx]
+        clock = self.stage_clock
+        with clock.stage("fence_wait", seq):
+            if st.fence is not None:
+                # the last launch that read this set must have consumed its
+                # inputs before we overwrite them (no-op once it completed)
+                st.fence.block_until_ready()
+                st.fence = None
+        with clock.stage("pack", seq, cpu=True):
+            return self._pack_into(st, requests)
+
+    def _pack_into(self, st: _StagingSet, requests) -> "LaunchPlan":
+        """The packing itself, into staging set `st` (see `_pack_requests`)."""
         C = self.batch_size
         n = self.n
         k = len(requests)
-        self._stage_idx = (self._stage_idx + 1) % len(self._stage)
-        st = self._stage[self._stage_idx]
-        if st.fence is not None:
-            # the last launch that read this set must have consumed its
-            # inputs before we overwrite them (no-op once it completed)
-            st.fence.block_until_ready()
-            st.fence = None
         words = st.words
         words[:] = 0
         valid = st.valid
@@ -1388,22 +1453,36 @@ class BN254Device:
             valid,
         )
 
-    def _dispatch_one(self, msg, requests):
-        t0 = time.perf_counter()
-        plan = self._pack_requests(requests)
-        t1 = time.perf_counter()
-        self.host_pack_ms += (t1 - t0) * 1000.0
+    def _launch(self, requests, operands, run):
+        """The host side of ONE launch, the body every dispatch path shares:
+        number it, pack it (`_pack_requests`: fence_wait, pack), hand the
+        plan's arrays and `operands()` — the H(m) columns, or the RLC
+        scalars and group operands — to the device (stage), then call the
+        kernels through `run(plan, staged, *operands)` until the jitted call
+        returns (enqueue). Returns (verdicts, seq); on the single-device
+        path the device work is in flight."""
+        seq = self._next_seq
+        self._next_seq += 1
+        clock = self.stage_clock
+        plan = self._pack_requests(requests, seq)
         self.host_pack_launches += 1
-        h_x, h_y = self._h_point(msg)
-        staged = self._stage_plan(plan)
-        verdicts = self._run_plan(plan, staged, h_x, h_y)
-        if isinstance(verdicts, jax.Array):
-            # fence the staging set this launch reads: _pack_requests blocks
-            # on it before the rotation wraps back onto these buffers
-            self._stage[self._stage_idx].fence = verdicts
-        self.host_dispatch_ms += (time.perf_counter() - t1) * 1000.0
+        with clock.stage("stage", seq):
+            staged = self._stage_plan(plan)
+            ops = operands()
+        with clock.stage("enqueue", seq):
+            verdicts = run(plan, staged, *ops)
+            if isinstance(verdicts, jax.Array):
+                # fence the staging set this launch reads: _pack_requests
+                # blocks on it before the rotation wraps back onto these
+                # buffers
+                self._stage[self._stage_idx].fence = verdicts
         self.host_dispatch_launches += 1
-        return verdicts
+        return verdicts, seq
+
+    def _dispatch_one(self, msg, requests):
+        return self._launch(
+            requests, lambda: self._h_point(msg), self._run_plan
+        )
 
     # -- multi-message launches (multi-tenant service coalescing) -----------
 
@@ -1467,20 +1546,11 @@ class BN254Device:
         reqs = [(it[2], it[3]) for it in items]
         if len(set(msgs)) <= 1:
             return self.dispatch(msgs[0] if msgs else b"", reqs)
-        t0 = time.perf_counter()
-        plan = self._pack_requests(reqs)
-        t1 = time.perf_counter()
-        self.host_pack_ms += (t1 - t0) * 1000.0
-        self.host_pack_launches += 1
-        h_x, h_y = self._h_lanes(msgs)
-        staged = self._stage_plan(plan)
-        verdicts = self._run_plan(plan, staged, h_x, h_y)
-        if isinstance(verdicts, jax.Array):
-            self._stage[self._stage_idx].fence = verdicts
-        self.host_dispatch_ms += (time.perf_counter() - t1) * 1000.0
-        self.host_dispatch_launches += 1
+        verdicts, seq = self._launch(
+            reqs, lambda: self._h_lanes(msgs), self._run_plan
+        )
         self.multi_msg_launches += 1
-        return (verdicts, len(reqs))
+        return (verdicts, len(reqs), seq)
 
 
 class BN254JaxConstructor(BN254Constructor):

@@ -569,6 +569,9 @@ class Field:
 
             fn = pl.pallas_call(
                 kernel,
+                # operation and stacked width, so a profiler trace tells
+                # the multiplications of one phase from another's
+                name=f"fp_mul_{n}x{bsz}",
                 out_shape=jax.ShapeDtypeStruct((n, bsz), jnp.uint32),
                 grid=(bsz // tile,),
                 in_specs=[

@@ -242,6 +242,7 @@ class BN254Pairing:
         at this public boundary)."""
         return self._f12_out(self._miller_loop_res(p, q, mask))
 
+    @jax.named_scope("miller_loop")
     def _miller_loop_res(self, p, q, mask=None):
         """`miller_loop` staying in the working representation (resident
         joint residues when self.resident) — the form `pairing` and
@@ -294,6 +295,7 @@ class BN254Pairing:
 
     # -- final exponentiation ------------------------------------------------
 
+    @jax.named_scope("final_exp")
     def final_exp(self, f):
         """f^((p^12-1)/r): easy part by conjugation/Frobenius + one Fp12
         inversion, hard part by the BN addition chain
@@ -420,6 +422,7 @@ class BLS12Pairing(BN254Pairing):
             self._Tw.f12_pow_const(x, -bls.Z, cyclo=True), 22
         )
 
+    @jax.named_scope("final_exp")
     def final_exp(self, f):
         """Easy part + BLS12 hard part via
         3(p^4-p^2+1)/r = (z-1)^2 (z+p) (z^2+p^2-1) + 3

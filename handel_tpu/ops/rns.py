@@ -586,6 +586,7 @@ class RnsField(Field):
 
             fn = pl.pallas_call(
                 kernel,
+                name=f"rns_mul_{k}x{bsz}",
                 out_shape=jax.ShapeDtypeStruct((k, bsz), jnp.int32),
                 grid=(bsz // tile,),
                 in_specs=[tile_spec(), tile_spec()] + [

@@ -57,8 +57,10 @@ __all__ = ["BatchVerifierService", "CircuitBreaker", "DevicePlane"]
 FallbackVerifier = Callable[[bytes, Sequence[tuple[BitSet, object]]], list]
 
 # queued-request tuple layout (one flat tuple, future LAST — every consumer
-# below indexes it positionally): (session, msg, pubkeys, bitset, sig, fut)
-_SESSION, _MSG, _PUBKEYS, _BITSET, _SIG, _FUT = range(6)
+# below indexes it positionally):
+# (session, msg, pubkeys, bitset, sig, t_enqueued, fut); t_enqueued is the
+# trace-clock time of the push, what `queueWaitMs` is measured from
+_SESSION, _MSG, _PUBKEYS, _BITSET, _SIG, _T_ENQ, _FUT = range(7)
 
 
 class BatchVerifierService:
@@ -125,6 +127,7 @@ class BatchVerifierService:
                 )
         for lane in self.plane.lanes:
             self._hook_breaker(lane)
+            self._bind_stage_clock(lane)
         self.max_delay = max_delay_ms / 1000.0
         self.max_inflight = max(1, max_inflight)
         # -- resilience plane: per-lane breakers + host failover ------------
@@ -199,6 +202,12 @@ class BatchVerifierService:
         self.latency_launches = 0
         self.throughput_launches = 0
         self.mesh_fallbacks = 0
+        # time candidates spent queued, push in `verify` to the moment a
+        # lane's dispatcher took their group: measured per candidate, summed.
+        # Dedup hits and coalesced waiters never enter the queue and are
+        # not counted; neither is a group that failed over undispatched.
+        self.queue_wait_ms = 0.0
+        self.queue_wait_candidates = 0
         # per-tenant counters (service plane labels)
         self.tenant_candidates: dict[str, int] = {}
         self.tenant_dedup_hits: dict[str, int] = {}
@@ -329,6 +338,7 @@ class BatchVerifierService:
             self.start()
         loop = asyncio.get_running_loop()
         scope = session if dedup_scope is None else dedup_scope
+        t_enq = trace_now()  # one stamp: the loop below never yields
         futs = []
         for bs, sig in requests:
             # content digest, not raw words: one 65k-committee bitset is
@@ -366,7 +376,7 @@ class BatchVerifierService:
                 continue
             fut = loop.create_future()
             if not self.queue.push(
-                session, (session, msg, pubkeys, bs, sig, fut)
+                session, (session, msg, pubkeys, bs, sig, t_enq, fut)
             ):
                 # per-tenant admission bound: the hot session absorbs its
                 # own refusal through the pipeline's requeue/retry budget
@@ -458,6 +468,24 @@ class BatchVerifierService:
             )
         return stall
 
+    def _bind_stage_clock(self, lane: DeviceLane) -> None:
+        """Hand the lane's engine this service's recorder, lane index and
+        trace thread, so the engine's own launch stages (core/trace.py
+        StageClock) land beside the service's spans. Engines without a
+        stage clock (host stubs) have nothing to bind."""
+        clock = getattr(lane.engine, "stage_clock", None)
+        if clock is not None:
+            clock.bind(self.rec, lane.index, lane.trace_tid)
+
+    @staticmethod
+    def _launch_seq(lane: DeviceLane, handle) -> int | None:
+        """The engine's number for the launch behind `handle`; None for a
+        failed dispatch or an engine that does not number its launches."""
+        seq_of = getattr(lane.engine, "launch_seq", None)
+        if seq_of is None or handle is None:
+            return None
+        return seq_of(handle)
+
     def _hook_breaker(self, lane: DeviceLane) -> None:
         """Make this lane's breaker transitions observable: each state
         edge emits a trace instant on the lane's own trace thread so
@@ -486,6 +514,7 @@ class BatchVerifierService:
         plane): only latency-mode groups are routed to it."""
         lane = self.plane.add_lane(engine, breaker, mesh=mesh)
         self._hook_breaker(lane)
+        self._bind_stage_clock(lane)
         if self.rec is not None:
             kind = "device-mesh" if mesh else "device-lane"
             self.rec.name_thread(lane.trace_tid, f"{kind}-{lane.index}")
@@ -717,12 +746,13 @@ class BatchVerifierService:
                 target.q.put_nowait(items)
             self._collector_held = None
 
-    def _lane_span_args(self, lane: DeviceLane, items: list) -> dict:
-        """Launch-lifecycle span args: lane, group size, and the sessions
-        whose candidates ride this launch (computed only while tracing —
-        the set build never runs on the untraced hot path)."""
+    def _lane_span_args(self, lane: DeviceLane, items: list, seq) -> dict:
+        """Launch-lifecycle span args: lane, the engine's launch number,
+        group size, and the sessions whose candidates ride this launch
+        (computed only while tracing — the set build never runs on the
+        untraced hot path)."""
         args = {
-            "lane": lane.index, "n": len(items),
+            "lane": lane.index, "seq": seq, "n": len(items),
             "mode": "mesh" if lane.mesh else "lane",
         }
         sessions = sorted({it[_SESSION] for it in items if it[_SESSION]})
@@ -739,25 +769,31 @@ class BatchVerifierService:
             items = await lane.q.get()
             handle = None
             tracing = self.rec is not None and self.rec.enabled
-            t_deq = trace_now() if tracing else 0.0
-            if tracing and lane.queued_ts:
-                # time the group sat in the hand-off cell waiting for this
-                # lane — the first stage of its lifecycle timeline
-                self.rec.span(
-                    "launch_queued",
-                    lane.queued_ts,
-                    t_deq,
-                    tid=lane.trace_tid,
-                    cat="device",
-                    args=self._lane_span_args(lane, items),
-                )
+            t_deq = trace_now()
+            queued_ts = lane.queued_ts
             if lane.breaker.allow():
-                t0 = trace_now()
+                self.queue_wait_ms += 1e3 * sum(
+                    t_deq - it[_T_ENQ] for it in items
+                )
+                self.queue_wait_candidates += len(items)
+                t0 = t_deq
                 handle = await self._dispatch_with_retries(
                     lane, self._launch_call(lane, items)
                 )
                 if tracing:
                     t_disp = trace_now()
+                    largs = self._lane_span_args(
+                        lane, items, self._launch_seq(lane, handle)
+                    )
+                    if queued_ts:
+                        # time the group sat in the hand-off cell waiting
+                        # for this lane — the first stage of its lifecycle
+                        # timeline (emitted here: only the dispatch knows
+                        # the launch's number)
+                        self.rec.span(
+                            "launch_queued", queued_ts, t_deq,
+                            tid=lane.trace_tid, cat="device", args=largs,
+                        )
                     # the host half of a launch: request packing + the
                     # async enqueue (PR 1's host_pack_ms lives in here)
                     self.rec.span(
@@ -779,7 +815,7 @@ class BatchVerifierService:
                         t_disp,
                         tid=lane.trace_tid,
                         cat="device",
-                        args=self._lane_span_args(lane, items),
+                        args=largs,
                     )
             if handle is None:
                 # this lane's breaker opened (or retries exhausted): the
@@ -918,19 +954,13 @@ class BatchVerifierService:
                 continue
             if self.rec is not None and self.rec.enabled:
                 t_end = trace_now()
-                # device wall per launch (verdict-arrival latency), the
-                # counterpart of dispatch_pack's host half
-                self.rec.span(
-                    "device_verify",
-                    t0,
-                    t_end,
-                    tid=SERVICE_TID,
-                    cat="verifier",
-                    args={"n": len(items), "device": lane.index},
+                largs = self._lane_span_args(
+                    lane, items, self._launch_seq(lane, handle)
                 )
-                largs = self._lane_span_args(lane, items)
                 # lane-timeline remainder of the lifecycle: in flight on
-                # the chip since dispatch, and the verdict transfer window.
+                # the chip since dispatch, and `launch_fetched`, the wait
+                # for the verdicts and their transfer (device wall per
+                # launch as the host sees it; it lies inside the former).
                 # Mesh launches carry their own span name so the critical-
                 # path analyzer (sim/trace_cli.py) attributes whole-mesh
                 # walls distinctly from per-chip lane walls.
@@ -984,6 +1014,7 @@ class BatchVerifierService:
         hc = self.plane.host_cost()
         pack_ms, pack_n = hc["pack_ms"], hc["pack_launches"]
         disp_ms, disp_n = hc["dispatch_ms"], hc["dispatch_launches"]
+        stage_ms = hc["stage_ms"]
         return {
             "verifierLaunches": float(self.launches),
             "verifierCandidates": float(self.candidates),
@@ -1024,6 +1055,21 @@ class BatchVerifierService:
             "hostDispatchMs": disp_ms,
             "hostDispatchLaunches": disp_n,
             "hostDispatchMsPerLaunch": disp_ms / disp_n if disp_n else 0.0,
+            # the same host path by stage (core/trace.py StageClock; 0 for
+            # engines without one): hostPackMs = fence wait + pack work,
+            # hostDispatchMs = stage + enqueue; pack wall minus pack CPU is
+            # time the packing thread did not run (interpreter lock)
+            "hostFenceWaitMs": stage_ms["fence_wait"],
+            "hostPackWorkMs": stage_ms["pack"],
+            "hostPackCpuMs": hc["pack_cpu_ms"],
+            "hostStageMs": stage_ms["stage"],
+            "hostEnqueueMs": stage_ms["enqueue"],
+            "hostFetchWaitMs": stage_ms["fetch_wait"],
+            "hostFetchCopyMs": stage_ms["fetch_copy"],
+            "hostFetchLaunches": hc["fetch_launches"],
+            # queue wait measured per candidate, push to lane hand-over
+            "queueWaitMs": self.queue_wait_ms,
+            "queueWaitCandidates": float(self.queue_wait_candidates),
             # resilience plane: worst lane state + fleet-summed counters
             "breakerState": max(
                 BREAKER_CODE[l.breaker.state] for l in self.plane.lanes

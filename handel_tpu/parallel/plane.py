@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import asyncio
 
+from handel_tpu.core.trace import LAUNCH_STAGES
 from handel_tpu.utils.breaker import CircuitBreaker
 
 __all__ = ["DeviceLane", "DevicePlane", "bn254_plane", "host_plane"]
@@ -266,23 +267,26 @@ class DevicePlane:
     def inflight_launches(self) -> int:
         return sum(l.inflight() for l in self.lanes)
 
-    def host_cost(self) -> dict[str, float]:
+    def host_cost(self) -> dict:
         """Per-launch host accounting SUMMED over the fleet's engines (the
-        service used to read the counters off device 0 only)."""
+        service used to read the counters off device 0 only): the pack and
+        dispatch totals any engine may carry, and `stage_ms`, the same path
+        by stage, from engines with a stage clock (core/trace.py)."""
         out = {"pack_ms": 0.0, "pack_launches": 0.0,
-               "dispatch_ms": 0.0, "dispatch_launches": 0.0}
+               "dispatch_ms": 0.0, "dispatch_launches": 0.0,
+               "fetch_launches": 0.0, "pack_cpu_ms": 0.0}
+        stage_ms = dict.fromkeys(LAUNCH_STAGES, 0.0)
         for lane in self.lanes:
             eng = lane.engine
-            out["pack_ms"] += float(getattr(eng, "host_pack_ms", 0.0))
-            out["pack_launches"] += float(
-                getattr(eng, "host_pack_launches", 0)
-            )
-            out["dispatch_ms"] += float(
-                getattr(eng, "host_dispatch_ms", 0.0)
-            )
-            out["dispatch_launches"] += float(
-                getattr(eng, "host_dispatch_launches", 0)
-            )
+            for key in ("pack_ms", "pack_launches", "dispatch_ms",
+                        "dispatch_launches", "fetch_launches"):
+                out[key] += float(getattr(eng, f"host_{key}", 0.0))
+            clock = getattr(eng, "stage_clock", None)
+            if clock is not None:
+                for name, ms in clock.ms.items():
+                    stage_ms[name] += ms
+                out["pack_cpu_ms"] += clock.cpu_ms["pack"]
+        out["stage_ms"] = stage_ms
         return out
 
     def values(self) -> dict[str, float]:

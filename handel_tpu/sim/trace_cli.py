@@ -8,7 +8,7 @@ cluster) and answers the questions the CSV cannot:
 - the aggregation wave: per level, when the first / median / last node
   completed it (the paper's completion-time curve, observed per run);
 - slowest-span attribution: which pipeline stage (recv, queue, verify,
-  merge, dispatch_pack, device_verify, net_transit) the wall time went to;
+  merge, dispatch_pack, launch_fetched, net_transit) the wall time went to;
 - per-contribution chains: recv -> queue -> verify -> merge span coverage,
   surfacing where a contribution stalled;
 - the CRITICAL PATH to threshold (`--critical-path`): walk the
@@ -241,7 +241,7 @@ class _TraceIndex:
                 ).append(e)
             elif name == "send" and a.get("span"):
                 self.sends[a["span"]] = e
-            elif name == "device_verify":
+            elif name == "launch_fetched":
                 self.device_ivs.setdefault(e.get("pid", 0), []).append(
                     (e["ts"], e["ts"] + e.get("dur", 0.0))
                 )
@@ -319,7 +319,7 @@ def _walk_chain(anchor: dict, index_of, send_of) -> list[dict]:
 
 def _chain_to_report(chain: list[dict], anchor: dict, device_ivs_of) -> dict:
     """Fold a walked chain into the critical-path report dict;
-    `device_ivs_of(pid)` yields that process's device_verify intervals for
+    `device_ivs_of(pid)` yields that process's launch_fetched intervals for
     the verify -> device re-attribution."""
     chain = list(reversed(chain))  # origin-first: send ... -> final merge
     start = min(e["ts"] for e in chain) if chain else anchor["ts"]
@@ -404,7 +404,7 @@ def critical_path(events: list[dict]) -> dict | None:
     The walk ends at a send with no producing merge — the contribution's
     origin. Returns None when the trace holds no threshold instant.
 
-    Verify time overlapping the shared service's `device_verify` launches
+    Verify time overlapping the shared service's `launch_fetched` spans
     (same process) is re-attributed to the `device` stage, so host-queue
     wait and chip wall are separated in the stage breakdown.
     """

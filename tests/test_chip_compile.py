@@ -16,6 +16,7 @@ never at import, in a `skipif` or in `parametrize` — everything compiles in
 the test's own process, and all such tests live in this one file.
 """
 
+import re
 from functools import partial
 
 import jax
@@ -94,6 +95,8 @@ def test_cios_mul_bn254(shape, chip_choices, width):
     x = shape((F.nlimbs, width), U32)
     compiled = jax.jit(F.mul).lower(x, x).compile()
     assert mosaic_calls(compiled) == 1
+    # the kernel's name says operation and stacked width (profiler traces)
+    assert f"%fp_mul_16x{width}" in compiled.as_text()
 
 
 @pytest.mark.parametrize("width", [128, 6912])
@@ -113,6 +116,7 @@ def test_rns_resident_mul(shape, chip_choices):
     r = shape((F.k_all, 6912), I32)
     compiled = jax.jit(F.mul_resident).lower(r, r).compile()
     assert mosaic_calls(compiled) == 1
+    assert f"%rns_mul_{F.k_all}x6912" in compiled.as_text()
 
 
 def _device(n_keys: int):
@@ -144,10 +148,17 @@ def test_range_aggregate(shape, chip_choices):
     """The aggregation stage of the range launch at full width: prefix-table
     gathers plus the 8-wide hole patch (point adds only, no pairing). The
     bank is a jit argument, so a 2-key engine lowers the 4096-key program."""
+    from handel_tpu.models.bn254_jax import _named
+
     dev = _device(2)
-    fn = jax.jit(partial(dev._range_aggregate, miss_k=8))
+    fn = jax.jit(_named(partial(dev._range_aggregate, miss_k=8), "range_agg8"))
     compiled = fn.lower(*_range_args(shape, 8), *_bank(shape, N_KEYS)).compile()
     assert mosaic_calls(compiled) > 0
+    # program and phase reach the compiled module: its name, and the scope
+    # in the operations' metadata — the Mosaic calls' too
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_range_agg8")
+    assert re.search(r'op_name="jit\(range_agg8\)/agg/[^"]*fp_mul_16x', text)
 
 
 def _report(name, compiled):
@@ -157,6 +168,13 @@ def _report(name, compiled):
         f"temp_bytes={ma.temp_size_in_bytes} "
         f"code_bytes={ma.generated_code_size_in_bytes}"
     )
+
+
+def _assert_phases(compiled):
+    """The four phases of a launch are scopes in the compiled program."""
+    text = compiled.as_text()
+    for scope in ("agg", "to_affine", "miller_loop", "final_exp"):
+        assert re.search(rf'op_name="jit\([^"]*/{scope}/', text), scope
 
 
 # The two full pairing launches are minutes each (range ~4.5 min, dense
@@ -177,6 +195,7 @@ def test_full_range_launch(shape, chip_choices):
     ).compile()
     _report("range launch", compiled)
     assert mosaic_calls(compiled) > 100
+    _assert_phases(compiled)
 
 
 @pytest.mark.slow
@@ -192,3 +211,4 @@ def test_full_dense_launch(shape, chip_choices):
     ).compile()
     _report("dense launch", compiled)
     assert mosaic_calls(compiled) > 100
+    _assert_phases(compiled)

@@ -1,0 +1,326 @@
+"""The launch lifecycle measured inside the program (ISSUE 26).
+
+One stage clock (core/trace.py StageClock) times every host stage of a
+device launch — fence_wait, pack, stage, enqueue on the dispatch side,
+fetch_wait, fetch_copy on the fetch side — as a counter (always), a
+`launch/<stage>` recorder span (when a recorder listens) and a
+`handel/<stage>` profiler annotation, all carrying the engine's launch
+number `seq`, which the service puts in its own launch spans too.
+
+Fast-tier by design, like tests/test_device_residency.py: the engines here
+pack, stage and fetch for real, but the pairing kernels (minutes of XLA on a
+CPU) are replaced by a jitted echo of the `valid` mask — the lifecycle is
+what is under test, not the verdicts.
+"""
+
+import asyncio
+import glob
+import os
+import random
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from handel_tpu import native as nat
+from handel_tpu.core.bitset import BitSet
+from handel_tpu.core.trace import LAUNCH_STAGES, FlightRecorder, StageClock
+from handel_tpu.models.bn254 import BN254PublicKey, BN254Signature
+from handel_tpu.models.bn254_jax import BN254Device, _named
+from handel_tpu.ops import bn254_ref as bn
+from handel_tpu.parallel.batch_verifier import BatchVerifierService
+
+N = 12
+C = 4
+STAGE_COUNTERS = (
+    "hostFenceWaitMs", "hostPackWorkMs", "hostPackCpuMs", "hostStageMs",
+    "hostEnqueueMs", "hostFetchWaitMs", "hostFetchCopyMs",
+    "hostFetchLaunches", "hostPackMs", "hostDispatchMs",
+)
+
+_echo = jax.jit(lambda valid: jnp.logical_and(valid, True))
+_accept = jax.jit(lambda: jnp.ones((1,), bool))
+
+
+def _pubkeys():
+    rng = random.Random(5)
+    sks = [rng.randrange(1, 1 << 20) for _ in range(N)]
+    return [BN254PublicKey(p) for p in nat.g2_mul_batch([bn.G2_GEN] * N, sks)]
+
+
+def _device(**kw) -> BN254Device:
+    """A small engine whose launches run an echo instead of a pairing."""
+    dev = BN254Device(_pubkeys(), batch_size=C, **kw)
+    dev._run_plan = lambda plan, staged, h_x, h_y: _echo(staged[-1])
+    dev._rlc_msm_kernel = lambda kind, miss_k, G: (lambda *args: ())
+    dev._rlc_check_kernel = lambda G: (lambda *args: _accept())
+    return dev
+
+
+def _requests(rng, k=C):
+    """k distinct range candidates (distinct content: no dedup hit)."""
+    sig = BN254Signature(bn.G1_GEN)
+    seen, reqs = set(), []
+    while len(reqs) < k:
+        size = rng.randrange(2, N)
+        lo = rng.randrange(0, N - size + 1)
+        if (lo, size) in seen:
+            continue
+        seen.add((lo, size))
+        bs = BitSet(N)
+        bs.set_range(lo, lo + size)
+        reqs.append((bs, sig))
+    return reqs
+
+
+def _launch(dev, how: str, reqs):
+    if how == "dispatch_multi":  # two messages: the per-lane-h path
+        items = [(b"m%d" % (j % 2), None, bs, sig)
+                 for j, (bs, sig) in enumerate(reqs)]
+        return dev.fetch(dev.dispatch_multi(items))
+    return dev.fetch(dev.dispatch(b"m", reqs))
+
+
+# -- (a) the stage counters add up, whichever path built the launch ----------
+
+
+@pytest.mark.parametrize("how", ["dispatch", "dispatch_multi", "rlc"])
+def test_stage_counters_add_up(how):
+    launches = 5
+    dev = _device(batch_check="rlc", rlc_rng=random.Random(1)) \
+        if how == "rlc" else _device()
+    svc = BatchVerifierService(dev)  # values() only: never started
+    rng = random.Random(7)
+    before = svc.values()
+    for _ in range(launches):
+        assert _launch(dev, how, _requests(rng)) == [True] * C
+        now = svc.values()
+        for key in STAGE_COUNTERS:
+            assert now[key] >= before[key], key  # monotone
+        before = now
+    v = svc.values()
+    assert v["hostPackLaunches"] == v["hostDispatchLaunches"] == launches
+    assert v["hostFetchLaunches"] == launches
+    # the old totals are the sums of their stages: nothing between two
+    # stages of a launch goes untimed (0.1 ms a launch)
+    assert abs(v["hostFenceWaitMs"] + v["hostPackWorkMs"]
+               - v["hostPackMs"]) <= 0.1 * launches
+    assert abs(v["hostStageMs"] + v["hostEnqueueMs"]
+               - v["hostDispatchMs"]) <= 0.1 * launches
+    assert v["hostPackMs"] == dev.host_pack_ms > 0.0
+    assert v["hostDispatchMs"] == dev.host_dispatch_ms > 0.0
+    assert 0.0 < v["hostPackCpuMs"] <= v["hostPackWorkMs"] + 1.0 * launches
+    assert v["hostFetchCopyMs"] > 0.0 and v["hostFetchWaitMs"] >= 0.0
+    # launches are numbered from 0, and a counter reset keeps the numbering
+    assert dev._next_seq == launches
+    dev.reset_host_counters()
+    assert dev.host_pack_ms == 0.0 and dev.host_fetch_launches == 0
+    assert dev.launch_seq(dev.dispatch(b"m", _requests(rng))) == launches
+
+
+def test_second_use_of_a_staging_set_waits_on_its_fence():
+    """fence_wait is the block on the launch that last read the staging set:
+    with two sets, the third launch's fence is the first launch's verdicts."""
+    dev = _device()
+    rng = random.Random(3)
+    handles = [dev.dispatch(b"m", _requests(rng)) for _ in range(3)]
+    assert [dev.launch_seq(h) for h in handles] == [0, 1, 2]
+    assert dev._stage[dev._stage_idx].fence is handles[2][0]
+    for h in handles:
+        dev.fetch(h)
+    assert dev.host_fetch_launches == dev.host_pack_launches == 3
+
+
+# -- (b) one seq through the engine's spans and the service's ----------------
+
+
+def test_launch_spans_share_seq_in_order_per_lane():
+    dev = _device()
+    rec = FlightRecorder()
+    rng = random.Random(11)
+    launches = 3
+
+    async def go():
+        svc = BatchVerifierService(dev, max_delay_ms=1.0, recorder=rec)
+        try:
+            for _ in range(launches):
+                got = await svc.verify(b"m", None, _requests(rng), session="s")
+                assert got == [True] * C
+        finally:
+            svc.stop()
+        return svc.plane.lanes[0]
+
+    lane = asyncio.run(go())
+    by_seq: dict = {}
+    for name, ph, ts, dur, tid, cat, args, _ in rec.events():
+        if ph == "X" and args and "seq" in args:
+            assert tid == lane.trace_tid and args["lane"] == lane.index, name
+            by_seq.setdefault(args["seq"], {})[name] = (ts, ts + dur)
+    assert sorted(by_seq) == list(range(launches))
+    engine = [f"launch/{s}" for s in LAUNCH_STAGES]
+    service = ["launch_queued", "launch_staged", "launch_on_device",
+               "launch_fetched"]
+    last_end = 0.0
+    for seq in range(launches):
+        spans = by_seq[seq]
+        assert sorted(spans) == sorted(engine + service)
+        starts = [spans[n][0] for n in engine]
+        assert starts == sorted(starts)  # the stages of a launch, in order
+        # the engine's dispatch stages lie inside the service's staging
+        # span, its fetch stages inside the service's fetch span
+        s0, s1 = spans["launch_staged"]
+        for n in engine[:4]:
+            assert s0 - 1e-3 <= spans[n][0] and spans[n][1] <= s1 + 1e-3, n
+        f0, f1 = spans["launch_fetched"]
+        for n in engine[4:]:
+            assert f0 - 1e-3 <= spans[n][0] and spans[n][1] <= f1 + 1e-3, n
+        assert spans["launch/fence_wait"][0] >= last_end - 1e-3  # lane order
+        last_end = spans["launch/enqueue"][1]
+    assert not any(e[0] == "device_verify" for e in rec.events())
+
+
+def test_stub_engine_launches_read_seq_none():
+    """An engine that does not number its launches: the service's spans
+    carry `seq: None` and nothing else changes."""
+    class Stub:
+        batch_size = C
+
+        def dispatch(self, msg, reqs):
+            return len(reqs)
+
+        def fetch(self, handle):
+            return [True] * handle
+
+    rec = FlightRecorder()
+
+    async def go():
+        svc = BatchVerifierService(Stub(), max_delay_ms=1.0, recorder=rec)
+        try:
+            return await svc.verify(b"m", None, _requests(random.Random(2)))
+        finally:
+            svc.stop()
+
+    assert asyncio.run(go()) == [True] * C
+    seqs = {e[0]: e[6]["seq"] for e in rec.events()
+            if e[0].startswith("launch_")}
+    assert seqs == dict.fromkeys(
+        ["launch_queued", "launch_staged", "launch_on_device",
+         "launch_fetched"])
+
+
+# -- (c) the same stages on the profiler's host plane ------------------------
+
+
+def test_profiler_annotations_carry_seq(tmp_path):
+    from jax.profiler import ProfileData
+
+    dev = _device()
+    dev.stage_clock.bind(None, lane=3, tid=0)
+    rng = random.Random(13)
+    _launch(dev, "dispatch", _requests(rng))  # warm the echo; seq 0 untraced
+    dev._next_seq = 0
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(3):
+            _launch(dev, "dispatch", _requests(rng))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    seen: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("handel/"):
+                    assert plane.name.startswith("/host:")
+                    st = dict(ev.stats)
+                    assert st["lane"] == 3
+                    seen.setdefault(ev.name[len("handel/"):], []).append(
+                        (st["seq"], ev.start_ns))
+    assert sorted(seen) == sorted(LAUNCH_STAGES)
+    for stage, evs in seen.items():
+        assert [seq for seq, _ in sorted(evs, key=lambda e: e[1])] == [0, 1, 2]
+
+
+# -- (d) nothing listens: the clock only counts ------------------------------
+
+
+class _CountingRecorder:
+    def __init__(self, enabled):
+        self.enabled = enabled
+        self.calls = []
+
+    def span(self, *args, **kw):
+        self.calls.append((args, kw))
+
+
+def test_stage_clock_without_a_listener_calls_nothing():
+    clock = StageClock()
+    with clock.stage("pack", 0, cpu=True):
+        pass
+    assert clock.ms["pack"] > 0.0 and clock.cpu_ms["pack"] >= 0.0
+    assert clock.ms["stage"] == 0.0
+
+    off = _CountingRecorder(enabled=False)
+    clock.bind(off, lane=1, tid=-3)
+    for seq in range(4):
+        with clock.stage("enqueue", seq):
+            pass
+    assert off.calls == []  # no span, so no args dict was built for one
+
+    on = _CountingRecorder(enabled=True)
+    clock.bind(on, lane=1, tid=-3)
+    with clock.stage("enqueue", 7):
+        pass
+    ((args, kw),) = on.calls
+    assert args[0] == "launch/enqueue" and args[2] >= args[1]
+    assert kw["tid"] == -3 and kw["args"] == {"seq": 7, "lane": 1}
+    before = clock.ms["enqueue"]
+    with pytest.raises(ValueError):
+        with clock.stage("enqueue", 8):
+            raise ValueError("a stage that fails is still timed")
+    assert clock.ms["enqueue"] > before and len(on.calls) == 1
+    clock.reset()
+    assert not any(clock.ms.values()) and not any(clock.cpu_ms.values())
+
+
+# -- (f) names on the device side ---------------------------------------------
+
+
+def test_jitted_programs_have_stable_names():
+    dev = _device(batch_check="rlc")
+    assert dev._kernel.__name__ == "verify_dense"
+    assert dev._combine_kernel(4).__name__ == "combine4"
+    real = BN254Device(_pubkeys(), batch_size=C, batch_check="rlc")
+    assert real._rlc_check_kernel(2).__name__ == "rlc_check2"
+    jitted = lambda fn: fn.__defaults__[0]  # the bank-injection wrappers
+    assert jitted(real._rlc_msm_kernel("dense", 0, 1)).__name__ == "rlc_msm_dense"
+    # the range classes build the prefix table first: its program too
+    assert jitted(real._range_agg_kernel(8)).__name__ == "range_agg8"
+    assert jitted(real._range_kernel(8)).__name__ == "verify_range8"
+    assert jitted(real._range_kernel(64)).__name__ == "verify_range64"
+    assert jitted(real._rlc_msm_kernel("range", 8, 2)).__name__ == "rlc_msm_range8"
+    # what the profiler's "XLA Modules" line will print
+    fn = jax.jit(_named(lambda x: x + 1, "verify_range8"))
+    assert "jit_verify_range8" in fn.lower(1.0).as_text()
+
+
+def test_launch_phases_are_named_scopes():
+    """agg / to_affine / miller_loop / final_exp reach the lowered program
+    as name-stack metadata (the aggregation stage alone: seconds)."""
+    dev = BN254Device(_pubkeys(), batch_size=C)
+    plan = dev._pack_requests(_requests(random.Random(17)))
+    args = dev._stage_plan(plan)[:4]
+    jitted = dev._range_agg_kernel(plan.miss_k).__defaults__[0]
+    text = jitted.lower(
+        *args, dev._prefix, dev._reg_x, dev._reg_y
+    ).as_text(debug_info=True)
+    assert "jit(range_agg8)/agg/" in text
+    # the pairing's two phases are scoped where both curve families pass
+    from handel_tpu.ops.pairing import BLS12Pairing, BN254Pairing
+
+    for cls in (BN254Pairing, BLS12Pairing):
+        assert cls.final_exp.__wrapped__.__name__ == "final_exp"
+        assert cls._miller_loop_res.__wrapped__.__name__ == "_miller_loop_res"

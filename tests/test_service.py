@@ -105,6 +105,39 @@ class MultiStubDevice:
         return handle
 
 
+# -- queue wait measured where it happens (ISSUE 26) -------------------------
+
+
+def test_queue_wait_counts_launched_candidates_only():
+    """`queueWaitMs`/`queueWaitCandidates` cover exactly the candidates a
+    lane's dispatcher took: dedup hits and coalesced duplicates never enter
+    the queue and are not counted."""
+    dev = MultiStubDevice(batch_size=4, launch_s=0.02)
+
+    async def go():
+        svc = BatchVerifierService(dev, max_delay_ms=1.0)
+        try:
+            reqs = [_req(t) for t in range(6)]
+            # the same six twice at once (the second six coalesce onto the
+            # first's lanes), then again afterwards (verdict-cache hits)
+            both = await asyncio.gather(
+                svc.verify(b"m", None, reqs, session="s"),
+                svc.verify(b"m", None, reqs, session="s"),
+            )
+            again = await svc.verify(b"m", None, reqs, session="s")
+            assert both == [[True] * 6] * 2 and again == [True] * 6
+            return svc.values()
+        finally:
+            svc.stop()
+
+    v = run(go())
+    assert v["queueWaitCandidates"] == sum(dev.lanes) == 6
+    assert v["verifierCandidates"] == 6 and v["dedupHits"] == 12
+    # two launches of 4 and 2: the second waited out the first's 20 ms
+    assert v["queueWaitMs"] >= 2 * 20.0 * 0.5
+    assert v["queueWaitMs"] / v["queueWaitCandidates"] < 1000.0
+
+
 # -- TenantQueue: deficit round robin ----------------------------------------
 
 
