@@ -129,7 +129,8 @@ def host_pipeline_bench(
             if i not in holes:
                 bs.set(i, True)
         requests.append((bs, sig))
-    # dense-fallback phase: scattered signer sets (> MISS_CAP hull holes)
+    # dense-fallback phase: scattered signer sets (hull holes past the
+    # widest range patch, n // 4)
     dense_requests = []
     for _ in range(lanes):
         bs = BitSet(n_registry)

@@ -11,8 +11,8 @@ fallback=None)`, and a few launch groups through `service.verify(...)`:
 
   * range class — contiguous partitioner level ranges with 0-8 offline
     holes (the prefix-table kernel, miss_k = 8);
-  * dense class — scattered signer sets with more than MISS_CAP holes (the
-    masked registry tree-sum kernel);
+  * dense class — scattered signer sets with more holes in the hull than
+    the widest range patch (n // 4; the masked registry tree-sum kernel);
 
 each with ONE forged candidate that must come back False while the rest
 come back True, and every verdict compared with the host reference
@@ -163,7 +163,8 @@ def range_group(rng, sks):
 
 def dense_group(rng, sks):
     """LANES candidates with scattered signer sets: about half the registry
-    each, so every hull has far more than MISS_CAP holes."""
+    each, so every hull has far more holes (about n / 2) than the widest
+    range patch (n // 4)."""
     sets = [
         [i for i in range(N_KEYS) if rng.random() < 0.5] for _ in range(LANES)
     ]

@@ -227,6 +227,11 @@ LAUNCH_STAGES = (
     "fence_wait", "pack", "stage", "enqueue", "fetch_wait", "fetch_copy",
 )
 
+#: the launch classes an engine counts its launches under (`class_launches`,
+#: models/bn254_jax.py): the two narrow hole-patch widths of the prefix-table
+#: path, the registry's wide one (n // 4), and the dense masked sum
+LAUNCH_CLASSES = ("range8", "range64", "range_wide", "dense")
+
 
 class StageClock:
     """Per-engine clock over the named host stages of its launches.

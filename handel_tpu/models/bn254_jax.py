@@ -35,7 +35,7 @@ from jax._src.core import trace_state_clean
 
 from handel_tpu.core.bitset import BitSet
 from handel_tpu.core.logging import DEFAULT_LOGGER
-from handel_tpu.core.trace import StageClock
+from handel_tpu.core.trace import LAUNCH_CLASSES, StageClock
 from handel_tpu.models import rlc
 from handel_tpu.models.bn254 import (
     BN254Constructor,
@@ -52,7 +52,8 @@ from handel_tpu.ops.pairing import BN254Pairing
 
 # Device-input arrays for one launch, as the packer hands them to dispatch:
 # kind selects the kernel family ("range" = prefix-table path with a miss_k-
-# wide hole patch, "dense" = masked registry sum); sig_* are packed limb
+# wide hole patch, miss_k one of the engine's `patch_widths`; "dense" =
+# masked registry sum); sig_* are packed limb
 # arrays; valid masks the real lanes. Array fields not used by `kind` are
 # None. `words` is the (C, W) uint64 bitset-word matrix — for a dense plan
 # it IS the device-transfer source (the kernel unpacks the candidate masks
@@ -289,7 +290,9 @@ class BN254Device:
         # the rotation/fence contract.
         self.stage_sets = 2
         self._stage = [
-            _StagingSet(self.n, batch_size, self.MISS_CAP, self.curves.F.nlimbs)
+            _StagingSet(
+                self.n, batch_size, self.patch_widths[-1], self.curves.F.nlimbs
+            )
             for _ in range(self.stage_sets)
         ]
         self._stage_idx = 0
@@ -300,9 +303,10 @@ class BN254Device:
         # (`host_dispatch_ms`), fetch_wait + fetch_copy pull the verdicts.
         # `_launch` and `_pull` are the only places a stage is timed.
         self.stage_clock = StageClock()
-        self.host_pack_launches = 0
-        self.host_dispatch_launches = 0
-        self.host_fetch_launches = 0
+        # ... beside them the launch counts: per stage side, per launch
+        # class (`_count_class`), and how full the wide class's patch runs
+        # (slots = wide x valid lanes, holes = the slots that carry a hole)
+        self.reset_host_counters()
         # launches are numbered per engine, from 0, in dispatch order; the
         # handle carries the number to `fetch`, and the service reads it
         # through `launch_seq`, so a launch's stages, spans and (dispatch
@@ -423,7 +427,7 @@ class BN254Device:
             self.n = st["n"]
             self._stage = [
                 _StagingSet(
-                    self.n, self.batch_size, self.MISS_CAP,
+                    self.n, self.batch_size, self.patch_widths[-1],
                     self.curves.F.nlimbs,
                 )
                 for _ in range(self.stage_sets)
@@ -1050,13 +1054,15 @@ class BN254Device:
         it and each variant is a full pairing-graph compile.
 
         Dispatches one synthetic launch per reachable input class — range
-        kernel at miss_k=8, range kernel at miss_k=64, dense fallback — so
-        no round ever stalls on a mid-run XLA compile (before this, the
-        first candidate in a new hole-count class blocked its whole round).
-        Classes a registry of this size cannot produce are skipped: the
-        64-hole class needs an 11-wide hull, the dense fallback a
-        (MISS_CAP+3)-wide one. Returns the number of launches issued.
-        Called at scheme construction (BN254JaxConstructor.prepare).
+        kernel at miss_k=8, at miss_k=64, at the registry's wide width
+        (n // 4, `patch_widths`), dense fallback — so no round ever stalls
+        on a mid-run XLA compile (before this, the first candidate in a new
+        hole-count class blocked its whole round). Classes a registry of
+        this size cannot produce are skipped: the 64-hole class needs an
+        11-wide hull, the dense fallback a (MISS_CAP+3)-wide one, the wide
+        class an n // 4 over MISS_CAP. Returns the number of launches
+        issued. Called at scheme construction
+        (BN254JaxConstructor.prepare).
         """
         shapes: list[list[int]] = [
             # zero holes -> miss_k=8 class (also builds the prefix table)
@@ -1065,9 +1071,13 @@ class BN254Device:
         if self.n >= 11:
             # hull [0, 11) with 9 holes -> miss_k=64 class
             shapes.append([0, 10])
-        if self.n >= self.MISS_CAP + 3:
-            # MISS_CAP+1 holes -> dense masked-sum fallback
+        if self.patch_widths[-1] > self.MISS_CAP:
+            # MISS_CAP+1 holes -> the wide class
             shapes.append([0, self.MISS_CAP + 2])
+        if self.n >= self.MISS_CAP + 3:
+            # the whole registry as hull, n-2 holes -> dense masked-sum
+            # fallback (past the wide class where there is one)
+            shapes.append([0, self.n - 1])
         sig = _WarmupSig(self.ref.G1_GEN)
         launches = 0
         for signers in shapes:
@@ -1126,11 +1136,45 @@ class BN254Device:
         self.host_pack_launches = 0
         self.host_dispatch_launches = 0
         self.host_fetch_launches = 0
+        self.class_launches = dict.fromkeys(LAUNCH_CLASSES, 0)
+        self.patch_slots = 0
+        self.patch_holes = 0
         self.rlc_stats = rlc.RlcStats()
 
-    # missing-signer patch width cap: candidates whose range hull has more
-    # holes than this fall back to the dense masked-sum kernel
+    # widest NARROW missing-signer patch: a launch whose largest hole count
+    # is over this takes the registry's wide class where it has one
+    # (`patch_widths`), else the dense masked-sum kernel
     MISS_CAP = 64
+
+    @property
+    def patch_widths(self) -> tuple[int, ...]:
+        """The range classes' patch widths, ascending: 8, MISS_CAP and,
+        where it exceeds MISS_CAP, n // 4 — the most holes a
+        top-level range (n / 2 ids) can have while patching them is still
+        cheaper than summing its signers, so one wide class holds every
+        level aggregate of a committee with up to half of a range absent.
+        Each width is one compile of the pairing graph; the wide one is
+        read off the registry, never configured."""
+        wide = self.n // 4
+        return (8, self.MISS_CAP, wide) if wide > self.MISS_CAP else (
+            8, self.MISS_CAP)
+
+    def _patch_width(self, max_holes: int) -> int:
+        """The class of a launch, from its largest hole count: the
+        narrowest patch width that holds it, 0 = dense."""
+        return next((k for k in self.patch_widths if max_holes <= k), 0)
+
+    def _count_class(self, plan) -> None:
+        """One launch of `plan`'s class (see `class_launches`)."""
+        if plan.kind == "dense":
+            name = "dense"
+        elif plan.miss_k > self.MISS_CAP:
+            name = "range_wide"
+            self.patch_slots += plan.miss_k * int(np.count_nonzero(plan.valid))
+            self.patch_holes += int(np.count_nonzero(plan.miss_ok))
+        else:
+            name = f"range{plan.miss_k}"
+        self.class_launches[name] += 1
 
     @staticmethod
     def _pack_sig_limbs(F, pts, out):
@@ -1254,7 +1298,12 @@ class BN254Device:
         pts += [self.ref.G1_GEN] * (C - k)  # pad lanes
         self._pack_sig_limbs(self.curves.F, pts, st)
 
-        if max_holes > self.MISS_CAP:
+        # quantize the patch width to the few classes of `patch_widths`, so
+        # that no more range kernels ever compile (each variant jit-compiles
+        # the whole pairing graph; a fresh hole-count class mid-run would
+        # otherwise stall that verification round on XLA)
+        miss_k = self._patch_width(max_holes)
+        if not miss_k:
             # dense fallback: the words themselves are the device input
             # (mask unpacked on device by _unpack_words)
             return LaunchPlan(
@@ -1262,11 +1311,6 @@ class BN254Device:
                 st.sig_x, st.sig_y, valid,
             )
 
-        # quantize the patch width to two classes so at most two range
-        # kernels ever compile (each variant jit-compiles the whole
-        # pairing graph; a fresh hole-count class mid-run would
-        # otherwise stall that verification round on XLA)
-        miss_k = 8 if max_holes <= 8 else self.MISS_CAP
         miss_idx = st.miss[:miss_k]
         miss_ok = st.miss_ok[:miss_k]
         miss_idx[:] = 0
@@ -1324,7 +1368,8 @@ class BN254Device:
             int(idx[-1] - idx[0] + 1 - idx.size) if v and idx.size else 0
             for idx, v in zip(sets, valid)
         ]
-        if max(holes, default=0) > self.MISS_CAP:
+        miss_k = self._patch_width(max(holes, default=0))
+        if not miss_k:
             mask = np.zeros((self.n, C), dtype=bool)
             for j, idx in enumerate(sets):
                 if valid[j] and idx.size:
@@ -1335,7 +1380,6 @@ class BN254Device:
             )
         lo = np.zeros((C,), np.int32)
         hi = np.zeros((C,), np.int32)
-        miss_k = 8 if max(holes, default=0) <= 8 else self.MISS_CAP
         miss_idx = np.zeros((miss_k, C), np.int64)
         miss_ok = np.zeros((miss_k, C), dtype=bool)
         for j, idx in enumerate(sets):
@@ -1466,6 +1510,7 @@ class BN254Device:
         clock = self.stage_clock
         plan = self._pack_requests(requests, seq)
         self.host_pack_launches += 1
+        self._count_class(plan)
         with clock.stage("stage", seq):
             staged = self._stage_plan(plan)
             ops = operands()

@@ -1015,6 +1015,7 @@ class BatchVerifierService:
         pack_ms, pack_n = hc["pack_ms"], hc["pack_launches"]
         disp_ms, disp_n = hc["dispatch_ms"], hc["dispatch_launches"]
         stage_ms = hc["stage_ms"]
+        classes = hc["class_launches"]
         return {
             "verifierLaunches": float(self.launches),
             "verifierCandidates": float(self.candidates),
@@ -1067,6 +1068,16 @@ class BatchVerifierService:
             "hostFetchWaitMs": stage_ms["fetch_wait"],
             "hostFetchCopyMs": stage_ms["fetch_copy"],
             "hostFetchLaunches": hc["fetch_launches"],
+            # launches by class (models/bn254_jax.py `patch_widths`: the
+            # packer picks the class from the launch's largest hole count),
+            # and how full the wide class's patch ran: slots = wide x valid
+            # lanes, holes = slots that carried a hole
+            "launchesRange8": classes["range8"],
+            "launchesRange64": classes["range64"],
+            "launchesRangeWide": classes["range_wide"],
+            "launchesDense": classes["dense"],
+            "patchSlots": hc["patch_slots"],
+            "patchHoles": hc["patch_holes"],
             # queue wait measured per candidate, push to lane hand-over
             "queueWaitMs": self.queue_wait_ms,
             "queueWaitCandidates": float(self.queue_wait_candidates),

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import asyncio
 
-from handel_tpu.core.trace import LAUNCH_STAGES
+from handel_tpu.core.trace import LAUNCH_CLASSES, LAUNCH_STAGES
 from handel_tpu.utils.breaker import CircuitBreaker
 
 __all__ = ["DeviceLane", "DevicePlane", "bn254_plane", "host_plane"]
@@ -271,13 +271,21 @@ class DevicePlane:
         """Per-launch host accounting SUMMED over the fleet's engines (the
         service used to read the counters off device 0 only): the pack and
         dispatch totals any engine may carry, and `stage_ms`, the same path
-        by stage, from engines with a stage clock (core/trace.py)."""
+        by stage, from engines with a stage clock (core/trace.py); and how
+        often each launch class engaged (`class_launches`) with the wide
+        class's patch slots and holes, from engines that count them."""
         out = {"pack_ms": 0.0, "pack_launches": 0.0,
                "dispatch_ms": 0.0, "dispatch_launches": 0.0,
-               "fetch_launches": 0.0, "pack_cpu_ms": 0.0}
+               "fetch_launches": 0.0, "pack_cpu_ms": 0.0,
+               "patch_slots": 0.0, "patch_holes": 0.0}
         stage_ms = dict.fromkeys(LAUNCH_STAGES, 0.0)
+        classes = dict.fromkeys(LAUNCH_CLASSES, 0.0)
         for lane in self.lanes:
             eng = lane.engine
+            for name, ct in getattr(eng, "class_launches", {}).items():
+                classes[name] += ct
+            for key in ("patch_slots", "patch_holes"):
+                out[key] += float(getattr(eng, key, 0.0))
             for key in ("pack_ms", "pack_launches", "dispatch_ms",
                         "dispatch_launches", "fetch_launches"):
                 out[key] += float(getattr(eng, f"host_{key}", 0.0))
@@ -287,6 +295,7 @@ class DevicePlane:
                     stage_ms[name] += ms
                 out["pack_cpu_ms"] += clock.cpu_ms["pack"]
         out["stage_ms"] = stage_ms
+        out["class_launches"] = classes
         return out
 
     def values(self) -> dict[str, float]:

@@ -144,21 +144,35 @@ def _range_args(shape, miss_k: int):
     )
 
 
-def test_range_aggregate(shape, chip_choices):
+# 8 = the narrow class every near-full range takes; 1024 = the 4096-key
+# registry's wide class (n // 4: a level range with its failing members
+# absent), a 131072-column key gather and a 1024-block tree sum
+@pytest.mark.parametrize("miss_k", [8, N_KEYS // 4])
+def test_range_aggregate(shape, chip_choices, miss_k):
     """The aggregation stage of the range launch at full width: prefix-table
-    gathers plus the 8-wide hole patch (point adds only, no pairing). The
-    bank is a jit argument, so a 2-key engine lowers the 4096-key program."""
+    gathers plus the miss_k-wide hole patch (point adds only, no pairing).
+    The bank is a jit argument, so a 2-key engine lowers the 4096-key
+    program."""
     from handel_tpu.models.bn254_jax import _named
 
     dev = _device(2)
-    fn = jax.jit(_named(partial(dev._range_aggregate, miss_k=8), "range_agg8"))
-    compiled = fn.lower(*_range_args(shape, 8), *_bank(shape, N_KEYS)).compile()
+    name = f"range_agg{miss_k}"
+    fn = jax.jit(_named(partial(dev._range_aggregate, miss_k=miss_k), name))
+    compiled = fn.lower(
+        *_range_args(shape, miss_k), *_bank(shape, N_KEYS)
+    ).compile()
     assert mosaic_calls(compiled) > 0
     # program and phase reach the compiled module: its name, and the scope
     # in the operations' metadata — the Mosaic calls' too
     text = compiled.as_text()
-    assert text.startswith("HloModule jit_range_agg8")
-    assert re.search(r'op_name="jit\(range_agg8\)/agg/[^"]*fp_mul_16x', text)
+    assert text.startswith(f"HloModule jit_{name}")
+    assert re.search(rf'op_name="jit\({name}\)/agg/[^"]*fp_mul_16x', text)
+    # the first stage of the patch's tree sum is the widest multiplication
+    # of the stage: half the patch x lanes x the stacked muls of a G2 add
+    if miss_k > 8:
+        widths = {int(w) for w in re.findall(r"%fp_mul_16x(\d+)", text)}
+        assert max(widths) % (miss_k // 2 * LANES) == 0, sorted(widths)
+    _report(name, compiled)
 
 
 def _report(name, compiled):

@@ -4,9 +4,10 @@ The device packer (`BN254Device._pack_requests`) builds every launch input —
 range bounds, missing-signer patch, dense mask, packed signature limbs —
 with array-at-once numpy ops over the batch. It must be BIT-IDENTICAL to
 the old per-candidate loop (`_pack_requests_loop`, kept as the oracle) for
-every signer-set shape: contiguous ranges, ranges with holes in both
-quantization classes, scattered sets past the MISS_CAP, empty bitsets,
-point-less signatures, and partial batches.
+every signer-set shape: contiguous ranges, ranges with holes in every
+quantization class (8, 64 and, where the registry has one, the wide class
+of n // 4), scattered sets past the widest patch, empty bitsets, point-less
+signatures, and partial batches.
 
 Fast tier: packing is pure host numpy — nothing here compiles a kernel.
 """
@@ -24,31 +25,44 @@ from handel_tpu.ops import bn254_ref as bn
 from handel_tpu.ops.fp import Field
 
 N = 130  # > MISS_CAP + 3 so the dense fallback class is reachable
+N_WIDE = 520  # n // 4 = 130 > MISS_CAP: a registry with a wide class
 C = 8
 
 
-@pytest.fixture(scope="module", params=["per_candidate", "rlc"])
+@pytest.fixture(scope="module", params=["per_candidate", "rlc", "wide"])
 def device(request):
     """Both batch-check modes (models/rlc.py): launch packing is shared
     between the per-candidate and RLC launch classes, so every equivalence
-    property below must hold identically under either device mode."""
+    property below must hold identically under either device mode. "wide"
+    is a per-candidate engine over a registry large enough for the third
+    range class (patch width n // 4); the other two keep today's ladder."""
+    n, mode = (N_WIDE, "per_candidate") if request.param == "wide" else (
+        N, request.param)
     rng = random.Random(11)
-    sks = [rng.randrange(1, 1 << 20) for _ in range(N)]
-    pks = [BN254PublicKey(p) for p in nat.g2_mul_batch([bn.G2_GEN] * N, sks)]
-    return BN254Device(pks, batch_size=C, batch_check=request.param)
+    sks = [rng.randrange(1, 1 << 20) for _ in range(n)]
+    pks = [BN254PublicKey(p) for p in nat.g2_mul_batch([bn.G2_GEN] * n, sks)]
+    return BN254Device(pks, batch_size=C, batch_check=mode)
 
 
-def _rand_request(rng, kind):
-    bs = BitSet(N)
+def _kinds(device):
+    """The request kinds a random batch draws from: one per launch class
+    the registry has, plus the two masked-lane shapes."""
+    wide = ["wide"] if device.patch_widths[-1] > device.MISS_CAP else []
+    return ["empty", "nosig", "range8", "range64", "dense"] + wide
+
+
+def _rand_request(rng, kind, n=N):
+    bs = BitSet(n)
     if kind == "empty":
         return (bs, BN254Signature(bn.G1_GEN))
     if kind == "nosig":
-        for i in rng.sample(range(N), 5):
+        for i in rng.sample(range(n), 5):
             bs.set(i, True)
         return (bs, object())  # no .point: lane must be masked out
-    max_holes = {"range8": 9, "range64": 60, "dense": None}[kind]
-    size = rng.randrange(1, N)
-    lo = rng.randrange(0, N - size + 1)
+    max_holes = {"range8": 9, "range64": 60, "wide": n // 4 + 1,
+                 "dense": None}[kind]
+    size = rng.randrange(1, n)
+    lo = rng.randrange(0, n - size + 1)
     n_holes = rng.randrange(0, size if max_holes is None else min(size, max_holes))
     holes = set(rng.sample(range(lo, lo + size), n_holes))
     holes.discard(lo)  # keep the hull anchored so hole counts stay exact
@@ -59,7 +73,7 @@ def _rand_request(rng, kind):
     return (bs, BN254Signature(bn.G1_GEN))
 
 
-def _mask_of(plan):
+def _mask_of(plan, n):
     """Dense candidate mask of a plan in (n, C) layout, whichever source
     the plan carries: the loop oracle's host-built `mask`, or the
     vectorized plan's packed `words` (the device-transfer source — the
@@ -69,13 +83,13 @@ def _mask_of(plan):
     bits = np.unpackbits(
         np.asarray(plan.words).view(np.uint8),
         axis=1,
-        count=N,
+        count=n,
         bitorder="little",
     ).view(np.bool_)
     return (bits & np.asarray(plan.valid)[:, None]).T
 
 
-def _assert_plans_equal(a, b, ctx):
+def _assert_plans_equal(a, b, ctx, n=N):
     assert a.kind == b.kind, ctx
     assert a.miss_k == b.miss_k, ctx
     for f in ("lo", "hi", "miss_idx", "miss_ok", "valid"):
@@ -86,7 +100,7 @@ def _assert_plans_equal(a, b, ctx):
             assert x.dtype == y.dtype, (ctx, f, x.dtype, y.dtype)
             assert x.shape == y.shape and (x == y).all(), (ctx, f)
     if a.kind == "dense":
-        ma, mb = _mask_of(a), _mask_of(b)
+        ma, mb = _mask_of(a, n), _mask_of(b, n)
         assert ma.shape == mb.shape and (ma == mb).all(), (ctx, "mask")
     for f in ("sig_x", "sig_y"):
         x, y = np.asarray(getattr(a, f)), np.asarray(getattr(b, f))
@@ -112,15 +126,19 @@ def test_pack_requests_matches_loop_property(device):
     """Random batches across all request shapes: the vectorized packer and
     the per-candidate loop must produce bit-identical device inputs."""
     rng = random.Random(23)
-    kinds = ["empty", "nosig", "range8", "range64", "dense"]
+    kinds = _kinds(device)
+    seen = set()
     for trial in range(120):
         reqs = [
-            _rand_request(rng, rng.choice(kinds))
+            _rand_request(rng, rng.choice(kinds), device.n)
             for _ in range(rng.randrange(1, C + 1))
         ]
         vec = _snap(device._pack_requests(reqs))
         loop = device._pack_requests_loop(reqs)
-        _assert_plans_equal(vec, loop, trial)
+        _assert_plans_equal(vec, loop, trial, device.n)
+        seen.add((vec.kind, vec.miss_k))
+    # every class of the registry's ladder was drawn
+    assert seen == {("range", k) for k in device.patch_widths} | {("dense", 0)}
 
 
 def test_pack_requests_rotation_boundary_property(device):
@@ -132,12 +150,13 @@ def test_pack_requests_rotation_boundary_property(device):
     k is checked against the oracle after pack k+1 ran, unsnapshotted, so
     any buffer sharing between adjacent launches would corrupt it."""
     rng = random.Random(41)
-    kinds = ["empty", "nosig", "range8", "range64", "dense"]
+    kinds = _kinds(device)
+    n = device.n
     assert device.stage_sets >= 2  # the contract under test
     for trial in range(25):
         streams = [
             [
-                _rand_request(rng, rng.choice(kinds))
+                _rand_request(rng, rng.choice(kinds), n)
                 for _ in range(rng.randrange(1, C + 1))
             ]
             for _ in range(3 + trial % 3)  # >= 3 consecutive launches
@@ -151,21 +170,26 @@ def test_pack_requests_rotation_boundary_property(device):
                     _snap(prev[1]),
                     device._pack_requests_loop(prev[0]),
                     trial,
+                    n,
                 )
             prev = (reqs, plan)
         _assert_plans_equal(
-            _snap(prev[1]), device._pack_requests_loop(prev[0]), trial
+            _snap(prev[1]), device._pack_requests_loop(prev[0]), trial, n
         )
 
 
 def test_pack_requests_class_selection(device):
-    """The two range quantization classes and the dense fallback trigger at
-    the same thresholds as the old loop: <=8 holes -> miss_k=8, <=64 ->
-    miss_k=64, >64 -> dense."""
+    """The class ladder, in the vectorised packer and in the loop alike:
+    <=8 holes -> miss_k=8, <=64 -> miss_k=64, then — only for a registry
+    whose n // 4 is over MISS_CAP — <= n // 4 -> the wide class, and dense
+    past the widest patch. Registries of 256 keys or fewer keep the
+    two-class ladder: 65 holes are dense there. `verify_dense` still serves
+    every hull with more than n // 4 holes."""
     sig = BN254Signature(bn.G1_GEN)
+    n = device.n
 
     def req_with_holes(n_holes):
-        bs = BitSet(N)
+        bs = BitSet(n)
         width = n_holes + 2
         for i in range(width):
             bs.set(i, True)
@@ -173,15 +197,42 @@ def test_pack_requests_class_selection(device):
             bs.set(i, False)
         return (bs, sig)
 
-    for n_holes, kind, miss_k in ((0, "range", 8), (8, "range", 8),
-                                  (9, "range", 64), (64, "range", 64),
-                                  (65, "dense", 0)):
-        plan = device._pack_requests([req_with_holes(n_holes)])
-        assert (plan.kind, plan.miss_k) == (kind, miss_k), n_holes
+    ladder = [(0, "range", 8), (8, "range", 8), (9, "range", 64),
+              (64, "range", 64)]
+    if n == N_WIDE:
+        assert device.patch_widths == (8, 64, 130)
+        ladder += [(65, "range", 130), (130, "range", 130), (131, "dense", 0),
+                   (n - 2, "dense", 0)]
+    else:
+        assert device.patch_widths == (8, 64)
+        ladder += [(65, "dense", 0), (n - 2, "dense", 0)]
+    for n_holes, kind, miss_k in ladder:
+        for pack in (device._pack_requests, device._pack_requests_loop):
+            plan = pack([req_with_holes(n_holes)])
+            assert (plan.kind, plan.miss_k) == (kind, miss_k), n_holes
+            if kind == "range":
+                assert np.asarray(plan.miss_idx).shape == (miss_k, C)
+                assert int(np.asarray(plan.miss_ok).sum()) == n_holes
+
+
+@pytest.mark.parametrize("n,widths", [
+    (8, (8, 64)), (256, (8, 64)), (259, (8, 64)), (260, (8, 64, 65)),
+    (4096, (8, 64, 1024)),
+])
+def test_patch_widths_follow_the_registry(n, widths):
+    """The wide width is read off the registry size (n // 4, only where
+    that is over MISS_CAP) — not an option, not a per-cell width. Checked
+    on the ladder rule itself: no engine, no keys."""
+    eng = BN254Device.__new__(BN254Device)
+    eng.n = n
+    assert eng.patch_widths == widths
+    assert [eng._patch_width(h) for h in (0, 8, 9, 64)] == [8, 8, 64, 64]
+    wide = widths[-1]
+    assert eng._patch_width(wide) == wide and eng._patch_width(wide + 1) == 0
 
 
 def test_pack_requests_rejects_wrong_length(device):
-    bs = BitSet(N + 1)
+    bs = BitSet(device.n + 1)
     bs.set(0, True)
     with pytest.raises(ValueError, match="bitset length"):
         device._pack_requests([(bs, BN254Signature(bn.G1_GEN))])
@@ -220,7 +271,7 @@ def test_batch_verify_bounds_dispatch_window(device, monkeypatch):
 
     monkeypatch.setattr(device, "dispatch", fake_dispatch)
     monkeypatch.setattr(device, "fetch", fake_fetch)
-    bs = BitSet(N)
+    bs = BitSet(device.n)
     bs.set(0, True)
     reqs = [(bs, BN254Signature(bn.G1_GEN))] * (C * 12)
     out = device.batch_verify(b"m", reqs)
@@ -240,7 +291,7 @@ def test_batch_check_mode_validated_and_routed(device):
         )
     if device.batch_check != "rlc":
         return
-    bs = BitSet(N)  # empty bitset: candidate invalid, nothing pre-launched
+    bs = BitSet(device.n)  # empty bitset: candidate invalid, nothing pre-launched
     handle = device.dispatch(b"m", [(bs, BN254Signature(bn.G1_GEN))])
     assert handle[0] == "rlc" and handle[3] is None
     assert device.fetch(handle) == [False]

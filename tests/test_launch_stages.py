@@ -42,15 +42,15 @@ _echo = jax.jit(lambda valid: jnp.logical_and(valid, True))
 _accept = jax.jit(lambda: jnp.ones((1,), bool))
 
 
-def _pubkeys():
+def _pubkeys(n=N):
     rng = random.Random(5)
-    sks = [rng.randrange(1, 1 << 20) for _ in range(N)]
-    return [BN254PublicKey(p) for p in nat.g2_mul_batch([bn.G2_GEN] * N, sks)]
+    sks = [rng.randrange(1, 1 << 20) for _ in range(n)]
+    return [BN254PublicKey(p) for p in nat.g2_mul_batch([bn.G2_GEN] * n, sks)]
 
 
-def _device(**kw) -> BN254Device:
+def _device(n=N, **kw) -> BN254Device:
     """A small engine whose launches run an echo instead of a pairing."""
-    dev = BN254Device(_pubkeys(), batch_size=C, **kw)
+    dev = BN254Device(_pubkeys(n), batch_size=C, **kw)
     dev._run_plan = lambda plan, staged, h_x, h_y: _echo(staged[-1])
     dev._rlc_msm_kernel = lambda kind, miss_k, G: (lambda *args: ())
     dev._rlc_check_kernel = lambda G: (lambda *args: _accept())
@@ -116,6 +116,59 @@ def test_stage_counters_add_up(how):
     dev.reset_host_counters()
     assert dev.host_pack_ms == 0.0 and dev.host_fetch_launches == 0
     assert dev.launch_seq(dev.dispatch(b"m", _requests(rng))) == launches
+
+
+CLASS_COUNTERS = ("launchesRange8", "launchesRange64", "launchesRangeWide",
+                  "launchesDense", "patchSlots", "patchHoles")
+
+
+@pytest.mark.parametrize("how", ["dispatch", "dispatch_multi"])
+def test_class_counters_count_each_launch_once(how):
+    """How often each launch class engages, counted where every dispatch
+    path passes (`_launch`) and summed into the service's values(): one
+    launch of each class of a 520-key registry (patch widths 8, 64 and
+    n // 4 = 130; dense past that), and for the wide one its patch slots
+    (width x valid lanes) and the holes really patched."""
+    n = 520
+    dev = _device(n)
+    assert dev.patch_widths == (8, 64, 130)
+    ran = []
+    echo = dev._run_plan
+    dev._run_plan = lambda plan, *rest: (
+        ran.append((plan.kind, plan.miss_k)), echo(plan, *rest))[1]
+    svc = BatchVerifierService(dev)  # values() only: never started
+    sig = BN254Signature(bn.G1_GEN)
+
+    def candidate(lo, size, n_holes):
+        bs = BitSet(n)
+        bs.set_range(lo, lo + size)
+        for i in range(lo + 1, lo + 1 + n_holes):
+            bs.set(i, False)
+        return (bs, sig)
+
+    zero = svc.values()
+    assert all(zero[k] == 0.0 for k in CLASS_COUNTERS)
+    launches = {
+        "launchesRange8": [candidate(0, 260, 0), candidate(260, 130, 8)],
+        "launchesRange64": [candidate(0, 260, 9), candidate(260, 130, 64)],
+        # the launch's class is its LARGEST hole count; an empty bitset is
+        # an invalid lane and brings no patch slots
+        "launchesRangeWide": [candidate(0, 260, 70), candidate(260, 130, 100),
+                              candidate(390, 65, 3), (BitSet(n), sig)],
+        "launchesDense": [candidate(0, 260, 131), candidate(0, 520, 4)],
+    }
+    done = 0
+    for key, reqs in launches.items():
+        _launch(dev, how, reqs)
+        done += 1
+        v = svc.values()
+        assert v[key] == 1.0, key
+        assert sum(v[k] for k in CLASS_COUNTERS[:4]) == done
+        assert v["hostDispatchLaunches"] == done
+    assert ran == [("range", 8), ("range", 64), ("range", 130), ("dense", 0)]
+    assert v["patchSlots"] == 130 * 3 and v["patchHoles"] == 70 + 100 + 3
+    dev.reset_host_counters()
+    assert all(svc.values()[k] == 0.0 for k in CLASS_COUNTERS)
 
 
 def test_second_use_of_a_staging_set_waits_on_its_fence():
