@@ -833,9 +833,9 @@ def build_problem(
     range-kernel argument tuple (lo, hi, miss_idx, miss_ok, sig, h, valid)
     plus the keypair material.
 
-    Curve-parametric (scripts/bench_bls12.py reuses it for BLS12-381):
-    `ref` is the scalar-oracle module (G1_GEN/G2_GEN/R) and the *_mul_batch
-    hooks do host keygen — defaults are BN254 through the native C++ path.
+    Curve-parametric: `ref` is the scalar-oracle module (G1_GEN/G2_GEN/R)
+    and the *_mul_batch hooks do host keygen — defaults are BN254 through
+    the native C++ path.
     """
     import jax.numpy as jnp
     import numpy as np

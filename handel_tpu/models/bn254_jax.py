@@ -322,6 +322,11 @@ class BN254Device:
         self.registry_staged_ms = 0.0
 
     @property
+    def field_limbs(self) -> int:
+        """16-bit limbs of the base field (the `fieldLimbs` gauge)."""
+        return self.curves.F.nlimbs
+
+    @property
     def host_pack_ms(self) -> float:
         ms = self.stage_clock.ms
         return ms["fence_wait"] + ms["pack"]
@@ -346,15 +351,7 @@ class BN254Device:
         """Prefix table over a registry bank (default: the active one).
         `stage_registry` passes the STAGED bank so the scan runs off the
         launch critical path."""
-        g2 = self.curves.g2
-
-        @jax.jit  # one executable for the whole scan + batch affine convert
-        def prefix_table(reg_x, reg_y):
-            P = g2.from_affine(reg_x, reg_y)
-            pref = g2.prefix_scan(P)  # inclusive prefix sums, projective
-            return g2.to_affine(pref)
-
-        x, y, inf = prefix_table(
+        x, y, inf = self._prefix_table_kernel()(
             self._reg_x if reg_x is None else reg_x,
             self._reg_y if reg_y is None else reg_y,
         )
@@ -364,6 +361,17 @@ class BN254Device:
             (pad(y[0]), pad(y[1])),
             jnp.pad(inf, (1, 0), constant_values=True),
         )
+
+    def _prefix_table_kernel(self):
+        """One executable for the whole scan + batch affine convert."""
+        g2 = self.curves.g2
+
+        def prefix_table(reg_x, reg_y):
+            P = g2.from_affine(reg_x, reg_y)
+            pref = g2.prefix_scan(P)  # inclusive prefix sums, projective
+            return g2.to_affine(pref)
+
+        return jax.jit(prefix_table)
 
     # -- epoch-based registry rotation (lifecycle/epoch.py) ----------------
 

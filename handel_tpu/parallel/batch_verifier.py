@@ -1126,6 +1126,7 @@ class BatchVerifierService:
             "hostDispatchMsPerLaunch",
             "devicesTotal",
             "devicesAvailable",
+            "fieldLimbs",
             "meshLanes",
             "meshLanesAvailable",
             "checkMode",

@@ -322,6 +322,13 @@ class DevicePlane:
             ),
             "devicesTotal": float(len(self.lanes)),
             "devicesAvailable": float(len(self.allowed())),
+            # which base field the engines compute in: 16-bit limbs of its
+            # modulus (16 = BN254, 24 = BLS12-381; 0 for host stubs), so a
+            # reader of the counters need not parse kernel names
+            "fieldLimbs": float(max(
+                (getattr(l.engine, "field_limbs", 0) for l in self.lanes),
+                default=0,
+            )),
             "schedPicks": float(self.sched_picks),
             "schedIdleViolations": float(self.idle_violations),
             "lanesAdded": float(self.lanes_added),

@@ -10,7 +10,12 @@ Where the reference offers two interchangeable BN256 backends
 curves behind one Constructor registry (simul/lib/config.go:211-225).
 """
 
+import asyncio
+import os
 import random
+import signal
+import sys
+import time
 
 import jax
 import numpy as np
@@ -144,3 +149,83 @@ def test_scheme_registry_dispatch():
     scheme = new_scheme("bls12-381-jax", batch_size=4)
     sk, pk = scheme.keygen(0)
     assert scheme.unmarshal_public(pk.marshal()).point == pk.point
+
+
+# -- ONE real launch through the served path -----------------------------------
+# `BatchVerifierService` over a `BLS12381Device` (16 keys, 4 lanes): a range
+# launch with holes and one forged candidate returns the plain reference's
+# verdicts — the benchmark's own reference (benchmark/reference/bls12_381.py),
+# which makes the keys and the signatures, as in the cell
+# `bls12-381-4096.closed256`. Slow tier: the launch's cold compile took 273 s
+# on the sandbox's CPU (PR 28), over the 180 s a tier-1 test may take alone.
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "benchmark")
+SERVED_N, SERVED_LANES = 16, 4
+SERVED_MSG = b"handel-tpu benchmark round"
+LIMIT_S = 900  # the test's own time limit: one cold compile of the launch
+
+
+@pytest.fixture
+def time_limit():
+    """Fail, do not hang: SIGALRM in the test's own process."""
+    def over(signum, frame):
+        raise TimeoutError(f"the launch did not end inside {LIMIT_S} s")
+
+    before = signal.signal(signal.SIGALRM, over)
+    signal.alarm(LIMIT_S)
+    yield
+    signal.alarm(0)
+    signal.signal(signal.SIGALRM, before)
+
+
+def test_one_served_launch_returns_the_reference_verdicts(time_limit):
+    from handel_tpu.core.bitset import BitSet
+    from handel_tpu.models.bls12_381 import BLS12381PublicKey, BLS12381Signature
+    from handel_tpu.models.registry import new_scheme
+    from handel_tpu.parallel.batch_verifier import BatchVerifierService
+
+    sys.path.insert(0, BENCH)
+    try:
+        from reference import bls12_381 as ref
+    finally:
+        sys.path.remove(BENCH)
+    sks, points = ref.keygen(random.Random(2800000003), SERVED_N)
+    # level ranges of the 16-id tree, holes inside; candidate 2 is forged
+    cands = [(0, 8, (2, 5)), (8, 8, ()), (4, 4, (6,)), (0, 16, (1, 9, 14))]
+    signers = [[i for i in range(lo, lo + size) if i not in holes]
+               for lo, size, holes in cands]
+    secrets = [sum(sks[i] for i in s) % ref.R for s in signers]
+    secrets[2] = (secrets[2] + 1) % ref.R
+    sigs = ref.sign_batch(SERVED_MSG, secrets)
+    want = [ref.verify(SERVED_MSG, points, s, sig) for s, sig in zip(signers, sigs)]
+    assert want == [True, True, False, True]
+
+    requests = []
+    for (lo, size, holes), sig in zip(cands, sigs):
+        bs = BitSet(SERVED_N)
+        bs.set_range(lo, lo + size)
+        for i in holes:
+            bs.set(i, False)
+        requests.append((bs, BLS12381Signature(sig)))
+    pubkeys = [BLS12381PublicKey(p) for p in points]
+    scheme = new_scheme("bls12-381-jax", batch_size=SERVED_LANES, warmup=False)
+    device = scheme.constructor.prepare(pubkeys)
+    assert device.field_limbs == 24
+
+    async def go():
+        svc = BatchVerifierService(device, fallback=None)
+        try:
+            got = await svc.verify(SERVED_MSG, pubkeys, requests, session="s")
+            return got, svc.values()
+        finally:
+            svc.stop()
+
+    t0 = time.perf_counter()
+    got, v = asyncio.run(go())
+    print(f"served launch: {time.perf_counter() - t0:.0f} s")
+    assert got == want
+    assert v["fieldLimbs"] == 24.0
+    assert v["launchesRange8"] == v["verifierLaunches"] == 1.0
+    assert v["verifierCandidates"] == 4.0
+    assert v["failoverBatches"] == v["deviceRetryCt"] == 0.0

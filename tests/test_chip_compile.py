@@ -17,6 +17,7 @@ the test's own process, and all such tests live in this one file.
 """
 
 import re
+import time
 from functools import partial
 
 import jax
@@ -99,13 +100,15 @@ def test_cios_mul_bn254(shape, chip_choices, width):
     assert f"%fp_mul_16x{width}" in compiled.as_text()
 
 
-@pytest.mark.parametrize("width", [128, 6912])
+# 13824 = the widest mul of the BLS12-381 range launch's pairing tail too
+@pytest.mark.parametrize("width", [128, 6912, 13824])
 def test_cios_mul_bls12_381(shape, chip_choices, width):
     F = fp.Field(bls.P)
     assert F.use_pallas and F.nlimbs == 24
     x = shape((F.nlimbs, width), U32)
     compiled = jax.jit(F.mul).lower(x, x).compile()
     assert mosaic_calls(compiled) == 1
+    assert f"%fp_mul_24x{width}" in compiled.as_text()
 
 
 def test_rns_resident_mul(shape, chip_choices):
@@ -119,18 +122,22 @@ def test_rns_resident_mul(shape, chip_choices):
     assert f"%rns_mul_{F.k_all}x6912" in compiled.as_text()
 
 
-def _device(n_keys: int):
-    from handel_tpu.models.bn254 import BN254PublicKey
-    from handel_tpu.models.bn254_jax import BN254Device
+def _device(n_keys: int, curve: str = "bn254"):
+    if curve == "bls12_381":
+        from handel_tpu.models.bls12_381 import BLS12381PublicKey as Key
+        from handel_tpu.models.bls12_381_jax import BLS12381Device as Device
+    else:
+        from handel_tpu.models.bn254 import BN254PublicKey as Key
+        from handel_tpu.models.bn254_jax import BN254Device as Device
 
-    dev = BN254Device([BN254PublicKey(bn.G2_GEN)] * n_keys, batch_size=LANES)
+    dev = Device([Key(Device.ref.G2_GEN)] * n_keys, batch_size=LANES)
     assert dev.curves.F.use_pallas and fp.default_pow_window() == 4
     return dev
 
 
-def _bank(shape, n_keys: int):
+def _bank(shape, n_keys: int, nlimbs: int = 16):
     """Shapes of a registry bank and its prefix table (jit arguments)."""
-    f2 = lambda n: (shape((16, n), U32), shape((16, n), U32))
+    f2 = lambda n: (shape((nlimbs, n), U32), shape((nlimbs, n), U32))
     prefix = (f2(n_keys + 1), f2(n_keys + 1), shape((n_keys + 1,), BOOL))
     return prefix, f2(n_keys), f2(n_keys)
 
@@ -210,6 +217,38 @@ def test_full_range_launch(shape, chip_choices):
     _report("range launch", compiled)
     assert mosaic_calls(compiled) > 100
     _assert_phases(compiled)
+
+
+# The 24-limb launch the cell `bls12-381-4096.closed256` runs: the prefix
+# table's scan and the range launch over (24, N) banks. ~9 min here.
+@pytest.mark.slow
+def test_full_range_launch_bls12_381(shape, chip_choices):
+    dev = _device(2, "bls12_381")
+    nl = dev.curves.F.nlimbs
+    assert nl == 24
+    _, reg_x, reg_y = _bank(shape, N_KEYS, nl)
+    t0 = time.perf_counter()
+    table = dev._prefix_table_kernel().lower(reg_x, reg_y).compile()
+    _report(f"bls12-381 prefix table ({time.perf_counter() - t0:.0f} s)", table)
+    assert "%fp_mul_24x" in table.as_text()
+    sig = shape((nl, LANES), U32)
+    h = shape((nl, 1), U32)
+    fn = jax.jit(
+        partial(dev._verify_batch_range, miss_k=8),
+        donate_argnums=(0, 1, 2, 3, 4, 5, 8),
+    )
+    t0 = time.perf_counter()
+    compiled = fn.lower(
+        *_range_args(shape, 8), sig, sig, h, h, shape((LANES,), BOOL),
+        *_bank(shape, N_KEYS, nl),
+    ).compile()
+    _report(f"bls12-381 range launch ({time.perf_counter() - t0:.0f} s)", compiled)
+    assert mosaic_calls(compiled) > 100
+    _assert_phases(compiled)
+    text = compiled.as_text()
+    # every Mosaic call of the launch is the 24-limb multiplication
+    assert not re.search(r"%fp_mul_(?!24x)", text)
+    assert "%fp_mul_24x13824" in text
 
 
 @pytest.mark.slow

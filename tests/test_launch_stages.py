@@ -11,6 +11,10 @@ Fast-tier by design, like tests/test_device_residency.py: the engines here
 pack, stage and fetch for real, but the pairing kernels (minutes of XLA on a
 CPU) are replaced by a jitted echo of the `valid` mask — the lifecycle is
 what is under test, not the verdicts.
+
+Every engine test runs once per device class (`curve`): BN254Device and its
+BLS12-381 binding share the launch engine, so each emits the same stages,
+counters, `seq` and names — over 16 limbs or 24 (`fieldLimbs`).
 """
 
 import asyncio
@@ -25,8 +29,11 @@ import pytest
 from handel_tpu import native as nat
 from handel_tpu.core.bitset import BitSet
 from handel_tpu.core.trace import LAUNCH_STAGES, FlightRecorder, StageClock
+from handel_tpu.models.bls12_381 import BLS12381PublicKey, BLS12381Signature
+from handel_tpu.models.bls12_381_jax import BLS12381Device
 from handel_tpu.models.bn254 import BN254PublicKey, BN254Signature
 from handel_tpu.models.bn254_jax import BN254Device, _named
+from handel_tpu.ops import bls12_381_ref as bls
 from handel_tpu.ops import bn254_ref as bn
 from handel_tpu.parallel.batch_verifier import BatchVerifierService
 
@@ -42,24 +49,51 @@ _echo = jax.jit(lambda valid: jnp.logical_and(valid, True))
 _accept = jax.jit(lambda: jnp.ones((1,), bool))
 
 
-def _pubkeys(n=N):
-    rng = random.Random(5)
-    sks = [rng.randrange(1, 1 << 20) for _ in range(n)]
-    return [BN254PublicKey(p) for p in nat.g2_mul_batch([bn.G2_GEN] * n, sks)]
+class _BN254:
+    Device, limbs = BN254Device, 16
+    sig = BN254Signature(bn.G1_GEN)
+
+    @staticmethod
+    def pubkeys(n):
+        rng = random.Random(5)
+        sks = [rng.randrange(1, 1 << 20) for _ in range(n)]
+        return [BN254PublicKey(p)
+                for p in nat.g2_mul_batch([bn.G2_GEN] * n, sks)]
 
 
-def _device(n=N, **kw) -> BN254Device:
+class _BLS12381:
+    Device, limbs = BLS12381Device, 24
+    sig = BLS12381Signature(bls.G1_GEN)
+
+    @staticmethod
+    def pubkeys(n):
+        # k B2, (k + 1) B2, ...: valid keys by n additions (the host scheme
+        # of this curve is pure Python: 25 ms a scalar multiplication)
+        pt = bls.g2_mul(bls.G2_GEN, random.Random(5).randrange(1, 1 << 20))
+        out = []
+        for _ in range(n):
+            out.append(BLS12381PublicKey(pt))
+            pt = bls.g2_add(pt, bls.G2_GEN)
+        return out
+
+
+@pytest.fixture(params=[_BN254, _BLS12381], ids=["bn254", "bls12_381"])
+def curve(request):
+    return request.param
+
+
+def _device(curve, n=N, **kw) -> BN254Device:
     """A small engine whose launches run an echo instead of a pairing."""
-    dev = BN254Device(_pubkeys(n), batch_size=C, **kw)
+    dev = curve.Device(curve.pubkeys(n), batch_size=C, **kw)
     dev._run_plan = lambda plan, staged, h_x, h_y: _echo(staged[-1])
     dev._rlc_msm_kernel = lambda kind, miss_k, G: (lambda *args: ())
     dev._rlc_check_kernel = lambda G: (lambda *args: _accept())
     return dev
 
 
-def _requests(rng, k=C):
+def _requests(rng, curve, k=C):
     """k distinct range candidates (distinct content: no dedup hit)."""
-    sig = BN254Signature(bn.G1_GEN)
+    sig = curve.sig
     seen, reqs = set(), []
     while len(reqs) < k:
         size = rng.randrange(2, N)
@@ -85,15 +119,18 @@ def _launch(dev, how: str, reqs):
 
 
 @pytest.mark.parametrize("how", ["dispatch", "dispatch_multi", "rlc"])
-def test_stage_counters_add_up(how):
+def test_stage_counters_add_up(curve, how):
     launches = 5
-    dev = _device(batch_check="rlc", rlc_rng=random.Random(1)) \
-        if how == "rlc" else _device()
+    dev = _device(curve, batch_check="rlc", rlc_rng=random.Random(1)) \
+        if how == "rlc" else _device(curve)
     svc = BatchVerifierService(dev)  # values() only: never started
+    # which field the process serves, without parsing a kernel's name
+    assert svc.values()["fieldLimbs"] == curve.limbs == dev.field_limbs
+    assert "fieldLimbs" in svc.gauge_keys()
     rng = random.Random(7)
     before = svc.values()
     for _ in range(launches):
-        assert _launch(dev, how, _requests(rng)) == [True] * C
+        assert _launch(dev, how, _requests(rng, curve)) == [True] * C
         now = svc.values()
         for key in STAGE_COUNTERS:
             assert now[key] >= before[key], key  # monotone
@@ -115,7 +152,7 @@ def test_stage_counters_add_up(how):
     assert dev._next_seq == launches
     dev.reset_host_counters()
     assert dev.host_pack_ms == 0.0 and dev.host_fetch_launches == 0
-    assert dev.launch_seq(dev.dispatch(b"m", _requests(rng))) == launches
+    assert dev.launch_seq(dev.dispatch(b"m", _requests(rng, curve))) == launches
 
 
 CLASS_COUNTERS = ("launchesRange8", "launchesRange64", "launchesRangeWide",
@@ -123,21 +160,21 @@ CLASS_COUNTERS = ("launchesRange8", "launchesRange64", "launchesRangeWide",
 
 
 @pytest.mark.parametrize("how", ["dispatch", "dispatch_multi"])
-def test_class_counters_count_each_launch_once(how):
+def test_class_counters_count_each_launch_once(curve, how):
     """How often each launch class engages, counted where every dispatch
     path passes (`_launch`) and summed into the service's values(): one
     launch of each class of a 520-key registry (patch widths 8, 64 and
     n // 4 = 130; dense past that), and for the wide one its patch slots
     (width x valid lanes) and the holes really patched."""
     n = 520
-    dev = _device(n)
+    dev = _device(curve, n)
     assert dev.patch_widths == (8, 64, 130)
     ran = []
     echo = dev._run_plan
     dev._run_plan = lambda plan, *rest: (
         ran.append((plan.kind, plan.miss_k)), echo(plan, *rest))[1]
     svc = BatchVerifierService(dev)  # values() only: never started
-    sig = BN254Signature(bn.G1_GEN)
+    sig = curve.sig
 
     def candidate(lo, size, n_holes):
         bs = BitSet(n)
@@ -171,12 +208,12 @@ def test_class_counters_count_each_launch_once(how):
     assert all(svc.values()[k] == 0.0 for k in CLASS_COUNTERS)
 
 
-def test_second_use_of_a_staging_set_waits_on_its_fence():
+def test_second_use_of_a_staging_set_waits_on_its_fence(curve):
     """fence_wait is the block on the launch that last read the staging set:
     with two sets, the third launch's fence is the first launch's verdicts."""
-    dev = _device()
+    dev = _device(curve)
     rng = random.Random(3)
-    handles = [dev.dispatch(b"m", _requests(rng)) for _ in range(3)]
+    handles = [dev.dispatch(b"m", _requests(rng, curve)) for _ in range(3)]
     assert [dev.launch_seq(h) for h in handles] == [0, 1, 2]
     assert dev._stage[dev._stage_idx].fence is handles[2][0]
     for h in handles:
@@ -187,8 +224,8 @@ def test_second_use_of_a_staging_set_waits_on_its_fence():
 # -- (b) one seq through the engine's spans and the service's ----------------
 
 
-def test_launch_spans_share_seq_in_order_per_lane():
-    dev = _device()
+def test_launch_spans_share_seq_in_order_per_lane(curve):
+    dev = _device(curve)
     rec = FlightRecorder()
     rng = random.Random(11)
     launches = 3
@@ -197,7 +234,8 @@ def test_launch_spans_share_seq_in_order_per_lane():
         svc = BatchVerifierService(dev, max_delay_ms=1.0, recorder=rec)
         try:
             for _ in range(launches):
-                got = await svc.verify(b"m", None, _requests(rng), session="s")
+                got = await svc.verify(
+                    b"m", None, _requests(rng, curve), session="s")
                 assert got == [True] * C
         finally:
             svc.stop()
@@ -249,7 +287,8 @@ def test_stub_engine_launches_read_seq_none():
     async def go():
         svc = BatchVerifierService(Stub(), max_delay_ms=1.0, recorder=rec)
         try:
-            return await svc.verify(b"m", None, _requests(random.Random(2)))
+            return await svc.verify(
+                b"m", None, _requests(random.Random(2), _BN254))
         finally:
             svc.stop()
 
@@ -264,20 +303,21 @@ def test_stub_engine_launches_read_seq_none():
 # -- (c) the same stages on the profiler's host plane ------------------------
 
 
-def test_profiler_annotations_carry_seq(tmp_path):
+def test_profiler_annotations_carry_seq(curve, tmp_path):
     from jax.profiler import ProfileData
 
-    dev = _device()
+    dev = _device(curve)
     dev.stage_clock.bind(None, lane=3, tid=0)
     rng = random.Random(13)
-    _launch(dev, "dispatch", _requests(rng))  # warm the echo; seq 0 untraced
+    # warm the echo; seq 0 untraced
+    _launch(dev, "dispatch", _requests(rng, curve))
     dev._next_seq = 0
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
         for _ in range(3):
-            _launch(dev, "dispatch", _requests(rng))
+            _launch(dev, "dispatch", _requests(rng, curve))
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(
@@ -342,11 +382,12 @@ def test_stage_clock_without_a_listener_calls_nothing():
 # -- (f) names on the device side ---------------------------------------------
 
 
-def test_jitted_programs_have_stable_names():
-    dev = _device(batch_check="rlc")
+def test_jitted_programs_have_stable_names(curve):
+    dev = _device(curve, batch_check="rlc")
     assert dev._kernel.__name__ == "verify_dense"
     assert dev._combine_kernel(4).__name__ == "combine4"
-    real = BN254Device(_pubkeys(), batch_size=C, batch_check="rlc")
+    assert dev._prefix_table_kernel().__name__ == "prefix_table"
+    real = curve.Device(curve.pubkeys(N), batch_size=C, batch_check="rlc")
     assert real._rlc_check_kernel(2).__name__ == "rlc_check2"
     jitted = lambda fn: fn.__defaults__[0]  # the bank-injection wrappers
     assert jitted(real._rlc_msm_kernel("dense", 0, 1)).__name__ == "rlc_msm_dense"
@@ -360,11 +401,11 @@ def test_jitted_programs_have_stable_names():
     assert "jit_verify_range8" in fn.lower(1.0).as_text()
 
 
-def test_launch_phases_are_named_scopes():
+def test_launch_phases_are_named_scopes(curve):
     """agg / to_affine / miller_loop / final_exp reach the lowered program
     as name-stack metadata (the aggregation stage alone: seconds)."""
-    dev = BN254Device(_pubkeys(), batch_size=C)
-    plan = dev._pack_requests(_requests(random.Random(17)))
+    dev = curve.Device(curve.pubkeys(N), batch_size=C)
+    plan = dev._pack_requests(_requests(random.Random(17), curve))
     args = dev._stage_plan(plan)[:4]
     jitted = dev._range_agg_kernel(plan.miss_k).__defaults__[0]
     text = jitted.lower(
