@@ -304,8 +304,9 @@ class BN254Device:
         # `_launch` and `_pull` are the only places a stage is timed.
         self.stage_clock = StageClock()
         # ... beside them the launch counts: per stage side, per launch
-        # class (`_count_class`), and how full the wide class's patch runs
-        # (slots = wide x valid lanes, holes = the slots that carry a hole)
+        # class (`_count_class`), how full the wide class's patch runs
+        # (slots = wide x valid lanes, holes = the slots that carry a hole),
+        # and the Miller loop's steps with those whose addition executes
         self.reset_host_counters()
         # launches are numbered per engine, from 0, in dispatch order; the
         # handle carries the number to `fetch`, and the service reads it
@@ -1147,6 +1148,8 @@ class BN254Device:
         self.class_launches = dict.fromkeys(LAUNCH_CLASSES, 0)
         self.patch_slots = 0
         self.patch_holes = 0
+        self.miller_steps = 0
+        self.miller_add_steps = 0
         self.rlc_stats = rlc.RlcStats()
 
     # widest NARROW missing-signer patch: a launch whose largest hole count
@@ -1173,7 +1176,11 @@ class BN254Device:
         return next((k for k in self.patch_widths if max_holes <= k), 0)
 
     def _count_class(self, plan) -> None:
-        """One launch of `plan`'s class (see `class_launches`)."""
+        """One launch of `plan`'s class (see `class_launches`), and the
+        Miller-loop steps its program runs, as the pairing that built the
+        program counts them."""
+        self.miller_steps += self.pairing.miller_steps
+        self.miller_add_steps += self.pairing.miller_add_steps
         if plan.kind == "dense":
             name = "dense"
         elif plan.miss_k > self.MISS_CAP:

@@ -131,61 +131,97 @@ def device_platform() -> str:
 
 
 def default_pow_window() -> int:
-    """Platform-aware pow strategy: 4-bit windows on the TPU (~3x fewer
-    executed muls per chain), plain bit scan on XLA:CPU. The windowed form
-    builds a 15-entry table plus a gather-inside-scan at EVERY pow site, and
+    """The widest digit a pow chain may use on this platform: 4 bits on the
+    TPU, 1 (the plain bit scan) on XLA:CPU. The windowed form builds a
+    14-multiplication table plus a gather-inside-scan at EVERY pow site, and
     the CPU backend — where only compile time matters (virtual-mesh dryruns,
     CI) — pays for that in compile seconds multiplied across the staged
     sharded executables. The bit scan compiles to the smallest graph; the
-    executed-mul count it wastes is irrelevant off-chip."""
+    multiplications it executes beyond a window's are irrelevant off-chip.
+    Whether a chain on the TPU takes the window is `pow_window`'s call."""
     return 4 if device_platform() == "tpu" else 1
+
+
+def bit_runs(bits) -> tuple[list[int], int]:
+    """A public bit string as runs: for each set bit the number of steps up
+    to and including it since the previous set bit, and the steps after the
+    last one. A loop over the runs does the per-step work in an inner loop
+    and the per-set-bit work once a run, so no step computes what its bit
+    would discard (the Miller loop's addition, a pow chain's multiply)."""
+    runs, k = [], 0
+    for b in bits:
+        k += 1
+        if b:
+            runs.append(k)
+            k = 0
+    return runs, k
+
+
+def pow_chain_muls(e: int, window: int) -> int:
+    """Multiplications `windowed_pow` executes for the public exponent `e`
+    beyond its squarings. The bit scan multiplies on the set bits after the
+    top one: popcount(e) - 1. A w-bit window builds its table (2^w - 2) and
+    multiplies on every digit step after the first."""
+    digits = windowed_pow_digits(e, window) if window > 1 else None
+    if digits is None:
+        return bin(e).count("1") - 1
+    return 2**window - 2 + len(digits) - 1
+
+
+def pow_window(e: int) -> int:
+    """Digit width of the chain for `e`: the bit scan or the platform's
+    window (`default_pow_window`), whichever executes fewer multiplications
+    (`pow_chain_muls`) — read off the exponent, which is public. Sparse
+    exponents scan bits (BLS12-381's |z|: 5 against 29; BN254's U: 27
+    against 29); Fermat's p - 2 takes the window (BN254: 77 against 109,
+    BLS12-381: 109 against 228)."""
+    return min((1, default_pow_window()), key=lambda w: pow_chain_muls(e, w))
 
 
 def windowed_pow(a, e: int, window: int, mul, sqr, stack, take, select):
     """Left-to-right windowed square-and-multiply, representation-agnostic.
 
-    The plain bit scan executes a multiply EVERY step (compute-and-select —
-    data-independent control flow); a w-bit window keeps the squaring count
-    but replaces w bit-steps with one digit-step (w sqrs + 1 table mul + 1
-    select), cutting executed muls from bits-1 to 2^w-2 + bits/w while the
-    traced graph stays scan-sized (the digit-loop body is traced once).
+    window<=1 is the plain bit scan, as a loop over the RUNS of the public
+    exponent's bits (`bit_runs`): an outer scan over the set bits, each
+    step an inner `fori_loop` of the squarings since the last set bit and
+    then one multiplication — a zero bit is its squaring and nothing else,
+    with no select and no conditional (a taken `lax.cond` costs about as
+    much again as the multiplication it would guard on a v5e: PERF.md
+    section 6, PR 29). No table, no gather: the compile-cheapest lowering,
+    and the cheapest to run for a sparse exponent.
 
-    window<=1 selects the plain bit scan (scan over bits, square + selected
-    multiply per step, no table/gather) — the compile-cheapest lowering,
-    the right choice where compile time dominates (see default_pow_window).
+    A w-bit window keeps the squaring count and replaces w bit-steps with
+    one digit-step (w sqrs + 1 table mul + 1 select on the rare zero
+    digit), cutting executed muls to 2^w-2 + bits/w while the traced graph
+    stays scan-sized (the digit-loop body is traced once). `pow_window`
+    chooses between the two from the exponent.
 
     Primitives: mul(a,b), sqr(a); stack(list_of_elems) -> stacked repr;
     take(stacked, traced_idx) -> elem; select(traced_bool, if_true, if_false).
     """
     import jax
 
-    if window <= 1:
-        bits = bin(e)[2:]
-        if len(bits) <= 8:  # tiny exponent: direct chain
+    digits = windowed_pow_digits(e, window) if window > 1 else None
+    if digits is None:
+        bits = bin(e)[3:]
+        if len(bits) < 8:  # tiny exponent: direct chain
             acc = a
-            for c in bits[1:]:
+            for c in bits:
                 acc = sqr(acc)
                 if c == "1":
                     acc = mul(acc, a)
             return acc
 
-        def bit_step(acc, bit):
-            acc = sqr(acc)
-            return select(bit == 1, mul(acc, a), acc), None
+        def square(_, x):
+            return sqr(x)
 
-        acc, _ = jax.lax.scan(
-            bit_step, a, jnp.asarray([int(c) for c in bits[1:]], jnp.uint32)
-        )
-        return acc
+        def run(acc, squarings):
+            return mul(jax.lax.fori_loop(0, squarings, square, acc), a), None
 
-    digits = windowed_pow_digits(e, window)
-    if digits is None:  # tiny exponent: direct chain
-        acc = a
-        for c in bin(e)[3:]:
-            acc = sqr(acc)
-            if c == "1":
-                acc = mul(acc, a)
-        return acc
+        runs, tail = bit_runs(c == "1" for c in bits)
+        acc, _ = jax.lax.scan(run, a, jnp.asarray(runs, jnp.int32))
+        return jax.lax.fori_loop(0, tail, square, acc) if tail else acc
+
     # table[k] = a^(k+1), k = 0..2^w-2 (digit 0 lanes select "no mul")
     table = [a]
     for _ in range(2**window - 2):
@@ -589,13 +625,14 @@ class Field:
 
     def pow_const(self, a, e: int, window: int | None = None):
         """a^e for a fixed public exponent: windowed square-and-multiply
-        (`windowed_pow`) — for the 254-bit Fermat inversion, 77 executed
-        muls instead of the bit-scan's 253 on accelerators; plain bit scan
-        on CPU where compile time dominates (default_pow_window)."""
+        (`windowed_pow`), the digit width read off the exponent
+        (`pow_window`) — for the 254-bit Fermat inversion, the window's 77
+        executed muls instead of the bit scan's 109 on accelerators; plain
+        bit scan on CPU where compile time dominates (default_pow_window)."""
         return windowed_pow(
             a,
             e,
-            default_pow_window() if window is None else window,
+            pow_window(e) if window is None else window,
             mul=self.mul,
             sqr=lambda x: self.mul(x, x),
             stack=lambda t: jnp.stack(t),

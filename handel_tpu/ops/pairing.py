@@ -14,9 +14,16 @@ Structure (scalar oracle: ops/bn254_ref.py `miller_loop_projective` /
     sparse line with Fp2 coefficients in the (1, w, w^3) slots. All scale
     factors live in Fp2 and die in the easy part of the final exponentiation.
   * **lax.scan over the 64 static bits** of 6u+2 (MSB-first, top bit
-    consumed by the loop init). Every step computes both the doubling and the
-    mixed addition and selects by the (statically known, per-step scalar) bit
-    — fixed trip count, no data-dependent control flow, and a traced graph
+    consumed by the loop init), taken as RUNS (ops/fp.py `bit_runs`): the
+    scan steps over the set bits only, and each step is an inner
+    `fori_loop` of the doublings since the last set bit followed by one
+    mixed addition, its line and the line's Fp12 product. The bits are
+    public and the same for every lane, so the trip counts are constants
+    the scan reads one a step; a zero bit costs its doubling and nothing
+    else (36 of BN254's 64 steps add, 5 of BLS12-381's 63) — no step
+    computes an addition to select it away, and there is no conditional
+    (a taken `lax.cond` costs about as much again as the addition on a
+    v5e). No data-dependent control flow, each body traced once: a graph
     ~64x smaller than full unrolling (XLA compile-time matters).
   * **Lane semantics.** Everything is batch-last limb arrays ((nlimbs, B)
     leaves, ops/fp.py layout); one Miller step is a handful of stacked
@@ -39,7 +46,7 @@ import jax.numpy as jnp
 from handel_tpu.ops import bls12_381_ref as bls
 from handel_tpu.ops import bn254_ref as bn
 from handel_tpu.ops.curve import BLS12Curves, BN254Curves
-from handel_tpu.ops.fp import Field
+from handel_tpu.ops.fp import Field, bit_runs
 from handel_tpu.ops.tower import Tower
 
 # MSB-first bits of the ate loop count 6u+2, top bit dropped (consumed by the
@@ -74,13 +81,14 @@ class BN254Pairing:
         # points convert at the boundaries when _Tw is the resident tower
         self._Tw: Tower = self.T.as_resident() if resident else self.T
         # Note on static unrolling: emitting the Miller loop's 64 steps as
-        # straight-line code (skipping the ~39 0-bit add branches the scan
-        # computes and discards) was measured and REJECTED — the ~60x-larger
+        # straight-line code was measured and REJECTED — the ~60x-larger
         # graph OOM-kills both the XLA CPU compiler (128 GB RSS) and this
         # environment's remote TPU compile helper (13.5 MB MLIR -> SIGKILL).
-        # The windowed pow chains (Tower.f12_pow_const, w=4) capture the
-        # same class of savings for the final exponentiation in scan-sized
-        # graphs instead.
+        # The scan runs over the set bits with the doublings between them
+        # in an inner loop instead (`_miller_loop_res`), so no 0-bit step
+        # computes an addition, and a sparse exponent's pow chain does the
+        # same (ops/fp.py `windowed_pow`): the savings of unrolling in
+        # scan-sized graphs.
         # psi-Frobenius constants for the ate correction points
         # (bn254_ref.miller_loop_projective: gamma_2 for x, gamma_3 for y)
         self._g2c = self.curves.params._GAMMA[2]
@@ -228,8 +236,22 @@ class BN254Pairing:
 
     # -- Miller loop ---------------------------------------------------------
 
-    # loop bits for the shared scan (overridden per curve family)
+    # loop bits for the shared scan (overridden per curve family), and the
+    # additions `_miller_tail` runs after it
     _LOOP_BITS = _ATE_BITS
+    _TAIL_ADDS = 2
+
+    @property
+    def miller_steps(self) -> int:
+        """Steps one Miller loop of this program runs: the scan's, plus the
+        tail's additions."""
+        return len(self._LOOP_BITS) + self._TAIL_ADDS
+
+    @property
+    def miller_add_steps(self) -> int:
+        """Of `miller_steps`, those whose addition executes: the scan's set
+        bits (a zero bit's step is its doubling alone) and the tail's."""
+        return sum(self._LOOP_BITS) + self._TAIL_ADDS
 
     def miller_loop(self, p, q, mask=None):
         """Batched Miller loop: shared dbl/add scan over the family's static
@@ -253,22 +275,24 @@ class BN254Pairing:
         xp, yp = p
         xq, yq = q
         batch = xp.shape[1]
-        bits = jnp.asarray(self._LOOP_BITS, jnp.uint32)
 
-        def step(carry, bit):
+        def dbl(_, carry):
             Tpt, f = carry
             f = Tw.f12_sqr(f)
             Tpt, line = self._dbl_step(Tpt, xp, yp)
-            f = Tw.f12_mul(f, self._line_f12(line, batch))
-            Ta, line_a = self._add_step(Tpt, (xq, yq), xp, yp)
-            fa = Tw.f12_mul(f, self._line_f12(line_a, batch))
-            takes = jnp.broadcast_to(bit == 1, (batch,))
-            Tpt = tuple(Tw.f2_select(takes, a, b) for a, b in zip(Ta, Tpt))
-            f = Tw.f12_select(takes, fa, f)
-            return (Tpt, f), None
+            return Tpt, Tw.f12_mul(f, self._line_f12(line, batch))
 
-        T0 = (xq, yq, Tw.f2_one(batch))
-        (Tpt, f), _ = jax.lax.scan(step, (T0, Tw.f12_one(batch)), bits)
+        def run(carry, doublings):
+            # the doublings up to and including a set bit's step, then that
+            # step's addition
+            Tpt, f = jax.lax.fori_loop(0, doublings, dbl, carry)
+            Tpt, line = self._add_step(Tpt, (xq, yq), xp, yp)
+            return (Tpt, Tw.f12_mul(f, self._line_f12(line, batch))), None
+
+        runs, tail = bit_runs(self._LOOP_BITS)
+        carry = (xq, yq, Tw.f2_one(batch)), Tw.f12_one(batch)
+        carry, _ = jax.lax.scan(run, carry, jnp.asarray(runs, jnp.int32))
+        Tpt, f = jax.lax.fori_loop(0, tail, dbl, carry)
         f = self._miller_tail(Tpt, f, (xq, yq), xp, yp, batch)
 
         if mask is not None:
@@ -401,6 +425,7 @@ class BLS12Pairing(BN254Pairing):
     """
 
     _LOOP_BITS = [int(c) for c in bin(-bls.Z)[3:]]
+    _TAIL_ADDS = 0
 
     @classmethod
     def _default_curves(cls):

@@ -766,12 +766,12 @@ class ResidentRns:
         """Windowed square-and-multiply on resident values. Bound-safe for
         inputs <= 2^28 * p: every internal product multiplies two values
         bounded by max(input, 2^6*p), well under the RES_MUL_LOG2 budget."""
-        from handel_tpu.ops.fp import default_pow_window, windowed_pow
+        from handel_tpu.ops.fp import pow_window, windowed_pow
 
         return windowed_pow(
             a,
             e,
-            default_pow_window() if window is None else window,
+            pow_window(e) if window is None else window,
             mul=self.mul,
             sqr=lambda x: self.mul(x, x),
             stack=lambda t: jnp.stack(t),

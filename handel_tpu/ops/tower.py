@@ -538,9 +538,11 @@ class Tower:
         subgroup (final exp).
 
         Two lowerings, same algebra:
-          * scan (default): square + selected multiply per bit — keeps the
-            traced graph ~60x smaller than unrolling, which matters for XLA
-            compile times (task spec: compiler-friendly control flow).
+          * scan (default): a loop over the runs of the public bits, the
+            squarings of a run and then one multiply, or a selected table
+            multiply per digit (`windowed_pow`) — keeps the traced graph
+            ~60x smaller than unrolling, which matters for XLA compile
+            times (task spec: compiler-friendly control flow).
           * unroll: python loop over the statically-known bits, emitting the
             multiply ONLY on 1-bits, at a graph that grows with bits(e). No
             production caller opts in — this environment's compilers cannot
@@ -549,11 +551,12 @@ class Tower:
             co-located deployments whose compiler can.
 
         `window` pins the scan's digit width (1 = plain bit scan, 4 = the
-        accelerator table+gather form); None defers to default_pow_window so
-        tests can oracle-check both lowerings on any backend."""
+        accelerator table+gather form) so tests can oracle-check both
+        lowerings on any backend; None reads it off the exponent
+        (`pow_window`)."""
         import jax
 
-        from handel_tpu.ops.fp import default_pow_window, windowed_pow
+        from handel_tpu.ops.fp import pow_window, windowed_pow
 
         sqr = self.f12_cyclo_sqr if cyclo else self.f12_sqr
         if unroll:
@@ -569,14 +572,14 @@ class Tower:
                     acc = self.f12_mul(acc, a)
             return acc
 
-        # windowed digit scan on accelerators — for the 63-bit BN U: 29
-        # executed f12_muls per chain instead of the bit-scan's 62, same
-        # squaring count; plain bit scan on CPU (default_pow_window: the
-        # per-site table+gather is a compile-time tax the CPU gate can't pay)
+        # the digit width follows the exponent (`pow_window`): the 63-bit
+        # BN U (27 f12_muls) and BLS12-381's sparse |z| (5) take the bit
+        # scan, whose runs multiply on the set bits only, where the 4-bit
+        # window would execute 29; plain bit scan on CPU too
         return windowed_pow(
             a,
             e,
-            default_pow_window() if window is None else window,
+            pow_window(e) if window is None else window,
             mul=self.f12_mul,
             sqr=sqr,
             stack=lambda t: jax.tree_util.tree_map(

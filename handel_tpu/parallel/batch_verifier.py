@@ -1078,6 +1078,11 @@ class BatchVerifierService:
             "launchesDense": classes["dense"],
             "patchSlots": hc["patch_slots"],
             "patchHoles": hc["patch_holes"],
+            # steps of the Miller loop the launch programs ran (tail
+            # additions included), and those whose addition executed
+            # (ops/pairing.py: the loop adds on its set bits only)
+            "millerSteps": hc["miller_steps"],
+            "millerAddSteps": hc["miller_add_steps"],
             # queue wait measured per candidate, push to lane hand-over
             "queueWaitMs": self.queue_wait_ms,
             "queueWaitCandidates": float(self.queue_wait_candidates),

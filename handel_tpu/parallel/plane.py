@@ -273,18 +273,21 @@ class DevicePlane:
         dispatch totals any engine may carry, and `stage_ms`, the same path
         by stage, from engines with a stage clock (core/trace.py); and how
         often each launch class engaged (`class_launches`) with the wide
-        class's patch slots and holes, from engines that count them."""
+        class's patch slots and holes and the Miller loop's steps and
+        executed additions, from engines that count them."""
+        counts = ("patch_slots", "patch_holes",
+                  "miller_steps", "miller_add_steps")
         out = {"pack_ms": 0.0, "pack_launches": 0.0,
                "dispatch_ms": 0.0, "dispatch_launches": 0.0,
                "fetch_launches": 0.0, "pack_cpu_ms": 0.0,
-               "patch_slots": 0.0, "patch_holes": 0.0}
+               **dict.fromkeys(counts, 0.0)}
         stage_ms = dict.fromkeys(LAUNCH_STAGES, 0.0)
         classes = dict.fromkeys(LAUNCH_CLASSES, 0.0)
         for lane in self.lanes:
             eng = lane.engine
             for name, ct in getattr(eng, "class_launches", {}).items():
                 classes[name] += ct
-            for key in ("patch_slots", "patch_holes"):
+            for key in counts:
                 out[key] += float(getattr(eng, key, 0.0))
             for key in ("pack_ms", "pack_launches", "dispatch_ms",
                         "dispatch_launches", "fetch_launches"):

@@ -192,35 +192,44 @@ def _report(name, compiled):
 
 
 def _assert_phases(compiled):
-    """The four phases of a launch are scopes in the compiled program."""
+    """The four phases of a launch are scopes in the compiled program; the
+    Miller loop and the final exponentiation's bit-scan chains are loops
+    over the runs of their public bits — a loop nested in a loop — and hold
+    no conditional (a taken one costs as much again as what it guards)."""
     text = compiled.as_text()
     for scope in ("agg", "to_affine", "miller_loop", "final_exp"):
         assert re.search(rf'op_name="jit\([^"]*/{scope}/', text), scope
+    for scope in ("miller_loop", "final_exp"):
+        inside = rf'op_name="jit\([^"]*/{scope}/[^"]*'
+        assert re.search(inside + r'while/body/[^"]*while/body/', text), scope
+        assert not re.search(r" conditional\([^\n]*" + inside, text), scope
 
 
-# The two full pairing launches are minutes each (range ~4.5 min, dense
-# ~5.5 min on this sandbox): run by hand before a chip call,
+# The full pairing launches are minutes each (2-4 min on this sandbox): run
+# by hand before a chip call,
 #   pytest tests/test_chip_compile.py -m slow -s
+# 8 = cell 1's class, 1024 = the wide class the failing-committee cell runs
 @pytest.mark.slow
-def test_full_range_launch(shape, chip_choices):
+@pytest.mark.parametrize("miss_k", [8, N_KEYS // 4])
+def test_full_range_launch(shape, chip_choices, miss_k):
     dev = _device(2)
     sig = shape((16, LANES), U32)
     h = shape((16, 1), U32)
     fn = jax.jit(
-        partial(dev._verify_batch_range, miss_k=8),
+        partial(dev._verify_batch_range, miss_k=miss_k),
         donate_argnums=(0, 1, 2, 3, 4, 5, 8),
     )
     compiled = fn.lower(
-        *_range_args(shape, 8), sig, sig, h, h, shape((LANES,), BOOL),
+        *_range_args(shape, miss_k), sig, sig, h, h, shape((LANES,), BOOL),
         *_bank(shape, N_KEYS),
     ).compile()
-    _report("range launch", compiled)
+    _report(f"range{miss_k} launch", compiled)
     assert mosaic_calls(compiled) > 100
     _assert_phases(compiled)
 
 
 # The 24-limb launch the cell `bls12-381-4096.closed256` runs: the prefix
-# table's scan and the range launch over (24, N) banks. ~9 min here.
+# table's scan and the range launch over (24, N) banks. ~3 min here.
 @pytest.mark.slow
 def test_full_range_launch_bls12_381(shape, chip_choices):
     dev = _device(2, "bls12_381")

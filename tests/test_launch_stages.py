@@ -51,6 +51,9 @@ _accept = jax.jit(lambda: jnp.ones((1,), bool))
 
 class _BN254:
     Device, limbs = BN254Device, 16
+    # 64 steps of 6u+2 and the two tail additions; 36 bits are set, and
+    # only their steps add
+    miller = (66, 38)
     sig = BN254Signature(bn.G1_GEN)
 
     @staticmethod
@@ -63,6 +66,8 @@ class _BN254:
 
 class _BLS12381:
     Device, limbs = BLS12381Device, 24
+    # |z|: 63 steps, no tail addition; 5 bits set
+    miller = (63, 5)
     sig = BLS12381Signature(bls.G1_GEN)
 
     @staticmethod
@@ -206,6 +211,32 @@ def test_class_counters_count_each_launch_once(curve, how):
     assert v["patchSlots"] == 130 * 3 and v["patchHoles"] == 70 + 100 + 3
     dev.reset_host_counters()
     assert all(svc.values()[k] == 0.0 for k in CLASS_COUNTERS)
+
+
+@pytest.mark.parametrize("how", ["dispatch", "dispatch_multi", "rlc"])
+def test_miller_step_counters_follow_the_loop_bits(curve, how):
+    """Per launch, values() gains the Miller loop's steps and those whose
+    addition executes, read off the pairing that built the program: the
+    loop adds on its set bits only, so the two differ by the zero bits (a
+    program that computed both and selected would report them equal)."""
+    dev = _device(curve, batch_check="rlc", rlc_rng=random.Random(1)) \
+        if how == "rlc" else _device(curve)
+    steps, adds = curve.miller
+    assert (dev.pairing.miller_steps, dev.pairing.miller_add_steps) == (
+        steps, adds)
+    svc = BatchVerifierService(dev)  # values() only: never started
+    v = svc.values()
+    assert v["millerSteps"] == v["millerAddSteps"] == 0.0
+    rng = random.Random(11)
+    for done in (1, 2, 3):
+        _launch(dev, how, _requests(rng, curve))
+        v = svc.values()
+        assert v["hostDispatchLaunches"] == done
+        assert v["millerSteps"] == steps * done
+        assert v["millerAddSteps"] == adds * done
+    dev.reset_host_counters()
+    v = svc.values()
+    assert v["millerSteps"] == v["millerAddSteps"] == 0.0
 
 
 def test_second_use_of_a_staging_set_waits_on_its_fence(curve):
