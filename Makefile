@@ -5,8 +5,8 @@
 # Measured on this image's single core: the pre-split full tier (fast +
 # kernel modules) ran 181 tests in 54:21 with a warm compile cache —
 # XLA-compile-bound, not runtime-bound — so the JAX kernel modules
-# (test_{fp,tower,curve,pairing,bls12_381}_jax, test_bn254_device,
-# test_bench) are slow-tier: nightly/CI coverage via test-slow/test-all.
+# (test_{fp,tower,curve,pairing,bls12_381}_jax, test_bn254_device)
+# are slow-tier: nightly/CI coverage via test-slow/test-all.
 # The fast tier keeps the pure-Python curve oracles, the full protocol/
 # sim/transport planes, and the 8-device sharding guards — measured
 # post-split: 135 tests in 2:00 on the same core (warm cache), restoring
@@ -14,7 +14,7 @@
 
 PY ?= python
 
-.PHONY: test test-fast test-slow test-all bench dryrun
+.PHONY: test test-fast test-slow test-all dryrun
 
 # fast tier: protocol + transports + sim harness + oracle + sharding guards
 test-fast:
@@ -29,9 +29,6 @@ test-all:
 	$(PY) -m pytest tests/ -x -q -m ""
 
 test: test-fast
-
-bench:
-	$(PY) bench.py
 
 dryrun:
 	GRAFT_DRYRUN_DEVICES=8 $(PY) __graft_entry__.py

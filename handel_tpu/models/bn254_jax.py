@@ -21,7 +21,6 @@ across calls.
 
 from __future__ import annotations
 
-import asyncio
 import random
 import time
 from collections import namedtuple
@@ -57,12 +56,11 @@ from handel_tpu.ops.pairing import BN254Pairing
 # arrays; valid masks the real lanes. Array fields not used by `kind` are
 # None. `words` is the (C, W) uint64 bitset-word matrix — for a dense plan
 # it IS the device-transfer source (the kernel unpacks the candidate masks
-# on device; no host-side (n, C) mask is ever materialized). The loop
-# oracle still builds the dense `mask` host-side; vectorized plans leave it
-# None. Plans from `_pack_requests` view ROTATED staging buffers (see
-# `_StagingSet`): a plan stays valid until the staging rotation wraps back
-# onto its set — with the default two sets, the second-next `_pack_requests`
-# call invalidates it. `_pack_requests_loop` plans own their arrays.
+# on device; no host-side (n, C) mask is ever materialized: `mask` stays
+# None; the tests' loop oracle fills it). Plans from `_pack_requests` view
+# ROTATED staging buffers (see `_StagingSet`): a plan stays valid until the
+# staging rotation wraps back onto its set — with the default two sets, the
+# second-next `_pack_requests` call invalidates it.
 LaunchPlan = namedtuple(
     "LaunchPlan",
     "kind miss_k lo hi miss_idx miss_ok words mask sig_x sig_y valid",
@@ -1138,7 +1136,7 @@ class BN254Device:
         return launches
 
     def reset_host_counters(self) -> None:
-        """Zero the host-stage cost counters (warmup and bench phase
+        """Zero the host-stage cost counters (warmup and phase
         boundaries: accumulation must start at the phase, not at
         construction). Launch numbering goes on: a seq is an identity."""
         self.stage_clock.reset()
@@ -1243,9 +1241,9 @@ class BN254Device:
         (see _StagingSet for the fence that enforces this against
         still-in-flight launches).
 
-        Bit-identical to `_pack_requests_loop` (property-tested across
-        rotation boundaries), which keeps the old per-candidate construction
-        as the readable oracle.
+        Bit-identical to the per-candidate construction that
+        tests/test_dispatch_pack.py keeps as the readable oracle
+        (property-tested across rotation boundaries).
         """
         self._stage_idx = (self._stage_idx + 1) % len(self._stage)
         st = self._stage[self._stage_idx]
@@ -1353,63 +1351,6 @@ class BN254Device:
         return LaunchPlan(
             "range", miss_k, lo, hi, miss_idx, miss_ok, words, None,
             st.sig_x, st.sig_y, valid,
-        )
-
-    def _pack_requests_loop(self, requests) -> "LaunchPlan":
-        """The pre-vectorization per-candidate packer, kept as the oracle
-        for `_pack_requests` equivalence tests and the bench.py host_pack_ms
-        before/after comparison. Allocates fresh arrays (no staging)."""
-        C = self.batch_size
-        F = self.curves.F
-        sig_pts = []
-        valid = np.zeros((C,), dtype=bool)
-        sets: list[np.ndarray] = []
-        for j, (bs, sig) in enumerate(requests):
-            if len(bs) != self.n:
-                raise ValueError("bitset length != registry size")
-            idx = np.fromiter(bs.indices(), dtype=np.int64)
-            sig_pt = getattr(sig, "point", None)
-            if idx.size and sig_pt is not None:
-                valid[j] = True
-                sig_pts.append(sig_pt)
-            else:
-                sig_pts.append(self.ref.G1_GEN)  # placeholder, lane masked out
-            sets.append(idx)
-        sig_pts += [self.ref.G1_GEN] * (C - len(sig_pts))  # pad lanes
-        sig_x = F.pack([p[0] for p in sig_pts])
-        sig_y = F.pack([p[1] for p in sig_pts])
-
-        holes = [
-            int(idx[-1] - idx[0] + 1 - idx.size) if v and idx.size else 0
-            for idx, v in zip(sets, valid)
-        ]
-        miss_k = self._patch_width(max(holes, default=0))
-        if not miss_k:
-            mask = np.zeros((self.n, C), dtype=bool)
-            for j, idx in enumerate(sets):
-                if valid[j] and idx.size:
-                    mask[idx, j] = True
-            return LaunchPlan(
-                "dense", 0, None, None, None, None, None, mask,
-                sig_x, sig_y, valid,
-            )
-        lo = np.zeros((C,), np.int32)
-        hi = np.zeros((C,), np.int32)
-        miss_idx = np.zeros((miss_k, C), np.int64)
-        miss_ok = np.zeros((miss_k, C), dtype=bool)
-        for j, idx in enumerate(sets):
-            if not valid[j] or not idx.size:
-                continue
-            lo[j] = idx[0]
-            hi[j] = idx[-1] + 1
-            missing = np.setdiff1d(
-                np.arange(idx[0], idx[-1] + 1), idx, assume_unique=True
-            )
-            miss_idx[: missing.size, j] = missing
-            miss_ok[: missing.size, j] = True
-        return LaunchPlan(
-            "range", miss_k, lo, hi, miss_idx, miss_ok, None, None,
-            sig_x, sig_y, valid,
         )
 
     def _stage_plan(self, plan):
@@ -1762,16 +1703,3 @@ class BN254JaxScheme(BN254Scheme):
             rns_resident=rns_resident,
             batch_check=batch_check,
         )
-
-
-def make_async_verifier(device: BN254Device):
-    """Adapt a BN254Device into the processing pipeline's AsyncVerifier,
-    running launches in a worker thread so the event loop stays live."""
-
-    async def verify(msg, pubkeys, requests):
-        loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(
-            None, partial(device.batch_verify, msg, requests)
-        )
-
-    return verify

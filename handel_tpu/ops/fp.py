@@ -17,9 +17,8 @@ Design:
   * **Montgomery multiplication** (radix 2^16, CIOS-style column interleave)
     as one fused Pallas kernel: inputs stream HBM->VMEM in (NLIMBS, TILE_B)
     blocks, all ~n^2 limb products and column sums happen in VMEM/registers.
-    Throughput is not measured on this machine; `_throughput_bench`
-    (chained-dispatch marginal slope, see `chained_marginal`) measures it
-    on the chip. The naive XLA graph this replaces materializes
+    Its speed on the chip is the benchmark's to say (`fp_mul.mac_rate`,
+    PERF.md section 3). The naive XLA graph this replaces materializes
     (B,16,16) intermediates through HBM.
   * **Batch stacking beats vmap.** Callers (ops/tower.py) flatten independent
     field muls into the batch dimension (one Fp12 mul = ONE mont_mul call at
@@ -39,8 +38,7 @@ bit-identical across backends.
   * ``backend="rns"`` — `ops/rns.py`'s residue-number-system Montgomery
     pipeline, which restructures the multiply into constant-matrix
     `dot_general` contractions so the MXU (idle under CIOS — contraction
-    depth 1; scripts/mxu_limb_lab.py measures the headroom) carries the
-    bulk work. `Field.__new__`
+    depth 1) carries the bulk work. `Field.__new__`
     redirects construction to `RnsField`, a subclass overriding only
     `mul`; everything else here (add/sub/inv/pow/pack/unpack, the
     carry-lookahead machinery) is inherited, and `ops/tower.py`'s
@@ -63,12 +61,6 @@ bit-identical across backends.
     static per-site bound literals (`blog`, accepted-and-ignored by the
     CIOS `sub`/`neg` above) — HACKING.md "Residue-resident pairing" has
     the bound algebra.
-
-Throughput methodology: per-backend `mont_muls_per_s` records are taken
-under ONE chained-dispatch methodology (`chained_marginal`, shared by
-`_throughput_bench`, scripts/fp_kernel_lab.py, and scripts/mxu_limb_lab.py)
-and gated like-for-like by scripts/bench_check.py — a CIOS row never
-judges an RNS row.
 
 Correctness oracle: ops/bn254_ref.py; property tests in tests/test_fp_jax.py.
 """
@@ -668,96 +660,3 @@ class Field:
     def from_mont(self, a):
         one = jnp.zeros_like(a).at[0].set(1)
         return self.mul(a, one)
-
-
-def chained_marginal(fn, a, b, k1: int = 8, k2: int = 72, trials: int = 4):
-    """Marginal throughput of a binary op under chained dispatch — the
-    one methodology shared by `_throughput_bench`, scripts/fp_kernel_lab.py
-    and scripts/mxu_limb_lab.py so the candidates stay comparable.
-
-    A single dispatch pays a host<->device round trip that can dwarf the
-    kernel, so a naive time-one-call loop measures the dispatch, not the
-    chip (the floor is not measured on this machine; this function returns
-    it beside the rate).
-    Instead: time k1- and k2-deep chains of dependent `fn(out, b)` calls
-    inside ONE jitted executable each (best of `trials`, completion forced
-    by a one-column device_get), and report the slope
-    (k2-k1)*batch/(t2-t1) — dispatch/fetch overhead cancels in the
-    difference. Returns (rate_ops_per_s, dispatch_floor_s); rate is None
-    when the slope is non-positive after one retry (timing noise at tiny
-    batches): a non-measurement, never an absurd figure.
-    """
-    import time
-
-    import jax
-
-    def chain(k):
-        def f(x, y):
-            out = x
-            for _ in range(k):
-                out = fn(out, y)
-            return out
-
-        return jax.jit(f)
-
-    def best_of(cf):
-        jax.device_get(cf(a, b)[:, :1])  # compile + warm
-        best = float("inf")
-        for _ in range(trials):
-            t0 = time.perf_counter()
-            jax.device_get(cf(a, b)[:, :1])
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    c1, c2 = chain(k1), chain(k2)
-    t1, t2 = best_of(c1), best_of(c2)
-    if t2 <= t1:  # timing noise (tiny batches): one retry
-        t1, t2 = best_of(c1), best_of(c2)
-    if t2 <= t1:
-        return None, t1
-    batch = a.shape[-1]
-    rate = (k2 - k1) * batch / (t2 - t1)
-    floor = max(t1 - k1 * batch / rate, 0.0)
-    return rate, floor
-
-
-def _throughput_bench(
-    batch: int = 1 << 18, trials: int = 4, backend: str = "cios"
-):
-    """Substantiates the module docstring's mult/s figure; run with
-    `python -m handel_tpu.ops.fp [batch] [backend]` on the target chip.
-    Chained-dispatch marginal methodology — see `chained_marginal`.
-    Returns (marginal_rate, dispatch_floor_s); rate 0.0 when the slope is
-    not measurable."""
-    import jax
-
-    from handel_tpu.ops import bn254_ref as bn
-
-    F = Field(bn.P, backend=backend)
-    rng = np.random.default_rng(1)
-    a = jnp.asarray(rng.integers(0, 1 << LIMB_BITS, (F.nlimbs, batch), np.uint32))
-    b = jnp.asarray(rng.integers(0, 1 << LIMB_BITS, (F.nlimbs, batch), np.uint32))
-    k1, k2 = 8, 72
-    rate, floor = chained_marginal(F.mul, a, b, k1=k1, k2=k2, trials=trials)
-    if rate is None:
-        print(
-            f"{jax.default_backend()}: marginal slope not measurable "
-            f"(floor ~{floor*1e3:.2f} ms at batch {batch}) — "
-            f"increase batch or chain depth",
-        )
-        return 0.0, floor
-    print(
-        f"{jax.default_backend()}: {rate/1e6:.1f}M {bn.P.bit_length()}-bit "
-        f"mont-muls/s marginal [{backend}] (batch {batch}, chain {k1}->{k2}, "
-        f"dispatch floor ~{floor*1e3:.1f} ms)"
-    )
-    return rate, floor
-
-
-if __name__ == "__main__":
-    import sys
-
-    _throughput_bench(
-        int(sys.argv[1]) if len(sys.argv) > 1 else 1 << 20,
-        backend=sys.argv[2] if len(sys.argv) > 2 else "cios",
-    )

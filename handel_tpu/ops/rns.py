@@ -2,8 +2,7 @@
 
 The production CIOS kernel (ops/fp.py) is a VPU workload: a 254-bit limb
 product is an outer product (contraction depth 1), so the 128x128 systolic
-array contributes nothing and the MXU (its int8 ceiling is not measured on
-this machine; scripts/mxu_limb_lab.py does) sits idle through every pairing.
+array contributes nothing and the MXU sits idle through every pairing.
 RNS restructures the same arithmetic so the heavy steps ARE deep matmul
 contractions against constant matrices — the shape the AI-ASIC ZKP
 literature targets (PAPERS.md, arxiv 2604.17808; ROADMAP item 1):
@@ -71,8 +70,8 @@ representation:
 The residue<->positional conversion counters (`conversion_counts`)
 increment at TRACE time — one count per traced call site, so a
 `lax.scan` body counts once however many steps it runs. That is exactly
-the right unit for the claim they substantiate (bench.py
-`rns_conversions_per_pairing`): per-mul before, per-line-boundary after.
+the right unit for the claim they substantiate (scripts/rns_smoke.py's
+conversion count): per-mul before, per-line-boundary after.
 
 **Montgomery convention.** The backend's Montgomery constant is M (the
 base-A product), not the CIOS kernel's R = 2^(16n): division by M is what

@@ -14,13 +14,13 @@ launch group, which shape it rides:
   * **latency** mode — the group is small enough to fit one mesh launch,
     the backlog is shallow, and its best SLO tier is entitled to the mesh
     (gold by default): route to the mesh lane, cutting the single-launch
-    wall ~K/2x (`small_batch_verify_p50_ms` bench contract).
+    wall (not measured on chips).
   * **throughput** mode — bulk batches and backlogged queues: today's
     per-lane path, where the mesh is worth more as K independent lanes.
 
 The scheduler integration lives in `DevicePlane.pick_mesh` (parallel/
 plane.py) and `BatchVerifierService._route_mesh` (batch_verifier.py); this
-module owns the policy, the engine builders, and the CI/bench host engine.
+module owns the policy, the engine builders, and the CI host engine.
 Like plane.py, nothing here imports jax at module level — the jax-backed
 builder (`bn254_mesh_engine`) imports lazily.
 """
@@ -82,12 +82,12 @@ class ModePolicy:
 
 
 class HostMeshDevice:
-    """Host-math engine modeling ONE whole-mesh launch (the CI/bench shape).
+    """Host-math engine modeling ONE whole-mesh launch (the CI shape).
 
     The real latency engine is `BN254Device(mesh_devices=K)`; its pairing
     walls can't be measured on a CI box where K forced host devices share
-    one core, so — exactly like service/driver.py HostDevice under
-    fleet_bench — this engine keeps the real verdict math (the scheme
+    one core, so — exactly like service/driver.py HostDevice —
+    this engine keeps the real verdict math (the scheme
     constructor's batch_verify) and SIMULATES the wall. Unlike HostDevice's
     fixed `launch_ms`, the wall here models INTRA-launch parallelism: each
     candidate costs `per_candidate_ms`, the candidates shard over
@@ -95,8 +95,7 @@ class HostMeshDevice:
     max over workers, contention included), and `collective_ms` is the
     serial all_gather + combine-tree share that Amdahl-caps the win. So a
     batch-n launch walls ~ per_candidate_ms * ceil(n/K) + collective_ms,
-    and `devices=1` is the single-lane baseline with identical code — the
-    pair the `small_batch_verify_p50_ms` bench contract compares.
+    and `devices=1` is the single-lane baseline with identical code.
     """
 
     def __init__(
@@ -182,7 +181,7 @@ def host_mesh_engine(
     per_candidate_ms: float = 1.0,
     collective_ms: float = 0.5,
 ) -> HostMeshDevice:
-    """The CI/bench mesh engine (see HostMeshDevice)."""
+    """The CI mesh engine (see HostMeshDevice)."""
     return HostMeshDevice(
         constructor,
         batch_size=batch_size,

@@ -148,7 +148,7 @@ class DevicePlane:
     acceptance property "no device idles while another has ≥ 2 queued
     launches": a violation is counted iff an idle admissible lane existed,
     some lane carried ≥ 2 launches, and the pick was NOT idle — impossible
-    under min-load, so the bench asserts the counter stays 0.
+    under min-load, so tests/test_plane.py asserts the counter stays 0.
     """
 
     def __init__(self, engines, breakers=None):
@@ -362,7 +362,7 @@ def host_plane(constructor, devices: int, batch_size: int = 64,
                launch_ms: float = 0.0,
                batch_check: str = "per_candidate") -> DevicePlane:
     """A plane of K host-math engines (service/driver.py HostDevice) — the
-    CI/bench shape: real scheduling + breakers, no kernels compiled."""
+    CI shape: real scheduling + breakers, no kernels compiled."""
     from handel_tpu.service.driver import HostDevice
 
     return DevicePlane([

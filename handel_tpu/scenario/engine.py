@@ -20,9 +20,8 @@ aggregation round with every configured axis active at once:
             quiesce + flip (lifecycle/epoch.py). A join lands in the next
             epoch's committee; the running round is unaffected by design.
 
-The result is a bench-record-shaped report (scripts/bench_check.py,
-headline `geo_weighted_ttt_s`) plus the trace dump + trace report in
-`workdir`, making every scenario a captured, regression-gated artifact.
+The result is a report (headline `geo_weighted_ttt_s`) plus the trace
+dump + trace report in `workdir`.
 """
 
 from __future__ import annotations
@@ -194,10 +193,6 @@ async def run_scenario(cfg, workdir: str, logger=DEFAULT_LOGGER) -> dict:
         "region_attributed": geo is None or len(region_hops) >= 1,
     }
     report = {
-        # bench-record shape (scripts/bench_check.py SIDE_METRICS)
-        "metric": "geo_weighted_ttt_s",
-        "value": round(ttt, 6),
-        "backend": "scenario",
         "captured_at": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
         "ok": all(checks.values()),
         "checks": checks,

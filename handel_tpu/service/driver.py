@@ -333,7 +333,7 @@ class MultiSessionCluster:
 
     async def run(self, timeout: float = 120.0) -> dict:
         """Spawn + start every session, await all terminal states, and
-        return the run summary (the bench/capture record shape)."""
+        return the run summary."""
         t0 = time.perf_counter()
         alert_task = (
             asyncio.ensure_future(self._alert_loop())

@@ -134,7 +134,7 @@ def main() -> int:
     if len(sys.argv) > 1 and sys.argv[1] == "scenario":
         # WAN scenario subcommand (handel_tpu/scenario/engine.py): run the
         # [scenario] TOML section's composed geo/churn/weights run in one
-        # process and write the bench-shaped report + trace into --workdir
+        # process and write the report + trace into --workdir
         zap = argparse.ArgumentParser(
             prog="python -m handel_tpu.sim scenario"
         )

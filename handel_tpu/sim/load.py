@@ -28,7 +28,7 @@ killed_at → unhealthy_detected (probe/passive) → recover_started →
 readmitted → first post-recovery completion (`region_recovery_s`).
 
 The report (`<workdir>/federation_report.json`) extends the soak_report
-schema: bench-record shaped, SIDE_METRICS flat on the record
+schema: headline figures flat on the record
 (`open_loop_p99_s`, `region_recovery_s`, `spillover_rate`), `checks`
 stamped by the shared specs in sim/report_checks.py.
 """
@@ -140,7 +140,7 @@ class SessionRecord:
 class LoadRun:
     """One open-loop run: build the federation, replay the arrival trace,
     drive the chaos timeline, emit the report. Split from the CLI so
-    tests and the bench can run short traces in-process."""
+    tests can run short traces in-process."""
 
     def __init__(self, load_p, fed_p, alert_p=None,
                  logger: Logger = DEFAULT_LOGGER):
@@ -707,11 +707,7 @@ class LoadRun:
         kill = self._kill_block()
         alerts, detect_ms, fp_rate = self._alert_block()
         report = {
-            # bench-record shape (scripts/bench_check.py): headline +
-            # SIDE_METRICS keys flat on the record, detail nested
-            "metric": "open_loop_p99_s",
-            "value": p99,
-            "backend": "cpu",
+            # headline figures flat on the record, detail nested
             "captured_at": time.strftime(
                 "%Y-%m-%dT%H:%M:%SZ", time.gmtime()
             ),

@@ -1,6 +1,6 @@
 """One source of truth for report acceptance checks.
 
-The soak and federation harnesses both emit bench-record-shaped reports
+The soak and federation harnesses both emit reports
 carrying a `checks` block, and their CI smokes re-assert the same
 invariants with human-readable failure detail. Before this module the
 predicate logic lived twice — once in the report builder, once in the

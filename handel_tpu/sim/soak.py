@@ -1,8 +1,8 @@
 """`python -m handel_tpu.sim soak` — the lifecycle plane's CI proof.
 
 A ~90 s continuously-loaded service run that exercises every production
-lifecycle mechanism (handel_tpu/lifecycle/) mid-flight and writes a
-bench-record-shaped `soak_report.json`:
+lifecycle mechanism (handel_tpu/lifecycle/) mid-flight and writes
+`soak_report.json`:
 
 - **sustained load** — a spawner keeps `concurrency` tiered sessions live
   for `duration_s`; every completion immediately back-fills, so the shared
@@ -409,11 +409,7 @@ class SoakRun:
         )
         soak_p99 = summary["session_p99_s"]
         report = {
-            # bench-record shape (scripts/bench_check.py): headline +
-            # SIDE_METRICS keys flat on the record, detail nested
-            "metric": "soak_p99_s",
-            "value": soak_p99,
-            "backend": "cpu",
+            # headline figures flat on the record, detail nested
             "captured_at": time.strftime(
                 "%Y-%m-%dT%H:%M:%SZ", time.gmtime()
             ),

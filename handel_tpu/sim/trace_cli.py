@@ -20,9 +20,8 @@ cluster) and answers the questions the CSV cannot:
 Options: `--merged out.json` writes the combined timeline (open in
 chrome://tracing or Perfetto); `--plot out.png` draws the wave via
 sim/plots.py; `--top N` bounds the attribution table; `--report out.json`
-writes the machine-readable `trace_report.json` (bench-record shaped, so
-scripts/bench_check.py tracks time-to-threshold / coverage / flow linkage /
-lane occupancy as side metrics).
+writes the machine-readable `trace_report.json` (time-to-threshold,
+coverage, flow linkage and lane occupancy flat on the record).
 """
 
 from __future__ import annotations
@@ -682,8 +681,8 @@ def stream_report(paths: list[str], top_k: int = 10) -> dict:
 
 
 def build_report(events: list[dict], exports: list[dict] | None = None) -> dict:
-    """The machine-readable `trace_report.json`: bench-record shaped
-    (metric/value/backend, scripts/bench_check.py extract_metrics) with the
+    """The machine-readable `trace_report.json`: a headline
+    (metric/value/backend) and the flat figures, with the
     critical-path breakdown, per-level wave, flow linkage, lane occupancy
     and the per-process clock offsets as payload."""
     cp = critical_path(events)

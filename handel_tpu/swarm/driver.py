@@ -337,9 +337,9 @@ class SwarmHost:
 
 
 def merge_summaries(parts: list[dict]) -> dict:
-    """Fleet record from per-process summaries. The three bench-gated
-    metrics (scripts/bench_check.py SIDE_METRICS): `swarm_identities`
-    (scale proof, higher is better), `mem_bytes_per_identity` (summed RSS
+    """Fleet record from per-process summaries. Its three headline
+    figures: `swarm_identities` (how many the run carried),
+    `mem_bytes_per_identity` (summed RSS
     over the committee — the extrapolation basis), and
     `swarm_time_to_threshold_s` (wall until the LAST member held a
     threshold signature — the whole-committee completion wave)."""

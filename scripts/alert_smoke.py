@@ -12,11 +12,7 @@ on (handel_tpu/obs/):
 2. **clean control** — the identical load with no kill. ZERO incidents
    may open: `false_positive_rate` must be exactly 0.0.
 
-`detection_latency_ms` and `false_positive_rate` ride the report flat
-(bench-record shape), so the final step hands the drill artifact to
-scripts/bench_check.py for SIDE_METRICS regression gating against any
-committed incident history (results/incident_report*.json — via the
-federation report that carries the same keys).
+`detection_latency_ms` and `false_positive_rate` ride the report flat.
 
 Usage: python scripts/alert_smoke.py [--artifact-dir DIR] [--duration S]
        [--rate SPS] [--latency-bound-ms MS]
@@ -28,7 +24,6 @@ import argparse
 import asyncio
 import json
 import os
-import subprocess
 import sys
 import tempfile
 
@@ -40,8 +35,6 @@ from handel_tpu.sim.config import (  # noqa: E402
     LoadParams,
 )
 from handel_tpu.sim.load import run_load  # noqa: E402
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def main(argv=None) -> int:
@@ -130,19 +123,6 @@ def main(argv=None) -> int:
             f"{json.dumps(clean['alerts']['report']['incidents'], indent=1)}"
         )
         assert clean["false_positive_rate"] == 0.0
-
-        # regression gate: the drill report carries the SIDE_METRICS flat
-        # (detection_latency_ms, false_positive_rate) — dry-run keeps the
-        # gate self-testing even with no committed history yet
-        rc = subprocess.call([
-            sys.executable,
-            os.path.join(REPO, "scripts", "bench_check.py"),
-            "--history",
-            os.path.join(REPO, "results", "federation_report*.json"),
-            "--fresh", os.path.join(d, "federation_report.json"),
-            "--dry-run",
-        ])
-        assert rc == 0, "bench_check --dry-run failed on the drill report"
 
     print("alert smoke: exactly-one-incident drill + clean control held")
     return 0

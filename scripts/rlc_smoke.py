@@ -1,4 +1,4 @@
-"""RLC batch-verification smoke: verdict parity, counters, bench gate.
+"""RLC batch-verification smoke: verdict parity, counters.
 
 The fast-tier guard for the random-linear-combination batch check
 (models/rlc.py + the HostDevice/BN254Device wiring): RLC verdicts must
@@ -8,10 +8,7 @@ mixed-message `dispatch_multi` launches — with the per-launch pairing cost
 asserted at M+1 Miller loops / 1 final exponentiation via the RlcStats
 kernel counters (against the 2C / C per-candidate baseline). A forged
 batch must come back with exactly the per-candidate culprit set, found by
-bisection. Then the host bench captures `rlc_verify_p50_ms` /
-`rlc_speedup_x` at batch 64 (acceptance: >= 3x) and self-tests
-`scripts/bench_check.py --dry-run` against a fresh artifact carrying both,
-keyed per fp_backend.
+bisection.
 
 Scope note: one CPU core takes minutes of XLA per MSM/pairing-tail graph,
 so this smoke drives the host-math RLC engine (native bn254 group ops) —
@@ -26,12 +23,9 @@ MSM stage and check S/X against the host oracle (minutes of XLA, off by
 default in CI).
 """
 
-import json
 import os
 import random
-import subprocess
 import sys
-import tempfile
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -45,7 +39,7 @@ from handel_tpu.models.bn254 import BN254Scheme  # noqa: E402
 from handel_tpu.service.driver import HostDevice  # noqa: E402
 
 N = 16  # registry size
-C = 64  # candidates per launch (the acceptance batch)
+C = 64  # candidates per launch
 M = 4  # distinct messages in the mixed-message launch
 
 
@@ -175,67 +169,7 @@ def main() -> int:
     if os.environ.get("HANDEL_TPU_RLC_SMOKE_DEVICE") == "1":
         _device_msm_phase(device, dpks, rng)
 
-    # -- bench: rlc_verify_p50_ms / rlc_speedup_x at batch 64 --------------
-    from bench import rlc_bench
-
-    trials = int(os.environ.get("HANDEL_TPU_RLC_SMOKE_TRIALS", "3"))
-    m = rlc_bench(batch=C, messages=M, trials=trials)
-    assert m["rlc_speedup_x"] >= 3.0, (
-        f"rlc speedup {m['rlc_speedup_x']}x below the 3x acceptance at "
-        f"batch {C}"
-    )
-    print(
-        f"rlc_smoke: batch-{C} host bench: rlc {m['rlc_verify_p50_ms']} ms "
-        f"vs per-candidate {m['rlc_per_candidate_p50_ms']} ms "
-        f"({m['rlc_speedup_x']}x)"
-    )
-
-    # -- bench_check --dry-run over a fresh artifact with both rows --------
-    fresh = {
-        "metric": "rlc_smoke",
-        "backend": "cpu",
-        "records": [
-            {
-                "metric": "rlc_verify_p50_ms",
-                "value": m["rlc_verify_p50_ms"],
-                "unit": "ms",
-                "backend": "cpu",
-                "fp_backend": fp,
-                **{k: v for k, v in m.items() if k != "rlc_verify_p50_ms"},
-            }
-            for fp in ("cios", "rns")
-        ],
-    }
-    with tempfile.NamedTemporaryFile("w", suffix=".json", delete=False) as f:
-        json.dump(fresh, f)
-        path = f.name
-    try:
-        r = subprocess.run(
-            [
-                sys.executable,
-                os.path.join(REPO, "scripts", "bench_check.py"),
-                "--dry-run",
-                "--fresh",
-                path,
-            ],
-            capture_output=True,
-            text=True,
-        )
-        sys.stdout.write(r.stdout)
-        sys.stderr.write(r.stderr)
-        assert r.returncode == 0, "bench_check --dry-run failed"
-        assert "rlc_verify_p50_ms" in r.stdout, (
-            "bench_check did not consider rlc_verify_p50_ms"
-        )
-        assert "rlc_speedup_x" in r.stdout, (
-            "bench_check did not consider rlc_speedup_x"
-        )
-    finally:
-        os.unlink(path)
-    print(
-        f"rlc_smoke: bench_check --dry-run gated both rlc metrics "
-        f"(total {time.perf_counter() - t0:.1f}s)"
-    )
+    print(f"rlc_smoke: ok (total {time.perf_counter() - t0:.1f}s)")
     return 0
 
 

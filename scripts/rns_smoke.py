@@ -1,6 +1,6 @@
 """RNS-backend CI gate (fast tier, CPU XLA path — ISSUE 14 + 16 acceptance).
 
-Seven checks, each a hard exit-nonzero failure:
+Five checks, each a hard exit-nonzero failure:
 
 1. Bit-exactness: a seeded batch of products (random + edge operands,
    including both operands at p-1) through `Field(backend="rns")` must
@@ -13,46 +13,33 @@ Seven checks, each a hard exit-nonzero failure:
 3. Backend plumbing: fp_backend survives TOML load/dump round-trip,
    rejects junk values, and reaches the constructed Field through
    new_scheme (TOML -> SimConfig -> scheme kwargs -> Curves -> Field).
-4. bench_check dry-run: constructed per-fp-backend `mont_muls_per_s`
-   records flow through scripts/bench_check.py keyed as
-   "<backend>/<fp_backend>" — an RNS row gates only against RNS history,
-   and a CIOS-only history yields a cross-backend refusal, never a
-   judgment.
-5. Residue-resident conversion count (ISSUE 16): tracing the resident
+4. Residue-resident conversion count (ISSUE 16): tracing the resident
    pairing crosses the CRT boundary O(line boundaries) times (points in,
    f12 out — <= 8), while the legacy form round-trips once per tower mul
    (thousands). Counted at trace time via `jax.eval_shape`, no compile.
-6. Resident tower bit-exactness (compile-cheap): a seeded batch through
+5. Resident tower bit-exactness (compile-cheap): a seeded batch through
    the RESIDENT `f12_mul` — residue planes in, lazy CRT reconstruction
    out — matches the scalar oracle and the CIOS tower bit-for-bit at the
    canonical boundary.
-7. bench_check dry-run over `pairing_p50_ms` / `rns_conversions_per_
-   pairing` (bench.py _pairing_bench): per-fp keying and the
-   cross-backend-judgment-refused rule, same contract as check 4.
 
 `--full` additionally runs the full resident BN254 pairing NUMERICALLY
 against the CIOS oracle — valid + forged candidates through both launch
 classes (`pairing` and the batched `pairing_check` product) — minutes of
 XLA compile on CPU, so it is opt-in (nightly), not every-push.
 
-On real hardware the MXU lab (scripts/mxu_limb_lab.py --persist) captures
-the actual marginal figures; this gate is the CPU-only stand-in that keeps
-the kernel and the gating plumbing honest on every commit.
+This gate is the CPU-only check of the kernel's arithmetic and the option's
+plumbing on every commit; it measures no speed.
 
 Usage: python scripts/rns_smoke.py [--full]
 """
 
 from __future__ import annotations
 
-import json
 import os
-import subprocess
 import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def check_bit_exact() -> None:
@@ -142,79 +129,6 @@ def check_toml_plumbing() -> None:
     F = sch.constructor.curves.F
     assert type(F) is RnsField and F.backend == "rns"
     print("rns_smoke: fp_backend plumbed TOML -> SimConfig -> Field")
-
-
-def check_bench_check_dry_run() -> None:
-    def rec(fp_backend: str, value: float) -> dict:
-        return {
-            "metric": "mont_muls_per_s",
-            "value": value,
-            "unit": "M muls/s",
-            "backend": "cpu",
-            "fp_backend": fp_backend,
-            "batch": 1024,
-            "captured_at": f"2026-01-01T00:00:0{int(value) % 10}Z",
-        }
-
-    with tempfile.TemporaryDirectory() as d:
-        for i, (cios, rns) in enumerate([(350.0, 420.0), (360.0, 410.0)]):
-            with open(os.path.join(d, f"BENCH_h{i}.json"), "w") as f:
-                json.dump({"records": [rec("cios", cios), rec("rns", rns)]},
-                          f)
-        fresh = os.path.join(d, "fresh.json")
-        with open(fresh, "w") as f:
-            # rns holds steady; cios "regresses" — dry-run must key them
-            # separately and never let the cios row judge the rns row
-            json.dump({"records": [rec("cios", 100.0), rec("rns", 415.0)]},
-                      f)
-        report_path = os.path.join(d, "report.json")
-        r = subprocess.run(
-            [
-                sys.executable,
-                os.path.join(REPO, "scripts", "bench_check.py"),
-                "--history", os.path.join(d, "BENCH_*.json"),
-                "--fresh", fresh,
-                "--dry-run", "--json", report_path,
-            ],
-            capture_output=True, text=True, cwd=REPO,
-        )
-        assert r.returncode == 0, r.stderr[-2000:]
-        report = json.load(open(report_path))
-        keys = {
-            (e["metric"], e["backend"])
-            for sec in ("regressions", "improved", "ok")
-            for e in report[sec]
-        }
-        assert ("mont_muls_per_s", "cpu/cios") in keys, report
-        assert ("mont_muls_per_s", "cpu/rns") in keys, report
-        regressed = {e["backend"] for e in report["regressions"]}
-        assert regressed == {"cpu/cios"}, (
-            f"per-fp-backend keying broken: {report}"
-        )
-
-        # cios-only history must REFUSE to judge an rns row
-        fresh2 = os.path.join(d, "fresh2.json")
-        with open(fresh2, "w") as f:
-            json.dump(rec("rns", 1.0), f)
-        for i in range(2):
-            with open(os.path.join(d, f"CONLY_h{i}.json"), "w") as f:
-                json.dump(rec("cios", 350.0 + i), f)
-        r2 = subprocess.run(
-            [
-                sys.executable,
-                os.path.join(REPO, "scripts", "bench_check.py"),
-                "--history", os.path.join(d, "CONLY_*.json"),
-                "--fresh", fresh2, "--json", report_path,
-            ],
-            capture_output=True, text=True, cwd=REPO,
-        )
-        assert r2.returncode == 0, (r2.stdout, r2.stderr[-2000:])
-        report2 = json.load(open(report_path))
-        assert report2["skipped"] and "cross-backend" in (
-            report2["skipped"][0]["reason"]
-        ), report2
-    print("rns_smoke: bench_check keys mont_muls_per_s per fp_backend "
-          "(cross-backend judgment refused)")
 
 
 def _pairing_stack():
@@ -309,92 +223,6 @@ def check_resident_tower_bit_exact(stack) -> None:
           f"over {len(a_vals)} lanes (incl. all-(p-1) operands)")
 
 
-def check_pairing_bench_gate() -> None:
-    """bench_check --dry-run over the new pairing metrics: per-fp keying
-    plus the cross-backend-judgment-refused rule (check 4's contract,
-    extended to bench.py _pairing_bench records)."""
-
-    def rec(metric: str, fp_backend: str, value: float) -> dict:
-        return {
-            "metric": metric,
-            "value": value,
-            "unit": "ms",
-            "backend": "cpu",
-            "fp_backend": fp_backend,
-            "batch": 4,
-            "captured_at": f"2026-02-01T00:00:0{int(value) % 10}Z",
-        }
-
-    def recs(cios_ms: float, rns_ms: float, conv: float) -> dict:
-        return {
-            "records": [
-                rec("pairing_p50_ms", "cios", cios_ms),
-                rec("pairing_p50_ms", "rns", rns_ms),
-                rec("rns_conversions_per_pairing", "rns", conv),
-            ]
-        }
-
-    with tempfile.TemporaryDirectory() as d:
-        for i, (c, r) in enumerate([(120.0, 80.0), (118.0, 82.0)]):
-            with open(os.path.join(d, f"PBENCH_h{i}.json"), "w") as f:
-                json.dump(recs(c, r, 6.0), f)
-        fresh = os.path.join(d, "fresh.json")
-        with open(fresh, "w") as f:
-            # cios p50 "regresses"; the rns rows hold — keying must judge
-            # them apart, and the conversion count gates as its own metric
-            json.dump(recs(500.0, 81.0, 6.0), f)
-        report_path = os.path.join(d, "report.json")
-        r = subprocess.run(
-            [
-                sys.executable,
-                os.path.join(REPO, "scripts", "bench_check.py"),
-                "--history", os.path.join(d, "PBENCH_*.json"),
-                "--fresh", fresh,
-                "--dry-run", "--json", report_path,
-            ],
-            capture_output=True, text=True, cwd=REPO,
-        )
-        assert r.returncode == 0, r.stderr[-2000:]
-        report = json.load(open(report_path))
-        keys = {
-            (e["metric"], e["backend"])
-            for sec in ("regressions", "improved", "ok")
-            for e in report[sec]
-        }
-        assert ("pairing_p50_ms", "cpu/cios") in keys, report
-        assert ("pairing_p50_ms", "cpu/rns") in keys, report
-        assert ("rns_conversions_per_pairing", "cpu/rns") in keys, report
-        regressed = {(e["metric"], e["backend"])
-                     for e in report["regressions"]}
-        assert regressed == {("pairing_p50_ms", "cpu/cios")}, (
-            f"per-fp pairing keying broken: {report}"
-        )
-
-        # cios-only pairing history must REFUSE to judge an rns row
-        fresh2 = os.path.join(d, "fresh2.json")
-        with open(fresh2, "w") as f:
-            json.dump(rec("pairing_p50_ms", "rns", 1000.0), f)
-        for i in range(2):
-            with open(os.path.join(d, f"PONLY_h{i}.json"), "w") as f:
-                json.dump(rec("pairing_p50_ms", "cios", 120.0 + i), f)
-        r2 = subprocess.run(
-            [
-                sys.executable,
-                os.path.join(REPO, "scripts", "bench_check.py"),
-                "--history", os.path.join(d, "PONLY_*.json"),
-                "--fresh", fresh2, "--json", report_path,
-            ],
-            capture_output=True, text=True, cwd=REPO,
-        )
-        assert r2.returncode == 0, (r2.stdout, r2.stderr[-2000:])
-        report2 = json.load(open(report_path))
-        assert report2["skipped"] and "cross-backend" in (
-            report2["skipped"][0]["reason"]
-        ), report2
-    print("rns_smoke: bench_check keys pairing_p50_ms per fp_backend "
-          "(cross-backend judgment refused)")
-
-
 def check_resident_pairing_full(stack) -> None:
     """--full only: the resident pairing NUMERICALLY vs the CIOS oracle —
     valid + forged candidates through both launch classes. Minutes of XLA
@@ -450,11 +278,9 @@ def main() -> int:
     check_bit_exact()
     check_crt_roundtrip()
     check_toml_plumbing()
-    check_bench_check_dry_run()
     stack = _pairing_stack()
     check_resident_conversions(stack)
     check_resident_tower_bit_exact(stack)
-    check_pairing_bench_gate()
     if full:
         check_resident_pairing_full(stack)
     print("rns_smoke: OK")

@@ -2,7 +2,8 @@
 
 Runs the `sim swarm` orchestrator multi-process with the hierarchical
 roll-up plane on (handel_tpu/obs/rollup.py), and asserts the acceptance
-surface in three acts:
+surface in two acts, then writes rollup_report.json (the three roll-up
+figures flat: fleet_series_count, rollup_bytes_per_host_s, fleet_eval_ms):
 
 1. **boundedness** — the master's merged series count must stay under a
    bound that depends on the key union, never the identity count, and the
@@ -11,11 +12,6 @@ surface in three acts:
    into a fresh `FleetRollup` feeding an `AlertPlane` on a manual clock;
    one forced host loss must open EXACTLY ONE incident whose attribution
    names the lost host, and recovery must close it.
-3. **regression gate** — the run writes a bench-record-shaped
-   rollup_report.json carrying the three SIDE_METRICS flat
-   (fleet_series_count, rollup_bytes_per_host_s, fleet_eval_ms) and hands
-   it to scripts/bench_check.py --dry-run against any committed history
-   (results/rollup_report*.json).
 
 Usage: python scripts/rollup_smoke.py [--artifact-dir DIR]
        [--identities N] [--processes M] [--series-bound K]
@@ -27,7 +23,6 @@ import argparse
 import asyncio
 import json
 import os
-import subprocess
 import sys
 import tempfile
 import time
@@ -38,8 +33,6 @@ from handel_tpu.obs import AlertPlane  # noqa: E402
 from handel_tpu.obs.rollup import FleetRollup  # noqa: E402
 from handel_tpu.sim.config import AlertParams, SimConfig, SwarmParams  # noqa: E402
 from handel_tpu.swarm.driver import run_swarm  # noqa: E402
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def host_loss_drill(digests: list[dict]) -> None:
@@ -142,12 +135,8 @@ def main(argv=None) -> int:
                 digests.append(json.load(f))
         host_loss_drill(digests)
 
-        # -- act 3: the bench-record artifact + regression gate ------------
+        # -- the artifact --------------------------------------------------
         record = {
-            "metric": "fleet_series_count",
-            "value": series,
-            "unit": "series",
-            "backend": "cpu",
             "captured_at": time.strftime(
                 "%Y-%m-%dT%H:%M:%SZ", time.gmtime()
             ),
@@ -167,15 +156,6 @@ def main(argv=None) -> int:
         with open(report_path, "w") as f:
             json.dump(record, f, indent=1)
             f.write("\n")
-        rc = subprocess.call([
-            sys.executable,
-            os.path.join(REPO, "scripts", "bench_check.py"),
-            "--history",
-            os.path.join(REPO, "results", "rollup_report*.json"),
-            "--fresh", report_path,
-            "--dry-run",
-        ])
-        assert rc == 0, "bench_check --dry-run failed on the rollup report"
 
         print(
             f"rollup smoke OK: {args.identities} identities / "

@@ -356,3 +356,31 @@ def test_rlc_bisection_isolates_culprits_and_matches_per_candidate_penalties():
     for origin in origins:
         assert a.score(origin) == b.score(origin), origin
     assert a.score(4) > 0 and a.score(7) > 0
+
+
+@pytest.mark.parametrize("c,m", [(8, 1), (8, 4)])
+def test_rlc_combined_check_costs_m_plus_one_miller_lanes(c, m):
+    """The RLC pairing-work contract (models/rlc.py): one combined check of
+    C candidates over M distinct messages issues M + 1 Miller lanes and one
+    final exponentiation — per candidate it would be 2C and C — whether it
+    accepts or, with one forged aggregate among them, rejects."""
+    from handel_tpu.models import rlc
+    from handel_tpu.models.bn254 import BN254Scheme
+
+    scheme = BN254Scheme()
+    ops = rlc.host_ops_for(scheme.constructor)
+    keys = [scheme.keygen(i) for i in range(c)]
+    msgs = [b"rlc-count-%d" % (j % m) for j in range(c)]
+    cands = [
+        (msg, pk.point, sk.sign(msg).point)
+        for msg, (sk, pk) in zip(msgs, keys)
+    ]
+    for forged, want in ((False, True), (True, False)):
+        if forged:
+            sk, pk = keys[c - 1]
+            cands[c - 1] = (
+                msgs[c - 1], pk.point, forged_signature(sk, msgs[c - 1]).point
+            )
+        st = rlc.RlcStats()
+        assert rlc.host_rlc_check(ops, cands, random.Random(c + m), st) is want
+        assert (st.miller_lanes, st.final_exp_lanes) == (m + 1, 1)

@@ -1,6 +1,7 @@
 """Chip bring-up contracts that need no chip and compile no pairing:
 strict device selection, one owner per chip, the one compile-cache helper,
-and the entry points that must FAIL without a TPU (chip_smoke.py, bench.py).
+and the entry points that must FAIL without a TPU (chip_smoke.py,
+benchmark/run.py).
 """
 
 import json
@@ -43,18 +44,28 @@ def test_chip_smoke_fails_at_once_without_tpu(argv):
     assert phases == ["device", "failed"], phases
 
 
-def test_bench_fails_without_chip(tmp_path):
-    """bench.py with no chip and no force hook: non-zero, no line."""
+def _cells():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return [w["name"] for w in json.load(f)["workloads"]]
+
+
+@pytest.mark.parametrize("cell", _cells())
+def test_benchmark_run_refuses_without_tpu(cell, tmp_path):
+    """benchmark/run.py under JAX_PLATFORMS=cpu: exit 1 at the device line,
+    before any key is generated, and no result line — a time from another
+    backend is not a measurement."""
     r = _run(
-        ["bench.py"],
+        ["benchmark/run.py", "--workload", cell, "--seed", "1",
+         "--seconds", "1"],
         {"JAX_PLATFORMS": "cpu",
-         "HANDEL_TPU_BENCH_ARTIFACT": str(tmp_path / "a.json")},
-        drop=("HANDEL_TPU_BENCH_FORCE_ACCEL_SHAPE",),
+         "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")},
     )
-    assert r.returncode != 0
-    assert r.stdout.strip() == ""
-    assert "no TPU" in r.stderr
-    assert not (tmp_path / "a.json").exists()
+    assert r.returncode == 1
+    lines = r.stdout.strip().splitlines()
+    assert lines[-1].startswith("run failed, no result")
+    assert "JAX found no TPU" in lines[-1]
+    phases = [json.loads(l).get("phase") for l in lines[:-1]]
+    assert phases == ["device"], phases
 
 
 # -- the compile-cache helper ------------------------------------------------

@@ -3,7 +3,7 @@
 The device packer (`BN254Device._pack_requests`) builds every launch input —
 range bounds, missing-signer patch, dense mask, packed signature limbs —
 with array-at-once numpy ops over the batch. It must be BIT-IDENTICAL to
-the old per-candidate loop (`_pack_requests_loop`, kept as the oracle) for
+the old per-candidate loop (`pack_requests_loop` below, the oracle) for
 every signer-set shape: contiguous ranges, ranges with holes in every
 quantization class (8, 64 and, where the registry has one, the wide class
 of n // 4), scattered sets past the widest patch, empty bitsets, point-less
@@ -13,6 +13,7 @@ Fast tier: packing is pure host numpy — nothing here compiles a kernel.
 """
 
 import random
+from functools import partial
 
 import numpy as np
 import pytest
@@ -20,13 +21,71 @@ import pytest
 from handel_tpu import native as nat
 from handel_tpu.core.bitset import BitSet
 from handel_tpu.models.bn254 import BN254PublicKey, BN254Signature
-from handel_tpu.models.bn254_jax import BN254Device
+from handel_tpu.models.bn254_jax import BN254Device, LaunchPlan
 from handel_tpu.ops import bn254_ref as bn
 from handel_tpu.ops.fp import Field
 
 N = 130  # > MISS_CAP + 3 so the dense fallback class is reachable
 N_WIDE = 520  # n // 4 = 130 > MISS_CAP: a registry with a wide class
 C = 8
+
+
+def pack_requests_loop(device, requests) -> LaunchPlan:
+    """The per-candidate packer `_pack_requests` replaced, kept as its
+    oracle. Allocates fresh arrays (no staging); the launch class comes
+    from the device's own `_patch_width`, so the ladder has one rule."""
+    C = device.batch_size
+    F = device.curves.F
+    sig_pts = []
+    valid = np.zeros((C,), dtype=bool)
+    sets: list[np.ndarray] = []
+    for j, (bs, sig) in enumerate(requests):
+        if len(bs) != device.n:
+            raise ValueError("bitset length != registry size")
+        idx = np.fromiter(bs.indices(), dtype=np.int64)
+        sig_pt = getattr(sig, "point", None)
+        if idx.size and sig_pt is not None:
+            valid[j] = True
+            sig_pts.append(sig_pt)
+        else:
+            sig_pts.append(device.ref.G1_GEN)  # placeholder, lane masked out
+        sets.append(idx)
+    sig_pts += [device.ref.G1_GEN] * (C - len(sig_pts))  # pad lanes
+    sig_x = F.pack([p[0] for p in sig_pts])
+    sig_y = F.pack([p[1] for p in sig_pts])
+
+    holes = [
+        int(idx[-1] - idx[0] + 1 - idx.size) if v and idx.size else 0
+        for idx, v in zip(sets, valid)
+    ]
+    miss_k = device._patch_width(max(holes, default=0))
+    if not miss_k:
+        mask = np.zeros((device.n, C), dtype=bool)
+        for j, idx in enumerate(sets):
+            if valid[j] and idx.size:
+                mask[idx, j] = True
+        return LaunchPlan(
+            "dense", 0, None, None, None, None, None, mask,
+            sig_x, sig_y, valid,
+        )
+    lo = np.zeros((C,), np.int32)
+    hi = np.zeros((C,), np.int32)
+    miss_idx = np.zeros((miss_k, C), np.int64)
+    miss_ok = np.zeros((miss_k, C), dtype=bool)
+    for j, idx in enumerate(sets):
+        if not valid[j] or not idx.size:
+            continue
+        lo[j] = idx[0]
+        hi[j] = idx[-1] + 1
+        missing = np.setdiff1d(
+            np.arange(idx[0], idx[-1] + 1), idx, assume_unique=True
+        )
+        miss_idx[: missing.size, j] = missing
+        miss_ok[: missing.size, j] = True
+    return LaunchPlan(
+        "range", miss_k, lo, hi, miss_idx, miss_ok, None, None,
+        sig_x, sig_y, valid,
+    )
 
 
 @pytest.fixture(scope="module", params=["per_candidate", "rlc", "wide"])
@@ -134,7 +193,7 @@ def test_pack_requests_matches_loop_property(device):
             for _ in range(rng.randrange(1, C + 1))
         ]
         vec = _snap(device._pack_requests(reqs))
-        loop = device._pack_requests_loop(reqs)
+        loop = pack_requests_loop(device, reqs)
         _assert_plans_equal(vec, loop, trial, device.n)
         seen.add((vec.kind, vec.miss_k))
     # every class of the registry's ladder was drawn
@@ -168,13 +227,13 @@ def test_pack_requests_rotation_boundary_property(device):
                 # the PREVIOUS plan's views survived this pack (other set)
                 _assert_plans_equal(
                     _snap(prev[1]),
-                    device._pack_requests_loop(prev[0]),
+                    pack_requests_loop(device, prev[0]),
                     trial,
                     n,
                 )
             prev = (reqs, plan)
         _assert_plans_equal(
-            _snap(prev[1]), device._pack_requests_loop(prev[0]), trial, n
+            _snap(prev[1]), pack_requests_loop(device, prev[0]), trial, n
         )
 
 
@@ -207,7 +266,8 @@ def test_pack_requests_class_selection(device):
         assert device.patch_widths == (8, 64)
         ladder += [(65, "dense", 0), (n - 2, "dense", 0)]
     for n_holes, kind, miss_k in ladder:
-        for pack in (device._pack_requests, device._pack_requests_loop):
+        for pack in (device._pack_requests,
+                     partial(pack_requests_loop, device)):
             plan = pack([req_with_holes(n_holes)])
             assert (plan.kind, plan.miss_k) == (kind, miss_k), n_holes
             if kind == "range":
@@ -237,7 +297,7 @@ def test_pack_requests_rejects_wrong_length(device):
     with pytest.raises(ValueError, match="bitset length"):
         device._pack_requests([(bs, BN254Signature(bn.G1_GEN))])
     with pytest.raises(ValueError, match="bitset length"):
-        device._pack_requests_loop([(bs, BN254Signature(bn.G1_GEN))])
+        pack_requests_loop(device, [(bs, BN254Signature(bn.G1_GEN))])
 
 
 def test_field_pack_batch_matches_pack():

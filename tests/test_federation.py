@@ -399,7 +399,7 @@ def test_load_run_e2e_with_kill_drill(tmp_path):
     assert kill["unhealthy_detected_s"] >= kill["killed_at_s"]
     assert kill["recovery_s"] is not None
     assert kill["post_recovery_completed"] > 0
-    # SIDE_METRICS keys sit flat on the record for bench_check
+    # the headline figures sit flat on the record
     for key in ("open_loop_p99_s", "region_recovery_s", "spillover_rate"):
         assert isinstance(report[key], (int, float))
     assert (tmp_path / "federation_report.json").exists()

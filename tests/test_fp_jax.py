@@ -2,7 +2,8 @@
 
 SURVEY.md §7 step 1: property tests of the Montgomery limb kernels against
 ops/bn254_ref.py. Runs on CPU (pure-XLA path); the Pallas TPU path shares the
-same `_mul_cols` body and is exercised by bench.py on hardware.
+same `_mul_cols` body; tests/test_chip_compile.py sends it through the
+chip's compiler and the benchmark's cells run it on the chip.
 
 The `F` fixture is parametrized over the Field backend seam (ops/fp.py):
 every property runs against BOTH the CIOS kernel and the RNS Montgomery

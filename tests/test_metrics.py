@@ -1,23 +1,19 @@
 """Live telemetry plane tests (ISSUE 5): exposition format, health/readiness
 transitions, registry scrapes over a traced LocalCluster, port hygiene,
-explicit gauge declarations, and the bench regression gate."""
+and explicit gauge declarations."""
 
 from __future__ import annotations
 
 import asyncio
 import json
 import os
-import sys
 import time
 import urllib.error
 import urllib.request
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "scripts"))
-
-from handel_tpu.core.metrics import (  # noqa: E402
+from handel_tpu.core.metrics import (
     MetricsRegistry,
     MetricsServer,
     is_gauge_key,
@@ -26,10 +22,8 @@ from handel_tpu.core.metrics import (  # noqa: E402
     parse_exposition,
     snake,
 )
-from handel_tpu.core.test_harness import LocalCluster  # noqa: E402
-from handel_tpu.core.trace import FlightRecorder, LogHistogram  # noqa: E402
-
-import bench_check  # noqa: E402  (scripts/bench_check.py)
+from handel_tpu.core.test_harness import LocalCluster
+from handel_tpu.core.trace import FlightRecorder, LogHistogram
 
 
 def _get(addr: str, path: str, timeout: float = 3.0):
@@ -498,84 +492,6 @@ def test_watch_discovers_endpoints(tmp_path):
     (tmp_path / "metrics_5.addr").write_text("127.0.0.1:9102\n")
     eps = watch_cli.discover_endpoints(str(tmp_path))
     assert eps == ["127.0.0.1:9100", "127.0.0.1:9101", "127.0.0.1:9102"]
-
-
-# -- bench regression gate ----------------------------------------------------
-
-
-def _bench_rec(value, backend="tpu", metric="4096sig_batch_verify_p50_ms",
-               **extra):
-    return {"metric": metric, "value": value, "unit": "ms",
-            "backend": backend, **extra}
-
-
-def test_bench_check_improvement_and_ok():
-    history = [_bench_rec(v) for v in (100.0, 104.0, 98.0)]
-    report = bench_check.detect_regressions(history, _bench_rec(90.0))
-    assert not report["regressions"]
-    assert report["improved"][0]["metric"] == "4096sig_batch_verify_p50_ms"
-    # within threshold: ok, not a regression
-    report = bench_check.detect_regressions(history, _bench_rec(110.0))
-    assert not report["regressions"] and report["ok"]
-
-
-def test_bench_check_flags_25pct_regression():
-    history = [_bench_rec(v) for v in (100.0, 104.0, 98.0)]
-    report = bench_check.detect_regressions(history, _bench_rec(125.0))
-    assert len(report["regressions"]) == 1
-    entry = report["regressions"][0]
-    assert entry["backend"] == "tpu"
-    assert entry["degradation"] == pytest.approx(0.25, abs=0.01)
-    # higher-is-better direction: a dropping dedup rate regresses too
-    history = [_bench_rec(100.0, dedup_hit_rate=0.9) for _ in range(3)]
-    fresh = _bench_rec(100.0, dedup_hit_rate=0.5)
-    report = bench_check.detect_regressions(history, fresh)
-    assert any(e["metric"] == "dedup_hit_rate"
-               for e in report["regressions"])
-
-
-def test_bench_check_skips_cross_backend():
-    """A TPU-persisted history must never judge a CPU-fallback number."""
-    history = [_bench_rec(v, backend="tpu") for v in (100.0, 101.0, 99.0)]
-    fresh = _bench_rec(
-        500.0, backend="cpu", metric="4096sig_batch_verify_p50_ms"
-    )
-    report = bench_check.detect_regressions(history, fresh)
-    assert not report["regressions"]
-    assert report["skipped"]
-    assert "cross-backend" in report["skipped"][0]["reason"]
-
-
-def test_bench_check_ignores_forced_and_invalid():
-    rec = _bench_rec(5.0, forced_shape=True)
-    assert bench_check.extract_metrics(rec) == {}
-    wrapped = {"n": 3, "rc": 0, "parsed": None}
-    assert bench_check.normalize(wrapped) is None
-    assert bench_check.normalize({"n": 1, "parsed": _bench_rec(7.0)})[
-        "value"
-    ] == 7.0
-
-
-def test_bench_check_cli_gate_and_dry_run(tmp_path):
-    for i, v in enumerate((100.0, 102.0, 98.0)):
-        (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-            json.dumps({"n": i, "rc": 0, "parsed": _bench_rec(v)})
-        )
-    fresh = tmp_path / "bench_device.json"
-    fresh.write_text(json.dumps(_bench_rec(130.0)))
-    argv = [
-        "--history", str(tmp_path / "BENCH_*.json"),
-        "--fresh", str(fresh),
-    ]
-    assert bench_check.main(argv) == 1  # 30% regression: gate fails
-    assert bench_check.main(argv + ["--dry-run"]) == 0
-    fresh.write_text(json.dumps(_bench_rec(101.0)))
-    assert bench_check.main(argv) == 0
-    # missing fresh artifact: hard error unless dry-run
-    argv_missing = ["--history", str(tmp_path / "BENCH_*.json"),
-                    "--fresh", str(tmp_path / "nope.json")]
-    assert bench_check.main(argv_missing) == 2
-    assert bench_check.main(argv_missing + ["--dry-run"]) == 0
 
 
 # -- localhost platform end to end --------------------------------------------

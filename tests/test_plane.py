@@ -115,11 +115,12 @@ def test_lane_values_shape():
     assert vals["load"] == 0.0
 
 
-def test_service_fleet_uses_every_lane():
-    """A flood of distinct aggregates over a 4-lane plane must reach every
+@pytest.mark.parametrize("k", [1, 4, 8])
+def test_service_fleet_uses_every_lane(k):
+    """A flood of distinct aggregates over a k-lane plane must reach every
     lane (least-loaded spreads; no lane starves) and keep the scheduler
-    audit clean."""
-    plane = _plane(4)
+    audit clean: no pick left an idle lane while another queued."""
+    plane = _plane(k)
 
     async def go():
         svc = BatchVerifierService(plane, max_delay_ms=0.1)
@@ -129,7 +130,7 @@ def test_service_fleet_uses_every_lane():
                     svc.verify(
                         i.to_bytes(2, "big"), PKS, [_req(i)], session="s"
                     )
-                    for i in range(64)
+                    for i in range(16 * k)
                 )
             )
             return out, svc.values()
@@ -139,7 +140,8 @@ def test_service_fleet_uses_every_lane():
     out, vals = asyncio.run(go())
     assert all(v == [True] for v in out)
     assert all(lane.engine.dispatched >= 1 for lane in plane.lanes)
-    assert vals["devicesTotal"] == 4.0
+    assert vals["devicesTotal"] == float(k)
+    assert plane.idle_violations == 0
     assert vals["schedIdleViolations"] == 0.0
     assert sum(lane.launches for lane in plane.lanes) == vals[
         "verifierLaunches"

@@ -268,27 +268,20 @@ def test_critical_path_covers_time_to_threshold(traced_run):
 
 
 def test_build_report_is_bench_record(traced_run):
-    """trace_report.json rides the bench_check gate: record-shaped
-    (metric/value/backend) with every side metric extractable."""
-    import sys
-
+    """trace_report.json carries its headline (metric/value/backend) and
+    every headline figure flat on the record, as numbers."""
     _, _, d = traced_run
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "scripts"))
-    try:
-        import bench_check
-    finally:
-        sys.path.pop(0)
     exports = trace_cli.load_exports([d])
     events = merge_traces(exports)["traceEvents"]
     report = trace_cli.build_report(events, exports)
     assert report["backend"] == "trace"
     assert report["metric"] == "trace_time_to_threshold_s"
     assert report["value"] > 0
-    got = bench_check.extract_metrics(report)
     for key in ("time_to_threshold_s", "critical_path_coverage",
                 "flow_linkage", "lane_occupancy"):
-        assert (key, "trace") in got, f"{key} not extracted by bench_check"
-    assert got[("critical_path_coverage", "trace")] >= 0.90
+        assert isinstance(report[key], (int, float)), key
+    assert report["time_to_threshold_s"] == report["value"]
+    assert report["critical_path_coverage"] >= 0.90
     json.dumps(report)
 
 
