@@ -1148,6 +1148,7 @@ class BN254Device:
         self.patch_holes = 0
         self.miller_steps = 0
         self.miller_add_steps = 0
+        self.miller_acc_fp_muls = 0
         self.rlc_stats = rlc.RlcStats()
 
     # widest NARROW missing-signer patch: a launch whose largest hole count
@@ -1175,10 +1176,12 @@ class BN254Device:
 
     def _count_class(self, plan) -> None:
         """One launch of `plan`'s class (see `class_launches`), and the
-        Miller-loop steps its program runs, as the pairing that built the
-        program counts them."""
+        Miller-loop steps its program runs with the base-field
+        multiplications a pair of their accumulator updates, as the pairing
+        that built the program counts them."""
         self.miller_steps += self.pairing.miller_steps
         self.miller_add_steps += self.pairing.miller_add_steps
+        self.miller_acc_fp_muls += self.pairing.miller_acc_fp_muls
         if plan.kind == "dense":
             name = "dense"
         elif plan.miss_k > self.MISS_CAP:

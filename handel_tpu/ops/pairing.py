@@ -13,6 +13,12 @@ Structure (scalar oracle: ops/bn254_ref.py `miller_loop_projective` /
     homogeneous projective coordinates on the twist E'(Fp2); each step emits a
     sparse line with Fp2 coefficients in the (1, w, w^3) slots. All scale
     factors live in Fp2 and die in the easy part of the final exponentiation.
+    The accumulator f never meets the line as a full Fp12 element: a step
+    squares f (`Tower.f12_sqr`, 36 base-field multiplications a pair) and
+    multiplies the line in by the sparse product (`Tower.f12_mul_line`, 39,
+    the coefficients' slots from `_LINE_SLOTS`), each ONE stacked
+    `Field.mul` call as the general product (54) is — 75 a doubling, 39 an
+    executed addition; `miller_acc_fp_muls` adds a loop's up.
   * **lax.scan over the 64 static bits** of 6u+2 (MSB-first, top bit
     consumed by the loop init), taken as RUNS (ops/fp.py `bit_runs`): the
     scan steps over the set bits only, and each step is an inner
@@ -150,18 +156,11 @@ class BN254Pairing:
             a = T.f2_add(a, a)
         return a
 
-    def _line_f12(self, line, batch):
-        """Sparse line -> full Fp12 element. The step formulas emit
-        (yp-term, xp-term, constant); the D-twist untwist puts them at
-        w-degree slots 0, 1, 3 (w^3 = v*w).
-
-        (Kept as a full element so the accumulator update is the single
-        stacked f12_mul launch; a 15-mul sparse multiply saves ~17% arithmetic
-        but triples the kernel-launch count — measured slower.)
-        """
-        c_yp, c_xp, c_const = line
-        z = self._Tw.f2_zero(batch)
-        return ((c_yp, z, z), (c_xp, c_const, z))
+    # where the step formulas' line coefficients (yp-term, xp-term,
+    # constant) sit in Fp12 — per half of l0 + l1 w, per power of v, an index
+    # into the line or None (ops/tower.py `f12_mul_line`). The D-twist
+    # untwist puts them at w-degrees 0, 1, 3 (w^3 = v*w).
+    _LINE_SLOTS = ((0, None, None), (1, 2, None))
 
     # -- Miller-loop steps (bn254_ref.miller_loop_projective dbl/add) --------
 
@@ -253,6 +252,19 @@ class BN254Pairing:
         bits (a zero bit's step is its doubling alone) and the tail's."""
         return sum(self._LOOP_BITS) + self._TAIL_ADDS
 
+    @property
+    def miller_acc_fp_muls(self) -> int:
+        """Base-field multiplications a pair that one Miller loop's
+        accumulator updates run, at the tower's own cost of each product:
+        every loop bit squares f and multiplies in the tangent's line,
+        every executed addition multiplies in one line more."""
+        Tw = self._Tw
+        return (
+            len(self._LOOP_BITS) * Tw.f12_sqr_fp_muls
+            + (len(self._LOOP_BITS) + self.miller_add_steps)
+            * Tw.F12_MUL_LINE_FP_MULS
+        )
+
     def miller_loop(self, p, q, mask=None):
         """Batched Miller loop: shared dbl/add scan over the family's static
         loop bits, then the family tail (`_miller_tail`).
@@ -280,14 +292,14 @@ class BN254Pairing:
             Tpt, f = carry
             f = Tw.f12_sqr(f)
             Tpt, line = self._dbl_step(Tpt, xp, yp)
-            return Tpt, Tw.f12_mul(f, self._line_f12(line, batch))
+            return Tpt, Tw.f12_mul_line(f, line, self._LINE_SLOTS)
 
         def run(carry, doublings):
             # the doublings up to and including a set bit's step, then that
             # step's addition
             Tpt, f = jax.lax.fori_loop(0, doublings, dbl, carry)
             Tpt, line = self._add_step(Tpt, (xq, yq), xp, yp)
-            return (Tpt, Tw.f12_mul(f, self._line_f12(line, batch))), None
+            return (Tpt, Tw.f12_mul_line(f, line, self._LINE_SLOTS)), None
 
         runs, tail = bit_runs(self._LOOP_BITS)
         carry = (xq, yq, Tw.f2_one(batch)), Tw.f12_one(batch)
@@ -313,9 +325,9 @@ class BN254Pairing:
         q2x, q2y = self._mm([(Tw.f2_conj(q1x, 8), g2), (Tw.f2_conj(q1y, 8), g3)])
         q2y = Tw.f2_neg(q2y, 8)  # q2 = -psi^2(Q)
         Tpt, line = self._add_step(Tpt, (q1x, q1y), xp, yp)
-        f = Tw.f12_mul(f, self._line_f12(line, batch))
+        f = Tw.f12_mul_line(f, line, self._LINE_SLOTS)
         _, line = self._add_step(Tpt, (q2x, q2y), xp, yp)
-        return Tw.f12_mul(f, self._line_f12(line, batch))
+        return Tw.f12_mul_line(f, line, self._LINE_SLOTS)
 
     # -- final exponentiation ------------------------------------------------
 
@@ -431,10 +443,8 @@ class BLS12Pairing(BN254Pairing):
     def _default_curves(cls):
         return BLS12Curves()
 
-    def _line_f12(self, line, batch):
-        c_yp, c_xp, c_const = line
-        z = self._Tw.f2_zero(batch)
-        return ((c_const, c_xp, z), (z, c_yp, z))
+    # M-type twist: w-degrees 0, 2, 3, the constant at w^0
+    _LINE_SLOTS = ((2, 1, None), (None, 0, None))
 
     def _miller_tail(self, Tpt, f, q, xp, yp, batch):
         # z < 0: f_z = 1/f_{|z|} up to final exp -> conjugate (resident:

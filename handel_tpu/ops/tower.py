@@ -6,9 +6,14 @@ independent base-field multiplications into the *batch* dimension and issues a
 single `Field.mul` call —
 
     Fp12 mul = 3 Fp6 muls = 18 Fp2 muls = 54 Fp muls  ->  ONE mont_mul at 54xB
+    Fp12 sqr = 2 Fp6 muls                = 36 Fp muls  ->  ONE mont_mul at 36xB
+    Fp12 x line (3 of 6 slots zero) = 13 Fp2 muls = 39 ->  ONE mont_mul at 39xB
 
 so the Pallas kernel's lanes stay full even for small pairing batches
-(ops/fp.py "batch stacking beats vmap"). Elements are pytrees of (nlimbs, B)
+(ops/fp.py "batch stacking beats vmap"). The last two are the Miller
+accumulator's updates (`f12_sqr`, `f12_mul_line`): a doubling step costs it
+75 multiplications a pair and an executed addition 39, where the general
+product for both cost 108 and 54. Elements are pytrees of (nlimbs, B)
 uint32 arrays: Fp2 = (c0, c1), Fp6 = (Fp2, Fp2, Fp2), Fp12 = (Fp6, Fp6).
 
 All values Montgomery-form, canonical (< p) — EXCEPT under the resident
@@ -42,6 +47,15 @@ class Tower:
     constants — P, XI, _GAMMA, and (for BN) U. Defaults to BN254
     (ops/bn254_ref.py); pass ops/bls12_381_ref for the 381-bit tower with
     xi = 1 + i."""
+
+    # base-field multiplications a lane of each stacked product: the width
+    # of its one `Field.mul` call is this times the batch
+    # (tests/test_miller_products.py holds each to the lanes really handed
+    # over; ops/pairing.py `miller_acc_fp_muls` adds them up)
+    F2_MUL_FP_MULS = 3
+    F6_MUL_FP_MULS = 6 * F2_MUL_FP_MULS
+    F12_MUL_FP_MULS = 3 * F6_MUL_FP_MULS
+    F12_MUL_LINE_FP_MULS = 13 * F2_MUL_FP_MULS
 
     def __init__(self, field: Field | None = None, params=bn):
         self.params = params
@@ -363,8 +377,108 @@ class Tower:
         c1 = tuple(self.f2_sub_many(list(zip(d, v1)), 16))
         return (c0, tuple(c1))
 
+    @property
+    def f12_sqr_fp_muls(self) -> int:
+        """Base-field multiplications a lane that `f12_sqr` hands to its one
+        `Field.mul` call: two Fp6 products, or the general product's three
+        under the resident field."""
+        if getattr(self.F, "is_resident", False):
+            return self.F12_MUL_FP_MULS
+        return 2 * self.F6_MUL_FP_MULS
+
     def f12_sqr(self, a):
-        return self.f12_mul(a, a)
+        """Complex squaring over Fp6: with v0 = a0 a1 and
+        t = (a0 + a1)(a0 + v a1) = a0^2 + v a1^2 + v0 + v v0,
+
+            c0 = t - v0 - v v0,   c1 = 2 v0
+
+        — two Fp6 products in ONE stacked f6_mul at twice the width (36x
+        batch, where `f12_mul(a, a)` stacks 54x), `v a1` by the xi add
+        chain. The values are `f12_mul(a, a)`'s, limb for limb.
+
+        Resident: the operand a0 + v a1 carries a1's bound plus the xi
+        chain's 5 bits plus one, which from the <= 2^22*p product fixed
+        point leaves f6_mul's <= 2^26*p operand budget — so the resident
+        tower keeps the general product and its bound row (HACKING.md
+        "Residue-resident pairing"); no blog literal below is ever read."""
+        if getattr(self.F, "is_resident", False):
+            return self.f12_mul(a, a)
+        a0, a1 = a
+        va1 = self.f6_mul_v(a1)
+        s = self.f2_add_many(list(zip(a0, a1)) + list(zip(a0, va1)))
+        lhs = tuple(self._f2_stack([a0[i], s[i]]) for i in range(3))
+        rhs = tuple(self._f2_stack([a1[i], s[3 + i]]) for i in range(3))
+        prod = self.f6_mul(lhs, rhs)
+        v0, t = (tuple(x) for x in zip(*(self._f2_unstack(c, 2) for c in prod)))
+        # u = v0 + v v0 and c1 = v0 + v0 in one add call, then c0 = t - u
+        w = self.f2_add_many(list(zip(v0, self.f6_mul_v(v0))) + list(zip(v0, v0)))
+        c0 = self.f2_sub_many(list(zip(t, w[:3])))
+        return (tuple(c0), tuple(w[3:]))
+
+    def f12_mul_line(self, f, line, slots):
+        """f * l for a Miller line l, sparse in Fp12: `line` holds its three
+        Fp2 coefficients and `slots` says where they sit — for each half of
+        l = l0 + l1 w, for each power of v, an index into `line` or None.
+        The two twists fill w-degrees 0, 1, 3 (D-type: ((0, None, None),
+        (1, 2, None))) or 0, 2, 3 (M-type); any placement that leaves v^2
+        empty and puts one coefficient in one half, two in the other, runs.
+
+        Karatsuba over Fp6 with the zeros left out: t0 = f0 l0, t1 = f1 l1,
+        t2 = (f0 + f1)(l0 + l1), c0 = t0 + v t1, c1 = t2 - t0 - t1. The
+        half with one coefficient S (at v^k) costs 3 Fp2 products, a * S;
+        a half with two, and the sum of the halves, 5 each:
+
+            a (b0 + b1 v) = (m0 + xi m4, m2 - m0 - m1, m1 + m3),
+            m = a0 b0, a1 b1, (a0 + a1)(b0 + b1), a2 b0, a2 b1
+
+        — all 13 in ONE stacked f2_mul (39x batch, where f12_mul of the
+        zero-padded line stacks 54x), every sum of a stage in one call.
+        The values are that f12_mul's, limb for limb.
+
+        Resident bounds (f <= 2^23*p, line coefficients <= 2^10*p): the
+        widest operands s0 + s1 <= 2^25*p and E0 + E1 <= 2^12*p; products
+        <= 2^8*p, their pair sums <= 2^9*p (the xi chain's and the first
+        sub's blog), xi-folds <= 2^14*p, the 5-product halves <= 2^15*p,
+        u = t0 + t1 <= 2^16*p (the last sub's blog): out c0 <= 2^16*p,
+        c1 <= 2^17*p."""
+        counts = [sum(i is not None for i in half) for half in slots]
+        if any(half[2] is not None for half in slots) or sorted(counts) != [1, 2]:
+            raise ValueError(f"unsupported line placement {slots!r}")
+        h = counts.index(1)  # the half with one coefficient, S at v^k
+        k = 0 if slots[h][0] is not None else 1
+        S = line[slots[h][k]]
+        D0, D1 = (line[i] for i in slots[1 - h][:2])
+        fs, fd = f[h], f[1 - h]
+        E = [D0, D1]
+        # stage 1: f0 + f1, the halves' sum and the two-coefficient half's
+        # Karatsuba sums; stage 2: the same sums of the sums
+        s0, s1, s2, E[k], fd01, D01 = self.f2_add_many(
+            list(zip(fs, fd)) + [(E[k], S), (fd[0], fd[1]), (D0, D1)]
+        )
+        s01, E01 = self.f2_add_many([(s0, s1), (E[0], E[1])])
+        lhs = [fs[0], fs[1], fs[2], fd[0], fd[1], fd01, fd[2], fd[2],
+               s0, s1, s01, s2, s2]
+        rhs = [S, S, S, D0, D1, D01, D0, D1, E[0], E[1], E01, E[0], E[1]]
+        r0, r1, r2, p0, p1, p2, p3, p4, q0, q1, q2, q3, q4 = self._f2_unstack(
+            self.f2_mul(self._f2_stack(lhs), self._f2_stack(rhs)), 13
+        )
+        p01, p13, q01, q13 = self.f2_add_many(
+            [(p0, p1), (p1, p3), (q0, q1), (q1, q3)]
+        )
+        # one xi chain: the two m4 folds, the single half's wrap when it
+        # sits at v, and t1's v^2 coordinate for c0 = t0 + v t1
+        fold = [p4, q4, p13 if h == 0 else (r1 if k else r2)] + [r2] * k
+        xp4, xq4, xt12, *xr2 = self.f2_mul_xi_many(fold, 9)
+        p1m, q1m = self.f2_sub_many([(p2, p01), (q2, q01)], 9)
+        p0x, q0x = self.f2_add_many([(p0, xp4), (q0, xq4)])
+        ts = (xr2[0], r0, r1) if k else (r0, r1, r2)
+        td, t2 = (p0x, p1m, p13), (q0x, q1m, q13)
+        t0, t1 = (ts, td) if h == 0 else (td, ts)
+        w = self.f2_add_many(
+            [(t0[0], xt12), (t0[1], t1[0]), (t0[2], t1[1])] + list(zip(ts, td))
+        )
+        c1 = self.f2_sub_many(list(zip(t2, w[3:])), 16)
+        return (tuple(w[:3]), tuple(c1))
 
     def f12_cyclo_sqr(self, a):
         """Squaring for elements of the cyclotomic subgroup G_{Phi6}(Fp2)

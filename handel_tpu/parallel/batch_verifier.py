@@ -1079,10 +1079,13 @@ class BatchVerifierService:
             "patchSlots": hc["patch_slots"],
             "patchHoles": hc["patch_holes"],
             # steps of the Miller loop the launch programs ran (tail
-            # additions included), and those whose addition executed
-            # (ops/pairing.py: the loop adds on its set bits only)
+            # additions included), those whose addition executed
+            # (ops/pairing.py: the loop adds on its set bits only), and the
+            # base-field multiplications a pair of their accumulator
+            # updates (a squaring and a sparse line product, ops/tower.py)
             "millerSteps": hc["miller_steps"],
             "millerAddSteps": hc["miller_add_steps"],
+            "millerAccFpMuls": hc["miller_acc_fp_muls"],
             # queue wait measured per candidate, push to lane hand-over
             "queueWaitMs": self.queue_wait_ms,
             "queueWaitCandidates": float(self.queue_wait_candidates),

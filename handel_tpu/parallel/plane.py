@@ -273,10 +273,11 @@ class DevicePlane:
         dispatch totals any engine may carry, and `stage_ms`, the same path
         by stage, from engines with a stage clock (core/trace.py); and how
         often each launch class engaged (`class_launches`) with the wide
-        class's patch slots and holes and the Miller loop's steps and
-        executed additions, from engines that count them."""
-        counts = ("patch_slots", "patch_holes",
-                  "miller_steps", "miller_add_steps")
+        class's patch slots and holes and the Miller loop's steps, executed
+        additions and accumulator multiplications, from engines that count
+        them."""
+        counts = ("patch_slots", "patch_holes", "miller_steps",
+                  "miller_add_steps", "miller_acc_fp_muls")
         out = {"pack_ms": 0.0, "pack_launches": 0.0,
                "dispatch_ms": 0.0, "dispatch_launches": 0.0,
                "fetch_launches": 0.0, "pack_cpu_ms": 0.0,

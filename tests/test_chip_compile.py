@@ -86,10 +86,12 @@ def mosaic_calls(compiled) -> int:
 
 # lane widths of stacked Field.mul calls read out of the lowered full-width
 # launches: 128 = one per-lane mul, 6912 = the Fp12 mul stacked 54x (the
-# most frequent width in both launches), 13824 = the widest mul of the
-# range launch, 4718592 = the dense launch's widest (4096 keys x 128 lanes
+# most frequent width in both launches), 9216 and 9984 = the Miller
+# accumulator's squaring (36x) and sparse line product (39x) over the 256
+# pairs, 13824 = the general Fp12 product over them (the widest mul of the
+# range launch), 4718592 = the dense launch's widest (4096 keys x 128 lanes
 # x 9 stacked muls of a G2 add)
-@pytest.mark.parametrize("width", [128, 6912, 13824, 4718592])
+@pytest.mark.parametrize("width", [128, 6912, 9216, 9984, 13824, 4718592])
 def test_cios_mul_bn254(shape, chip_choices, width):
     F = fp.Field(bn.P)
     assert F.use_pallas and F.nlimbs == 16
@@ -100,8 +102,8 @@ def test_cios_mul_bn254(shape, chip_choices, width):
     assert f"%fp_mul_16x{width}" in compiled.as_text()
 
 
-# 13824 = the widest mul of the BLS12-381 range launch's pairing tail too
-@pytest.mark.parametrize("width", [128, 6912, 13824])
+# the same widths of the BLS12-381 range launch's pairing tail
+@pytest.mark.parametrize("width", [128, 6912, 9216, 9984, 13824])
 def test_cios_mul_bls12_381(shape, chip_choices, width):
     F = fp.Field(bls.P)
     assert F.use_pallas and F.nlimbs == 24
@@ -203,6 +205,12 @@ def _assert_phases(compiled):
         inside = rf'op_name="jit\([^"]*/{scope}/[^"]*'
         assert re.search(inside + r'while/body/[^"]*while/body/', text), scope
         assert not re.search(r" conditional\([^\n]*" + inside, text), scope
+    # the Miller accumulator's updates over the launch's 2 x LANES pairs: a
+    # squaring (36x) and a sparse line product (39x), never the general
+    # Fp12 product (54x) at that width
+    widths = {int(w) for w in re.findall(r"%fp_mul_\d+x(\d+)", text)}
+    assert {36 * 2 * LANES, 39 * 2 * LANES} <= widths, sorted(widths)
+    assert 54 * 2 * LANES not in widths
 
 
 # The full pairing launches are minutes each (2-4 min on this sandbox): run
@@ -257,7 +265,7 @@ def test_full_range_launch_bls12_381(shape, chip_choices):
     text = compiled.as_text()
     # every Mosaic call of the launch is the 24-limb multiplication
     assert not re.search(r"%fp_mul_(?!24x)", text)
-    assert "%fp_mul_24x13824" in text
+    assert "%fp_mul_24x9984" in text
 
 
 @pytest.mark.slow

@@ -54,6 +54,8 @@ class _BN254:
     # 64 steps of 6u+2 and the two tail additions; 36 bits are set, and
     # only their steps add
     miller = (66, 38)
+    # 64 squarings (36) and 64 + 38 sparse line products (39)
+    acc_fp_muls = 6282
     sig = BN254Signature(bn.G1_GEN)
 
     @staticmethod
@@ -68,6 +70,7 @@ class _BLS12381:
     Device, limbs = BLS12381Device, 24
     # |z|: 63 steps, no tail addition; 5 bits set
     miller = (63, 5)
+    acc_fp_muls = 4920  # 63 x (36 + 39) + 5 x 39
     sig = BLS12381Signature(bls.G1_GEN)
 
     @staticmethod
@@ -237,6 +240,37 @@ def test_miller_step_counters_follow_the_loop_bits(curve, how):
     dev.reset_host_counters()
     v = svc.values()
     assert v["millerSteps"] == v["millerAddSteps"] == 0.0
+
+
+@pytest.mark.parametrize("how", ["dispatch", "dispatch_multi", "rlc"])
+def test_miller_accumulator_multiplications_a_launch(curve, how):
+    """values()["millerAccFpMuls"] gains, a launch, the base-field
+    multiplications a pair that the Miller loop's accumulator updates run,
+    as the pairing adds them up from the tower's product costs
+    (tests/test_miller_products.py holds those to the lanes handed to
+    `Field.mul`): a squaring of 36 and a sparse line product of 39 a
+    doubling, one line product an addition. A program that squared and
+    multiplied the padded line by the general product would read
+    54 x (2 x bits + additions): 8 964 / 7 074."""
+    dev = _device(curve, batch_check="rlc", rlc_rng=random.Random(1)) \
+        if how == "rlc" else _device(curve)
+    steps, adds = curve.miller
+    bits = steps - dev.pairing._TAIL_ADDS
+    assert dev.pairing.miller_acc_fp_muls == curve.acc_fp_muls \
+        == bits * (36 + 39) + adds * 39 < 54 * (2 * bits + adds)
+    svc = BatchVerifierService(dev)  # values() only: never started
+    assert svc.values()["millerAccFpMuls"] == 0.0
+    rng = random.Random(13)
+    for done in (1, 2):
+        _launch(dev, how, _requests(rng, curve))
+        v = svc.values()
+        assert v["hostDispatchLaunches"] == done
+        assert v["millerAccFpMuls"] == curve.acc_fp_muls * done
+        # what the benchmark's `miller.acc_fp_muls` divides
+        assert round(v["millerAccFpMuls"] / v["millerSteps"], 2) == round(
+            curve.acc_fp_muls / steps, 2)
+    dev.reset_host_counters()
+    assert svc.values()["millerAccFpMuls"] == 0.0
 
 
 def test_second_use_of_a_staging_set_waits_on_its_fence(curve):
