@@ -5,13 +5,18 @@ verify loop of the reference (`verifySignature`, processing.go:342-368 —
 aggregate-pubkey loop + `bn256.Pair` at bn256/cf/bn256.go:86-98) with ONE
 batched launch per candidate batch:
 
-  1. aggregate public keys = masked G2 tree-sum over the device-resident
-     registry array (ops/curve.py `masked_sum`; the reference's per-signature
-     Combine loop at processing.go:355-361),
-  2. batched product-of-pairings check
-     e(H(m), X_j) * e(-S_j, B2) == 1  for every candidate j
+  1. aggregate public keys = masked tree-sum in the KEY GROUP over the
+     device-resident registry array (ops/curve.py `masked_sum`; the
+     reference's per-signature Combine loop at processing.go:355-361),
+  2. batched product-of-pairings check, for every candidate j,
+     e(H(m), X_j) * e(-S_j, B2) == 1   (keys in G2, signatures in G1) or
+     e(X_j, H(m)) * e(-B1, S_j) == 1   (keys in G1, signatures in G2)
      with one shared final exponentiation (ops/pairing.py `pairing_check`;
      the reference's per-signature two-pairing compare, bn256/go/bn256.go:82-94).
+
+Which of the curve family's two groups holds the keys is the engine's GROUP
+BINDING (`BN254Device.key_group`), a property of the scheme's name as the
+curve is; signatures and H(m) live in the other group.
 
 Keys/signatures/wire formats are the host objects from models/bn254.py
 (cloudflare-compatible marshal); only verification moves on device. Candidate
@@ -52,9 +57,10 @@ from handel_tpu.ops.pairing import BN254Pairing
 # Device-input arrays for one launch, as the packer hands them to dispatch:
 # kind selects the kernel family ("range" = prefix-table path with a miss_k-
 # wide hole patch, miss_k one of the engine's `patch_widths`; "dense" =
-# masked registry sum); sig_* are packed limb
-# arrays; valid masks the real lanes. Array fields not used by `kind` are
-# None. `words` is the (C, W) uint64 bitset-word matrix — for a dense plan
+# masked registry sum); sig_* are the signature group's packed coordinates
+# (a limb array in G1, a pair of them in G2); valid masks the real lanes.
+# Array fields not used by `kind` are None. `words` is the (C, W) uint64
+# bitset-word matrix — for a dense plan
 # it IS the device-transfer source (the kernel unpacks the candidate masks
 # on device; no host-side (n, C) mask is ever materialized: `mask` stays
 # None; the tests' loop oracle fills it). Plans from `_pack_requests` view
@@ -99,16 +105,31 @@ class _StagingSet:
     __slots__ = ("words", "valid", "lo", "hi", "miss", "miss_ok",
                  "sig_x", "sig_y", "fence")
 
-    def __init__(self, n: int, C: int, miss_cap: int, nlimbs: int):
+    def __init__(self, n: int, C: int, miss_cap: int, nlimbs: int,
+                 sig_cols: int = 1):
         self.words = np.zeros((C, (n + 63) // 64), np.uint64)
         self.valid = np.zeros((C,), bool)
         self.lo = np.zeros((C,), np.int32)
         self.hi = np.zeros((C,), np.int32)
         self.miss = np.zeros((miss_cap, C), np.int64)
         self.miss_ok = np.zeros((miss_cap, C), bool)
-        self.sig_x = np.zeros((nlimbs, C), np.uint32)
-        self.sig_y = np.zeros((nlimbs, C), np.uint32)
+        # a signature coordinate is `sig_cols` base-field columns: one limb
+        # array in G1, a pair of them in G2
+        col = lambda: np.zeros((nlimbs, C), np.uint32)
+        coord = lambda: col() if sig_cols == 1 else tuple(
+            col() for _ in range(sig_cols))
+        self.sig_x, self.sig_y = coord(), coord()
         self.fence = None
+
+
+def _cols(elem) -> tuple:
+    """The base-field columns of one packed coordinate (Fp: the array
+    itself; Fp2: its pair)."""
+    return elem if isinstance(elem, tuple) else (elem,)
+
+
+# per-column map over packed coordinates, whichever group they belong to
+_tree = jax.tree_util.tree_map
 
 
 class _WarmupSig:
@@ -124,18 +145,25 @@ class _WarmupSig:
 class BN254Device:
     """Device-side verification engine bound to one registry.
 
-    Holds the registry's public keys as dense (nlimbs, N) G2 coordinate
-    arrays uploaded once (SURVEY.md §2.1 identity row: "registry pubkeys
-    additionally uploaded once to device memory as a dense G2 array").
+    Holds the registry's public keys as dense (nlimbs, N) key-group
+    coordinate arrays uploaded once (SURVEY.md §2.1 identity row: "registry
+    pubkeys additionally uploaded once to device memory as a dense G2
+    array").
 
-    Curve-family bindings are class attributes so the BLS12-381 device
-    (models/bls12_381_jax.py) reuses the whole launch machinery.
+    Curve-family and group bindings are class attributes so the BLS12-381
+    devices (models/bls12_381_jax.py) reuse the whole launch machinery.
     """
 
-    ref = bn  # scalar-oracle module: generators + placeholder points
+    ref = bn  # scalar-oracle module of the curve family
     Curves = BN254Curves
     Pairing = BN254Pairing
-    _hash_to_g1 = staticmethod(hash_to_g1)
+    # group binding: registry keys, prefix table, hull gather, hole patch
+    # and dense mask live in G<key_group> (`self.kg`); staging, the cached
+    # H(m) and `combine_batch` in the other group (`self.sg`). A coordinate
+    # is one limb array in G1 and an Fp2 pair of them in G2, so everything
+    # between the packer and the pairing maps over a coordinate's columns.
+    key_group = 2
+    _hash_to_sig_group = staticmethod(hash_to_g1)
 
     def __init__(
         self,
@@ -159,6 +187,22 @@ class BN254Device:
         self._rlc_rng = rlc_rng or random.SystemRandom()
         self.rlc_stats = rlc.RlcStats()
         self.curves = curves or self.Curves()
+        g1, g2 = self.curves.g1, self.curves.g2
+        self.kg, self.sg = (g2, g1) if self.key_group == 2 else (g1, g2)
+        if self.key_group == 1:
+            # the RLC launch class, the mesh pipeline and the residue-
+            # resident field were written and proven with keys in G2 only
+            for option, asked in (
+                ('batch_check="rlc"', self.batch_check == "rlc"),
+                ("mesh_devices > 1", mesh_devices > 1),
+                ('fp_backend="rns"', self.curves.F.backend == "rns"),
+            ):
+                if asked:
+                    raise ValueError(
+                        f"{type(self).__name__} keeps keys in G1 and "
+                        f"signatures in G2 and does not support {option}: "
+                        "that path exists for keys in G2 only"
+                    )
         # rns_resident toggles the residue-resident pairing form
         # (ops/pairing.py): None = auto (on exactly for the 'rns' field
         # backend), False forces per-mul CRT, True demands the rns backend
@@ -176,20 +220,15 @@ class BN254Device:
             if jax_device is not None
             else jax.device_put
         )
-        T = self.curves.T
-        pts = [pk.point for pk in registry_pubkeys]
-        if any(p is None for p in pts):
-            raise ValueError("registry public keys must be valid G2 points")
         # the registry is committed to the device ONCE, here, and every
         # launch selects from it with on-device gathers (the prefix table
         # below is derived from these arrays and lives on device too) —
         # steady-state launches perform no implicit host→device transfer
         # of registry/prefix data (pinned by tests/test_device_residency.py
         # under jax.transfer_guard)
-        self._reg_x = self._dput(T.f2_pack([p[0] for p in pts]))
-        self._reg_y = self._dput(T.f2_pack([p[1] for p in pts]))
+        self._reg_x, self._reg_y = self._put_bank(registry_pubkeys, "registry")
         # multi-chip plane (SURVEY.md §5.7): registry shards over the mesh
-        # for the masked G2 segment-sum, candidate lanes shard for the
+        # for the masked key segment-sum, candidate lanes shard for the
         # pairing check. Same host entry points — `_dispatch_one` routes to
         # a STAGED pipeline of separate executables (sharded sum / range
         # aggregation -> affine epilogue -> sharded pairing check) instead of
@@ -246,6 +285,7 @@ class BN254Device:
             )
             self._affine_kernel = jax.jit(self.curves.g2.to_affine)
             self._neg_kernel = jax.jit(self.curves.F.neg)
+            T = self.curves.T
             self._b2x = T.f2_pack([self.ref.G2_GEN[0]])
             self._b2y = T.f2_pack([self.ref.G2_GEN[1]])
         # staged-kernel cache: used by every mesh launch and by any caller
@@ -287,13 +327,7 @@ class BN254Device:
         # `_stage_plan`, no per-launch snapshot copies. See _StagingSet for
         # the rotation/fence contract.
         self.stage_sets = 2
-        self._stage = [
-            _StagingSet(
-                self.n, batch_size, self.patch_widths[-1], self.curves.F.nlimbs
-            )
-            for _ in range(self.stage_sets)
-        ]
-        self._stage_idx = 0
+        self._new_staging()
         # host cost per launch, by stage (core/trace.py StageClock; monitor
         # plane via BatchVerifierService.values): fence_wait + pack build
         # the launch plan in staging (`host_pack_ms`), stage + enqueue are
@@ -324,6 +358,31 @@ class BN254Device:
     def field_limbs(self) -> int:
         """16-bit limbs of the base field (the `fieldLimbs` gauge)."""
         return self.curves.F.nlimbs
+
+    def _put_bank(self, pubkeys, what: str):
+        """One registry bank on the device: the keys' affine coordinates as
+        key-group columns (x, y)."""
+        pts = [pk.point for pk in pubkeys]
+        if any(p is None for p in pts):
+            raise ValueError(
+                f"{what} public keys must be valid G{self.key_group} points"
+            )
+        pack = self.kg.ops.pack
+        return (
+            self._dput(pack([p[0] for p in pts])),
+            self._dput(pack([p[1] for p in pts])),
+        )
+
+    def _new_staging(self) -> None:
+        """Fresh staging sets for the current registry size."""
+        self._stage = [
+            _StagingSet(
+                self.n, self.batch_size, self.patch_widths[-1],
+                self.curves.F.nlimbs, self.sg.ops.COLS,
+            )
+            for _ in range(self.stage_sets)
+        ]
+        self._stage_idx = 0
 
     @property
     def host_pack_ms(self) -> float:
@@ -356,19 +415,19 @@ class BN254Device:
         )
         pad = lambda a: jnp.pad(a, ((0, 0), (1, 0)))  # exclusive: slot 0 = O
         return (
-            (pad(x[0]), pad(x[1])),
-            (pad(y[0]), pad(y[1])),
+            _tree(pad, x),
+            _tree(pad, y),
             jnp.pad(inf, (1, 0), constant_values=True),
         )
 
     def _prefix_table_kernel(self):
         """One executable for the whole scan + batch affine convert."""
-        g2 = self.curves.g2
+        kg = self.kg
 
         def prefix_table(reg_x, reg_y):
-            P = g2.from_affine(reg_x, reg_y)
-            pref = g2.prefix_scan(P)  # inclusive prefix sums, projective
-            return g2.to_affine(pref)
+            P = kg.from_affine(reg_x, reg_y)
+            pref = kg.prefix_scan(P)  # inclusive prefix sums, projective
+            return kg.to_affine(pref)
 
         return jax.jit(prefix_table)
 
@@ -380,18 +439,13 @@ class BN254Device:
     ) -> int:
         """Stage the NEXT validator set as a second device-resident bank
         while the active one keeps serving launches. Everything expensive —
-        the host f2 pack, the device_put, the prefix-table scan — happens
+        the host limb pack, the device_put, the prefix-table scan — happens
         here, off the launch critical path; the later `activate_staged` is
         a pointer flip between launches. Re-staging before activation
         replaces the pending bank (last staging wins). Returns the staged
         registry size."""
         t0 = time.perf_counter()
-        T = self.curves.T
-        pts = [pk.point for pk in registry_pubkeys]
-        if any(p is None for p in pts):
-            raise ValueError("staged registry keys must be valid G2 points")
-        reg_x = self._dput(T.f2_pack([p[0] for p in pts]))
-        reg_y = self._dput(T.f2_pack([p[1] for p in pts]))
+        reg_x, reg_y = self._put_bank(registry_pubkeys, "staged registry")
         prefix = None
         if build_prefix:
             prefix = self._build_prefix(reg_x, reg_y)
@@ -400,11 +454,12 @@ class BN254Device:
         else:
             jax.block_until_ready(reg_y)
         self._staged = {
-            "reg_x": reg_x, "reg_y": reg_y, "n": len(pts), "prefix": prefix,
+            "reg_x": reg_x, "reg_y": reg_y, "n": len(registry_pubkeys),
+            "prefix": prefix,
         }
         self.registry_stagings += 1
         self.registry_staged_ms += (time.perf_counter() - t0) * 1e3
-        return len(pts)
+        return len(registry_pubkeys)
 
     def activate_staged(self) -> int:
         """Flip the staged bank live — the caller quiesces launches around
@@ -432,14 +487,7 @@ class BN254Device:
         self._prefix_cache = st["prefix"]
         if st["n"] != self.n:
             self.n = st["n"]
-            self._stage = [
-                _StagingSet(
-                    self.n, self.batch_size, self.patch_widths[-1],
-                    self.curves.F.nlimbs,
-                )
-                for _ in range(self.stage_sets)
-            ]
-            self._stage_idx = 0
+            self._new_staging()
         self._staged = None
         self.epoch += 1
         return self.epoch
@@ -448,41 +496,37 @@ class BN254Device:
 
     def _pairing_tail(self, agg, sig_x, sig_y, h_x, h_y, valid):
         """Shared epilogue: affine-convert the aggregates and run the batched
-        product-of-pairings check  e(H, X_j) * e(-S_j, B2) == 1."""
+        product-of-pairings check over 2C lanes. The key side's lanes are
+        [aggregate X_j, generator B], the signature side's [H(m), S_j], and
+        the G1 side's second half is negated:
+        e(H, X_j) * e(-S_j, B2) == 1 with keys in G2,
+        e(X_j, H) * e(-B1, S_j) == 1 with keys in G1."""
         C = self.batch_size
-        g2 = self.curves.g2
-        T = self.curves.T
-        F = self.curves.F
+        kg = self.kg
+        neg = self.curves.F.neg
         with jax.named_scope("to_affine"):
-            agg_inf = g2.is_infinity(agg)
-            qx, qy, _ = g2.to_affine(agg)
+            agg_inf = kg.is_infinity(agg)
+            ax, ay, _ = kg.to_affine(agg)
 
-        b2 = (
-            T.f2_pack([self.ref.G2_GEN[0]] * 1),
-            T.f2_pack([self.ref.G2_GEN[1]] * 1),
-        )
-        bx = (
-            jnp.broadcast_to(b2[0][0], qx[0].shape),
-            jnp.broadcast_to(b2[0][1], qx[0].shape),
-        )
-        by = (
-            jnp.broadcast_to(b2[1][0], qy[0].shape),
-            jnp.broadcast_to(b2[1][1], qy[0].shape),
-        )
-        neg_sig_y = F.neg(sig_y)
+        wide = lambda c, like: _tree(
+            lambda a, l: jnp.broadcast_to(a, l.shape), c, like)
+        cat = lambda c, d: _tree(
+            lambda a, b: jnp.concatenate([a, b], axis=1), c, d)
+        gx = wide(kg.ops.pack([kg.gen[0]]), ax)
+        gy = wide(kg.ops.pack([kg.gen[1]]), ay)
+        if self.key_group == 2:
+            sig_y = neg(sig_y)
+        else:
+            gy = neg(gy)
         ok_lane = valid & ~agg_inf
-        px = jnp.concatenate([jnp.broadcast_to(h_x, sig_x.shape), sig_x], axis=1)
-        py = jnp.concatenate([jnp.broadcast_to(h_y, sig_y.shape), neg_sig_y], axis=1)
-        qx2 = (
-            jnp.concatenate([qx[0], bx[0]], axis=1),
-            jnp.concatenate([qx[1], bx[1]], axis=1),
-        )
-        qy2 = (
-            jnp.concatenate([qy[0], by[0]], axis=1),
-            jnp.concatenate([qy[1], by[1]], axis=1),
+        sig_side = (cat(wide(h_x, sig_x), sig_x), cat(wide(h_y, sig_y), sig_y))
+        key_side = (cat(ax, gx), cat(ay, gy))
+        p, q = (
+            (sig_side, key_side) if self.key_group == 2
+            else (key_side, sig_side)
         )
         lane_mask = jnp.concatenate([ok_lane, ok_lane])
-        checks = self.pairing.pairing_check((px, py), (qx2, qy2), lane_mask, C)
+        checks = self.pairing.pairing_check(p, q, lane_mask, C)
         return checks & ok_lane
 
     def _unpack_words(self, words32, valid):
@@ -498,10 +542,11 @@ class BN254Device:
         return bits.T.reshape(-1)
 
     def _verify_batch(self, reg_x, reg_y, words32, sig_x, sig_y, h_x, h_y, valid):
-        """General launch: masked G2 segment-sum + batched multi-pairing.
+        """General launch: masked key segment-sum + batched multi-pairing.
 
-        Shapes: reg_* (L, N) Fp2 pairs; words32 (C, 2W) uint32 packed bitset
-        words (mask unpacked on device, `_unpack_words`); sig_*/h_* (L, C);
+        Shapes: reg_* (L, N) key-group coordinates; words32 (C, 2W) uint32
+        packed bitset words (mask unpacked on device, `_unpack_words`);
+        sig_* (L, C) and h_* (L, 1 or C) signature-group coordinates;
         valid (C,) bool. Returns (C,) verdicts. The fallback for arbitrary
         signer sets — contiguous-range candidates take `_verify_batch_range`.
         """
@@ -514,21 +559,19 @@ class BN254Device:
         signer sets: the registry tiled block-major across candidates,
         masked by the bitset words, tree-summed."""
         C = self.batch_size
-        g2 = self.curves.g2
+        kg = self.kg
         mask = self._unpack_words(words32, valid)
         tile = lambda a: jnp.repeat(a, C, axis=1)  # (L, N) -> (L, N*C)
-        P2 = g2.from_affine(
-            (tile(reg_x[0]), tile(reg_x[1])), (tile(reg_y[0]), tile(reg_y[1]))
-        )
-        return g2.masked_sum(P2, mask, self.n)
+        P2 = kg.from_affine(_tree(tile, reg_x), _tree(tile, reg_y))
+        return kg.masked_sum(P2, mask, self.n)
 
     def _gather_prefix(self, prefix, idx):
-        """(C,) int32 -> projective G2 batch from the prefix table."""
-        g2 = self.curves.g2
-        (x0, x1), (y0, y1), inf = prefix
+        """(C,) int32 -> projective key-group batch from the prefix table."""
+        kg = self.kg
+        x, y, inf = prefix
         take = lambda a: jnp.take(a, idx, axis=1)
-        P = g2.from_affine((take(x0), take(x1)), (take(y0), take(y1)))
-        return g2.select(jnp.take(inf, idx), g2.infinity(idx.shape[0]), P)
+        P = kg.from_affine(_tree(take, x), _tree(take, y))
+        return kg.select(jnp.take(inf, idx), kg.infinity(idx.shape[0]), P)
 
     @jax.named_scope("agg")
     def _range_aggregate(
@@ -544,20 +587,30 @@ class BN254Device:
         `self._reg_x` here would bake the construction-time bank in as a
         compile-time constant and every flip would silently keep verifying
         against the OLD validator set.)"""
-        g2 = self.curves.g2
-        hull = g2.add(
+        kg = self.kg
+        hull = kg.add(
             self._gather_prefix(prefix, hi),
-            g2.neg(self._gather_prefix(prefix, lo)),
+            kg.neg(self._gather_prefix(prefix, lo)),
         )
         if miss_k:
             take = lambda a: jnp.take(a, miss_idx, axis=1)
-            Pm = g2.from_affine(
-                (take(reg_x[0]), take(reg_x[1])),
-                (take(reg_y[0]), take(reg_y[1])),
-            )
-            msum = g2.masked_sum(Pm, miss_ok, miss_k)
-            hull = g2.add(hull, g2.neg(msum))
+            Pm = kg.from_affine(_tree(take, reg_x), _tree(take, reg_y))
+            msum = kg.masked_sum(Pm, miss_ok, miss_k)
+            hull = kg.add(hull, kg.neg(msum))
         return hull
+
+    def _agg_fp_muls(self, plan) -> int:
+        """Base-field multiplications of `plan`'s `agg` stage, from the key
+        group's cost of an addition (ops/curve.py `add_fp_muls`): the dense
+        class tree-sums n blocks of C lanes; a range class subtracts two
+        prefix slots, tree-sums its miss_k-wide patch and subtracts that."""
+        kg, C = self.kg, self.batch_size
+        if plan.kind == "dense":
+            return kg.sum_fp_muls(self.n, C)
+        subtraction = C * kg.add_fp_muls
+        if not plan.miss_k:
+            return subtraction
+        return 2 * subtraction + kg.sum_fp_muls(plan.miss_k, C)
 
     def _verify_batch_range(
         self, lo, hi, miss_idx, miss_ok, sig_x, sig_y, h_x, h_y, valid,
@@ -898,11 +951,9 @@ class BN254Device:
     def _h_point(self, msg: bytes):
         cached = self._h_cache.get(msg)
         if cached is None:
-            h = self._hash_to_g1(msg)
-            cached = (
-                self._dput(self.curves.F.pack([h[0]])),
-                self._dput(self.curves.F.pack([h[1]])),
-            )
+            h = self._hash_to_sig_group(msg)
+            pack = self.sg.ops.pack
+            cached = (self._dput(pack([h[0]])), self._dput(pack([h[1]])))
             self._h_cache[msg] = cached
         return cached
 
@@ -982,16 +1033,16 @@ class BN254Device:
     # -- batched aggregate combine (store.py merge path) --------------------
 
     def _combine_kernel(self, k: int):
-        """One masked G1 tree-sum + batch affine convert per group-width
-        class (k quantized to powers of two so a handful of executables
-        cover every merge shape). Point adds only — compiles in seconds,
-        nothing pairing-shaped."""
+        """One masked signature-group tree-sum + batch affine convert per
+        group-width class (k quantized to powers of two so a handful of
+        executables cover every merge shape). Point adds only — compiles in
+        seconds, nothing pairing-shaped."""
         fn = self._combine_kernels.get(k)
         if fn is None:
-            g1 = self.curves.g1
+            sg = self.sg
 
             def kern(px, py, pz, mask):
-                return g1.to_affine(g1.masked_sum((px, py, pz), mask, k))
+                return sg.to_affine(sg.masked_sum((px, py, pz), mask, k))
 
             kern.__name__ = f"combine{k}"
             fn = jax.jit(kern)
@@ -999,8 +1050,8 @@ class BN254Device:
         return fn
 
     def combine_batch(self, groups, compiled_only: bool = False):
-        """Sum many groups of G1 points — aggregate-signature merges — in
-        one vmap'd launch per batch_size chunk.
+        """Sum many groups of signature-group points — aggregate-signature
+        merges — in one vmap'd launch per batch_size chunk.
 
         `groups` is a sequence of point sequences (affine scalar-oracle
         tuples, None = infinity); returns one combined affine point (or
@@ -1041,15 +1092,9 @@ class BN254Device:
             for i, p in enumerate(g):
                 flat[i * C + j] = p
                 mask[i, j] = p is not None
-        P = self.curves.pack_g1(flat)
-        x, y, inf = self._combine_kernel(k)(*P, jnp.asarray(mask.reshape(-1)))
-        F = self.curves.F
-        xs = F.unpack(x)
-        ys = F.unpack(y)
-        infs = np.asarray(inf)
-        return [
-            None if infs[j] else (xs[j], ys[j]) for j in range(len(groups))
-        ]
+        P = self.sg.pack(flat)
+        out = self._combine_kernel(k)(*P, jnp.asarray(mask.reshape(-1)))
+        return self.sg.unpack_affine(*out)[: len(groups)]
 
     def warmup(self, multi_msg: bool = False) -> int:
         """Compile every kernel a verification round can reach, up front.
@@ -1085,7 +1130,7 @@ class BN254Device:
             # the whole registry as hull, n-2 holes -> dense masked-sum
             # fallback (past the wide class where there is one)
             shapes.append([0, self.n - 1])
-        sig = _WarmupSig(self.ref.G1_GEN)
+        sig = _WarmupSig(self.sg.gen)
         launches = 0
         for signers in shapes:
             bs = BitSet(self.n)
@@ -1129,7 +1174,7 @@ class BN254Device:
         # (combine_batch(compiled_only=True)), so wider merges host-fold
         # instead of ever compiling mid-round
         for k in (2, 4, 8):
-            self.combine_batch([[self.ref.G1_GEN] * k])
+            self.combine_batch([[self.sg.gen] * k])
             launches += 1
         # warmup launches must not skew the host-cost telemetry
         self.reset_host_counters()
@@ -1149,6 +1194,7 @@ class BN254Device:
         self.miller_steps = 0
         self.miller_add_steps = 0
         self.miller_acc_fp_muls = 0
+        self.agg_fp_muls = 0
         self.rlc_stats = rlc.RlcStats()
 
     # widest NARROW missing-signer patch: a launch whose largest hole count
@@ -1175,10 +1221,12 @@ class BN254Device:
         return next((k for k in self.patch_widths if max_holes <= k), 0)
 
     def _count_class(self, plan) -> None:
-        """One launch of `plan`'s class (see `class_launches`), and the
+        """One launch of `plan`'s class (see `class_launches`), the
         Miller-loop steps its program runs with the base-field
         multiplications a pair of their accumulator updates, as the pairing
-        that built the program counts them."""
+        that built the program counts them, and the base-field
+        multiplications of its `agg` stage, as the key group counts them."""
+        self.agg_fp_muls += self._agg_fp_muls(plan)
         self.miller_steps += self.pairing.miller_steps
         self.miller_add_steps += self.pairing.miller_add_steps
         self.miller_acc_fp_muls += self.pairing.miller_acc_fp_muls
@@ -1193,9 +1241,10 @@ class BN254Device:
         self.class_launches[name] += 1
 
     @staticmethod
-    def _pack_sig_limbs(F, pts, out):
-        """Pack G1 coordinate limbs into staging, uniquing by point object
-        identity first: Handel traffic re-delivers the same aggregate (one
+    def _pack_sig_limbs(ops, pts, out):
+        """Pack signature coordinate limbs (`ops`: the signature group's
+        element algebra) into staging, uniquing by point object identity
+        first: Handel traffic re-delivers the same aggregate (one
         signature OBJECT fanned across lanes after dedup coalescing), so the
         bigint limb conversion — the single most expensive per-lane pack op
         — runs once per distinct point, then scatters by fancy index."""
@@ -1208,10 +1257,12 @@ class BN254Device:
                 i = uniq[id(p)] = len(upts)
                 upts.append(p)
             inv[j] = i
-        ux = F.pack_batch_np([p[0] for p in upts])
-        uy = F.pack_batch_np([p[1] for p in upts])
-        out.sig_x[:] = ux[:, inv]
-        out.sig_y[:] = uy[:, inv]
+        ux = ops.pack_np([p[0] for p in upts])
+        uy = ops.pack_np([p[1] for p in upts])
+        for dst, src in zip(
+            _cols(out.sig_x) + _cols(out.sig_y), _cols(ux) + _cols(uy)
+        ):
+            dst[:] = src[:, inv]
 
     # all-ones uint64, for the hull word-mask construction below
     _U64_ONES = np.uint64(0xFFFFFFFFFFFFFFFF)
@@ -1307,12 +1358,10 @@ class BN254Device:
 
         # lanes with a point but an empty bitset stay masked placeholders,
         # like the old loop (valid gating covers both cases)
-        pts = [
-            pt if valid[j] else self.ref.G1_GEN
-            for j, pt in enumerate(sig_pts)
-        ]
-        pts += [self.ref.G1_GEN] * (C - k)  # pad lanes
-        self._pack_sig_limbs(self.curves.F, pts, st)
+        gen = self.sg.gen
+        pts = [pt if valid[j] else gen for j, pt in enumerate(sig_pts)]
+        pts += [gen] * (C - k)  # pad lanes
+        self._pack_sig_limbs(self.sg.ops, pts, st)
 
         # quantize the patch width to the few classes of `patch_widths`, so
         # that no more range kernels ever compile (each variant jit-compiles
@@ -1496,9 +1545,9 @@ class BN254Device:
         per-lane h matrix never pulls a device array back to the host."""
         cached = self._h_np_cache.get(msg)
         if cached is None:
-            h = self._hash_to_g1(msg)
-            F = self.curves.F
-            cached = (F.pack_batch_np([h[0]]), F.pack_batch_np([h[1]]))
+            h = self._hash_to_sig_group(msg)
+            pack_np = self.sg.ops.pack_np
+            cached = (pack_np([h[0]]), pack_np([h[1]]))
             self._h_np_cache[msg] = cached
         return cached
 
@@ -1517,17 +1566,12 @@ class BN254Device:
                 i = uniq[m] = len(cols)
                 cols.append(self._h_cols(m))
             inv[j] = i
-        hx = np.concatenate([c[0] for c in cols], axis=1)[:, inv]
-        hy = np.concatenate([c[1] for c in cols], axis=1)[:, inv]
-        if len(msgs) < C:
-            # padded lanes are masked invalid; any finite h keeps the math
-            # well-defined, so repeat the last real column
-            hx = np.concatenate(
-                [hx, np.repeat(hx[:, -1:], C - len(msgs), axis=1)], axis=1
-            )
-            hy = np.concatenate(
-                [hy, np.repeat(hy[:, -1:], C - len(msgs), axis=1)], axis=1
-            )
+        # padded lanes are masked invalid; any finite h keeps the math
+        # well-defined, so they repeat the last real column
+        inv = np.concatenate([inv, np.full((C - len(msgs),), inv[-1])])
+        lanes = lambda *col: np.concatenate(col, axis=1)[:, inv]
+        hx = _tree(lanes, *(c[0] for c in cols))
+        hy = _tree(lanes, *(c[1] for c in cols))
         return self._dput(hx), self._dput(hy)
 
     def dispatch_multi(self, items):
@@ -1649,7 +1693,7 @@ class BN254JaxConstructor(BN254Constructor):
 
     def device_combine(self, groups):
         """Batched aggregate combine for `core/processing.py CombineShim`:
-        sum each group of G1 signature points in one device launch. Returns
+        sum each group of signature points in one device launch. Returns
         None (caller falls back to host serial combine) until the device
         exists — the shim must never force an eager registry upload — or
         when the breaker has the device offline."""
@@ -1689,6 +1733,8 @@ class BN254JaxScheme(BN254Scheme):
     wire formats (incl. unmarshal_public/unmarshal_secret for the registry
     CSV) with the device-verification constructor swapped in."""
 
+    Constructor = BN254JaxConstructor
+
     def __init__(
         self,
         batch_size: int = 16,
@@ -1698,7 +1744,7 @@ class BN254JaxScheme(BN254Scheme):
         rns_resident: bool | None = None,
         batch_check: str = "per_candidate",
     ):
-        self.constructor = BN254JaxConstructor(
+        self.constructor = self.Constructor(
             batch_size=batch_size,
             mesh_devices=mesh_devices,
             warmup=warmup,

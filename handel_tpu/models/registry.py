@@ -48,6 +48,18 @@ def _bls12_381_jax(**kw):
     return BLS12381JaxScheme(**kw)
 
 
+def _bls12_381_minpk(**kw):
+    from handel_tpu.models.bls12_381 import MinPkScheme
+
+    return MinPkScheme()
+
+
+def _bls12_381_minpk_jax(**kw):
+    from handel_tpu.models.bls12_381_jax import BLS12381MinPkJaxScheme
+
+    return BLS12381MinPkJaxScheme(**kw)
+
+
 # alias -> (is_device_scheme, factory)
 _TABLE = {
     "fake": (False, _fake),
@@ -65,9 +77,16 @@ _TABLE = {
     "bls12-381-jax": (True, _bls12_381_jax),
     "bls12-381-tpu": (True, _bls12_381_jax),
     "bls12381-jax": (True, _bls12_381_jax),
+    # the same curve, keys in G1 and signatures in G2 (the BLS draft's
+    # minimal-pubkey-size; `bls12-381` is minimal-signature-size)
+    "bls12-381-minpk": (False, _bls12_381_minpk),
+    "bls12-381-minpk-jax": (True, _bls12_381_minpk_jax),
 }
 
-SCHEMES = ("fake", "bn254", "bn254-jax", "eddsa", "bls12-381", "bls12-381-jax")
+SCHEMES = (
+    "fake", "bn254", "bn254-jax", "eddsa", "bls12-381", "bls12-381-jax",
+    "bls12-381-minpk", "bls12-381-minpk-jax",
+)
 
 
 def new_scheme(name: str, **kwargs):
@@ -78,7 +97,11 @@ def new_scheme(name: str, **kwargs):
 
 
 # device factory -> the host scheme it is a keygen facade over
-_HOST_OF = {_bn254_jax: _bn254, _bls12_381_jax: _bls12_381}
+_HOST_OF = {
+    _bn254_jax: _bn254,
+    _bls12_381_jax: _bls12_381,
+    _bls12_381_minpk_jax: _bls12_381_minpk,
+}
 
 
 def new_keygen_scheme(name: str):

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from handel_tpu.ops import bls12_381_ref as _bls
 from handel_tpu.ops import bn254_ref as bn
@@ -41,11 +42,30 @@ class _FpAdapter:
     b3 = 3b for the curve constant (y^2 = x^3 + b): 9 for BN254's b = 3,
     12 for BLS12-381's b = 4 — both realized as add chains."""
 
+    # host side of an element: the scalar oracle's value of 0 and 1, the
+    # base-field columns an element is staged as, and what one element
+    # multiplication / one `mul_b3` hands to `Field.mul` (lanes a lane)
+    ZERO, ONE = 0, 1
+    COLS = 1
+    MUL_FP_MULS = 1
+    B3_FP_MULS = 0  # an add chain
+
     def __init__(self, F: Field, b3: int = 9):
         self.F = F
         self.b3 = b3
         if b3 not in (9, 12):
             raise ValueError(f"unsupported curve constant b3={b3}")
+
+    def pack(self, vals):
+        """List of scalar-oracle elements -> device element of that batch."""
+        return self.F.pack(vals)
+
+    def pack_np(self, vals):
+        """`pack` stopping at the host: the element's numpy limb columns."""
+        return self.F.pack_batch_np(vals)
+
+    def unpack(self, e):
+        return self.F.unpack(e)
 
     def add(self, a, b):
         return self.F.add(a, b)
@@ -105,12 +125,30 @@ class _FpAdapter:
 class _Fp2Adapter:
     """Quadratic-extension algebra for G2': elements are Fp2 pairs."""
 
+    ZERO, ONE = (0, 0), (1, 0)
+    COLS = 2
+    MUL_FP_MULS = Tower.F2_MUL_FP_MULS
+    B3_FP_MULS = Tower.F2_MUL_FP_MULS  # the twist's b' is no small integer
+
     def __init__(self, T: Tower, params=bn):
         self.T = T
         # E' twist coefficient b' (3/xi for BN254's D-twist, 4*xi for
         # BLS12-381's M-twist); b3 = 3*b' as a host constant
         self._b3 = params.f2_scalar(params.TWIST_B, 3)
         self._b3_packed = None
+
+    def pack(self, vals):
+        return self.T.f2_pack(vals)
+
+    def pack_np(self, vals):
+        F = self.T.F
+        return (
+            F.pack_batch_np([v[0] for v in vals]),
+            F.pack_batch_np([v[1] for v in vals]),
+        )
+
+    def unpack(self, e):
+        return self.T.f2_unpack(e)
 
     def add(self, a, b):
         return self.T.f2_add(a, b)
@@ -164,8 +202,50 @@ class Curve:
     """Batched short-Weierstrass group (y^2 = x^3 + b, a = 0) over an element
     algebra. Points are (X, Y, Z) pytrees; identity is (0, 1, 0)."""
 
-    def __init__(self, ops):
+    def __init__(self, ops, gen=None):
         self.ops = ops
+        # the group's generator as the scalar oracle writes it (affine)
+        self.gen = gen
+
+    # -- what the group's additions cost --------------------------------------
+
+    @property
+    def add_fp_muls(self) -> int:
+        """Base-field multiplications a lane of one complete `add`: its 12
+        element products and two `mul_b3`, in the lanes each hands to
+        `Field.mul` (12 in G1, 42 in G2)."""
+        o = self.ops
+        return 12 * o.MUL_FP_MULS + 2 * o.B3_FP_MULS
+
+    def sum_fp_muls(self, n: int, b: int) -> int:
+        """Base-field multiplications of `sum_points` (and `masked_sum`)
+        over n blocks of b lanes: each stage adds half the blocks left, an
+        odd count padded by one first."""
+        adds = 0
+        while n > 1:
+            n = (n + 1) // 2
+            adds += n
+        return adds * b * self.add_fp_muls
+
+    # -- host boundary: scalar-oracle points <-> device batches --------------
+
+    def pack(self, pts):
+        """List of affine scalar-oracle points (None = infinity) ->
+        projective batch."""
+        o = self.ops
+        return (
+            o.pack([o.ZERO if p is None else p[0] for p in pts]),
+            o.pack([o.ONE if p is None else p[1] for p in pts]),
+            o.pack([o.ZERO if p is None else o.ONE for p in pts]),
+        )
+
+    def unpack_affine(self, x, y, inf):
+        """Affine device batch (`to_affine`'s result) -> list of points."""
+        xs, ys, infs = self.ops.unpack(x), self.ops.unpack(y), np.asarray(inf)
+        return [None if infs[i] else (xs[i], ys[i]) for i in range(len(xs))]
+
+    def unpack(self, P):
+        return self.unpack_affine(*self.to_affine(P))
 
     # -- constructors -------------------------------------------------------
 
@@ -424,42 +504,27 @@ class BN254Curves:
         # — routes through whichever kernel the constructed Field carries.
         self.F = field or Field(self.params.P, backend=backend)
         self.T = tower or Tower(self.F, params=self.params)
-        self.g1 = Curve(_FpAdapter(self.F, b3=self.g1_b3))
-        self.g2 = Curve(_Fp2Adapter(self.T, params=self.params))
+        self.g1 = Curve(
+            _FpAdapter(self.F, b3=self.g1_b3), gen=self.params.G1_GEN
+        )
+        self.g2 = Curve(
+            _Fp2Adapter(self.T, params=self.params), gen=self.params.G2_GEN
+        )
 
     # -- host packing: scalar oracle points <-> device batches ---------------
 
     def pack_g1(self, pts):
         """List of scalar-oracle affine G1 points (or None) -> projective batch."""
-        xs = [0 if p is None else p[0] for p in pts]
-        ys = [1 if p is None else p[1] for p in pts]
-        zs = [0 if p is None else 1 for p in pts]
-        return (self.F.pack(xs), self.F.pack(ys), self.F.pack(zs))
+        return self.g1.pack(pts)
 
     def unpack_g1(self, P):
-        x, y, inf = self.g1.to_affine(P)
-        xs = self.F.unpack(x)
-        ys = self.F.unpack(y)
-        import numpy as np
-
-        infs = np.asarray(inf)
-        return [None if infs[i] else (xs[i], ys[i]) for i in range(len(xs))]
+        return self.g1.unpack(P)
 
     def pack_g2(self, pts):
-        f20, f21 = (0, 0), (1, 0)
-        xs = [f20 if p is None else p[0] for p in pts]
-        ys = [f21 if p is None else p[1] for p in pts]
-        zs = [f20 if p is None else f21 for p in pts]
-        return (self.T.f2_pack(xs), self.T.f2_pack(ys), self.T.f2_pack(zs))
+        return self.g2.pack(pts)
 
     def unpack_g2(self, P):
-        x, y, inf = self.g2.to_affine(P)
-        xs = self.T.f2_unpack(x)
-        ys = self.T.f2_unpack(y)
-        import numpy as np
-
-        infs = np.asarray(inf)
-        return [None if infs[i] else (xs[i], ys[i]) for i in range(len(xs))]
+        return self.g2.unpack(P)
 
     @staticmethod
     def scalar_bits(ks, nbits: int = 256):

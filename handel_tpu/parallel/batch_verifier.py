@@ -1086,6 +1086,10 @@ class BatchVerifierService:
             "millerSteps": hc["miller_steps"],
             "millerAddSteps": hc["miller_add_steps"],
             "millerAccFpMuls": hc["miller_acc_fp_muls"],
+            # base-field multiplications the launch programs' `agg` stage
+            # ran (prefix hull, hole patch or dense sum, in the key group:
+            # models/bn254_jax.py `_agg_fp_muls`)
+            "aggFpMuls": hc["agg_fp_muls"],
             # queue wait measured per candidate, push to lane hand-over
             "queueWaitMs": self.queue_wait_ms,
             "queueWaitCandidates": float(self.queue_wait_candidates),
@@ -1135,6 +1139,7 @@ class BatchVerifierService:
             "devicesTotal",
             "devicesAvailable",
             "fieldLimbs",
+            "keyGroup",
             "meshLanes",
             "meshLanesAvailable",
             "checkMode",

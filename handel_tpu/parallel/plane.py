@@ -274,10 +274,10 @@ class DevicePlane:
         by stage, from engines with a stage clock (core/trace.py); and how
         often each launch class engaged (`class_launches`) with the wide
         class's patch slots and holes and the Miller loop's steps, executed
-        additions and accumulator multiplications, from engines that count
-        them."""
+        additions and accumulator multiplications and the `agg` stage's
+        multiplications, from engines that count them."""
         counts = ("patch_slots", "patch_holes", "miller_steps",
-                  "miller_add_steps", "miller_acc_fp_muls")
+                  "miller_add_steps", "miller_acc_fp_muls", "agg_fp_muls")
         out = {"pack_ms": 0.0, "pack_launches": 0.0,
                "dispatch_ms": 0.0, "dispatch_launches": 0.0,
                "fetch_launches": 0.0, "pack_cpu_ms": 0.0,
@@ -331,6 +331,12 @@ class DevicePlane:
             # reader of the counters need not parse kernel names
             "fieldLimbs": float(max(
                 (getattr(l.engine, "field_limbs", 0) for l in self.lanes),
+                default=0,
+            )),
+            # ... and which group their registry keys live in (1 or 2:
+            # models/bn254_jax.py `key_group`; 0 for host stubs)
+            "keyGroup": float(max(
+                (getattr(l.engine, "key_group", 0) for l in self.lanes),
                 default=0,
             )),
             "schedPicks": float(self.sched_picks),

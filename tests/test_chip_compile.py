@@ -16,6 +16,7 @@ never at import, in a `skipif` or in `parametrize` — everything compiles in
 the test's own process, and all such tests live in this one file.
 """
 
+import hashlib
 import re
 import time
 from functools import partial
@@ -125,23 +126,34 @@ def test_rns_resident_mul(shape, chip_choices):
 
 
 def _device(n_keys: int, curve: str = "bn254"):
-    if curve == "bls12_381":
+    if curve == "bls12_381_minpk":
+        from handel_tpu.models.bls12_381 import MinPkPublicKey as Key
+        from handel_tpu.models.bls12_381_jax import BLS12381MinPkDevice as Device
+    elif curve == "bls12_381":
         from handel_tpu.models.bls12_381 import BLS12381PublicKey as Key
         from handel_tpu.models.bls12_381_jax import BLS12381Device as Device
     else:
         from handel_tpu.models.bn254 import BN254PublicKey as Key
         from handel_tpu.models.bn254_jax import BN254Device as Device
 
-    dev = Device([Key(Device.ref.G2_GEN)] * n_keys, batch_size=LANES)
+    gen = Device.ref.G2_GEN if Device.key_group == 2 else Device.ref.G1_GEN
+    dev = Device([Key(gen)] * n_keys, batch_size=LANES)
     assert dev.curves.F.use_pallas and fp.default_pow_window() == 4
     return dev
 
 
-def _bank(shape, n_keys: int, nlimbs: int = 16):
+def _coord(shape, nlimbs: int, n: int, cols: int):
+    """Shape of one packed coordinate over n lanes: a limb array in G1
+    (cols 1), an Fp2 pair of them in G2 (cols 2)."""
+    col = shape((nlimbs, n), U32)
+    return col if cols == 1 else (col,) * cols
+
+
+def _bank(shape, n_keys: int, nlimbs: int = 16, cols: int = 2):
     """Shapes of a registry bank and its prefix table (jit arguments)."""
-    f2 = lambda n: (shape((nlimbs, n), U32), shape((nlimbs, n), U32))
-    prefix = (f2(n_keys + 1), f2(n_keys + 1), shape((n_keys + 1,), BOOL))
-    return prefix, f2(n_keys), f2(n_keys)
+    key = lambda n: _coord(shape, nlimbs, n, cols)
+    prefix = (key(n_keys + 1), key(n_keys + 1), shape((n_keys + 1,), BOOL))
+    return prefix, key(n_keys), key(n_keys)
 
 
 def _range_args(shape, miss_k: int):
@@ -282,3 +294,111 @@ def test_full_dense_launch(shape, chip_choices):
     _report("dense launch", compiled)
     assert mosaic_calls(compiled) > 100
     _assert_phases(compiled)
+
+
+# -- the other group binding: keys in G1, signatures in G2 ---------------------
+# (models/bls12_381_jax.py `BLS12381MinPkDevice`, the cell
+# `bls12-381-minpk-4096-failing.closed256`: every launch `jit_verify_range1024`)
+
+
+@pytest.mark.parametrize("miss_k", [8, N_KEYS // 4])
+def test_range_aggregate_keys_in_g1(shape, chip_choices, miss_k):
+    """The G1 aggregation stage at full width: prefix gathers over (24, N)
+    single-column banks and the miss_k-wide patch's tree sum, none of which
+    had been lowered for the chip before this class."""
+    from handel_tpu.models.bn254_jax import _named
+
+    dev = _device(2, "bls12_381_minpk")
+    assert dev.key_group == 1 and dev.curves.F.nlimbs == 24
+    name = f"range_agg{miss_k}"
+    fn = jax.jit(_named(partial(dev._range_aggregate, miss_k=miss_k), name))
+    compiled = fn.lower(
+        *_range_args(shape, miss_k), *_bank(shape, N_KEYS, 24, cols=1)
+    ).compile()
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_{name}")
+    assert re.search(rf'op_name="jit\({name}\)/agg/[^"]*fp_mul_24x', text)
+    assert not re.search(r"%fp_mul_(?!24x)", text)
+    # a G1 addition stacks its 12 multiplications as 3, 3 and 6: the widest
+    # call is the tree's first stage, half the patch x lanes x 6
+    widths = {int(w) for w in re.findall(r"%fp_mul_24x(\d+)", text)}
+    assert max(widths) == miss_k // 2 * LANES * 6, sorted(widths)
+    _report(f"G1 {name}", compiled)
+
+
+# What the refactor to a group binding must not move: the launch programs of
+# the two G2-keyed classes, lowered for the described chip at the cells' own
+# shapes, are the programs the parent commit lowers. Pinned as the SHA-256 of
+# the StableHLO text with each Mosaic call's serialized body cut out (the
+# body's bytes hold source line numbers of its callers). A change that means
+# to alter a launch program — or a new jax — refreshes the pins from the
+# assertion's message; ISSUE 35's refactor compared them with the parent's
+# own lowering in one run (CHANGES.md).
+LOWERED = {
+    ("bn254", "prefix_table"):
+        "397eece2002baaa1e2232f09dfe1b11a698ed92d9fb4ed30190fefecf4ade75d",
+    ("bn254", "verify_range8"):
+        "70980f1ffbde228f7e9240597ef0c95639aeb2087c18d77e0f96aa12c557fa5c",
+    ("bn254", "verify_range1024"):
+        "d6ffaa20bbcc5f5d06d947d326b255adb1ab2e51af4190fb7614a9200f1bafc0",
+    ("bls12_381", "prefix_table"):
+        "c2bbdeafcb1ca615b5cd32b4cb7917420928e4cec2bb097a7beeaf846d975fc0",
+    ("bls12_381", "verify_range8"):
+        "33a73d96291f94cef14836681d09b45ebab6dbe71d4603f20ddb19a62583e821",
+    ("bls12_381", "verify_range1024"):
+        "84c7a1417e5ecdde8e91d4ba9ea84b80e962234924645419499f83669b4e8996",
+}
+
+
+def _lower_launch(shape, dev, program: str):
+    """The Lowered of one launch program at 4096 keys and 128 lanes."""
+    from handel_tpu.models.bn254_jax import _named
+
+    nl = dev.curves.F.nlimbs
+    cols_key, cols_sig = dev.kg.ops.COLS, dev.sg.ops.COLS
+    prefix, reg_x, reg_y = _bank(shape, N_KEYS, nl, cols_key)
+    if program == "prefix_table":
+        return dev._prefix_table_kernel().lower(reg_x, reg_y)
+    miss_k = int(program[len("verify_range"):])
+    fn = jax.jit(
+        _named(partial(dev._verify_batch_range, miss_k=miss_k), program),
+        donate_argnums=(0, 1, 2, 3, 4, 5, 8),
+    )
+    sig = _coord(shape, nl, LANES, cols_sig)
+    h = _coord(shape, nl, 1, cols_sig)
+    return fn.lower(
+        *_range_args(shape, miss_k), sig, sig, h, h, shape((LANES,), BOOL),
+        prefix, reg_x, reg_y,
+    )
+
+
+@pytest.mark.parametrize("curve, program", list(LOWERED))
+def test_launch_programs_lower_as_pinned(shape, chip_choices, curve, program):
+    text = _lower_launch(shape, _device(2, curve), program).as_text()
+    assert f"jit_{program}" in text and "tpu_custom_call" in text
+    cut = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
+    assert cut.count("BODY") == text.count("tpu_custom_call") > 0
+    got = hashlib.sha256(cut.encode()).hexdigest()
+    assert got == LOWERED[curve, program], (curve, program, got)
+
+
+# The G1-keyed launch the new cell runs, and its prefix table, through the
+# chip's compiler (minutes: by hand before a chip call, like its siblings).
+@pytest.mark.slow
+def test_full_range_launch_bls12_381_minpk(shape, chip_choices):
+    dev = _device(2, "bls12_381_minpk")
+    t0 = time.perf_counter()
+    table = _lower_launch(shape, dev, "prefix_table").compile()
+    _report(f"G1 prefix table ({time.perf_counter() - t0:.0f} s)", table)
+    assert "%fp_mul_24x" in table.as_text()
+    t0 = time.perf_counter()
+    compiled = _lower_launch(
+        shape, dev, f"verify_range{N_KEYS // 4}").compile()
+    _report(f"G1 range1024 launch ({time.perf_counter() - t0:.0f} s)", compiled)
+    assert mosaic_calls(compiled) > 100
+    _assert_phases(compiled)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_verify_range1024")
+    assert not re.search(r"%fp_mul_(?!24x)", text)
+    # the tail is the G2-keyed class's: the same accumulator products
+    assert "%fp_mul_24x9984" in text and "%fp_mul_24x9216" in text

@@ -12,15 +12,19 @@ pack, stage and fetch for real, but the pairing kernels (minutes of XLA on a
 CPU) are replaced by a jitted echo of the `valid` mask — the lifecycle is
 what is under test, not the verdicts.
 
-Every engine test runs once per device class (`curve`): BN254Device and its
-BLS12-381 binding share the launch engine, so each emits the same stages,
-counters, `seq` and names — over 16 limbs or 24 (`fieldLimbs`).
+Every engine test runs once per device class (`curve`): BN254Device, its
+BLS12-381 binding and that curve's other group binding (keys in G1,
+signatures in G2) share the launch engine, so each emits the same stages,
+counters, `seq` and names — over 16 limbs or 24 (`fieldLimbs`), with the
+registry in G2 or G1 (`keyGroup`). The G1-keyed class has no RLC launch
+class: its `rlc` cases assert the refusal.
 """
 
 import asyncio
 import glob
 import os
 import random
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -29,8 +33,13 @@ import pytest
 from handel_tpu import native as nat
 from handel_tpu.core.bitset import BitSet
 from handel_tpu.core.trace import LAUNCH_STAGES, FlightRecorder, StageClock
-from handel_tpu.models.bls12_381 import BLS12381PublicKey, BLS12381Signature
-from handel_tpu.models.bls12_381_jax import BLS12381Device
+from handel_tpu.models.bls12_381 import (
+    BLS12381PublicKey,
+    BLS12381Signature,
+    MinPkPublicKey,
+    MinPkSignature,
+)
+from handel_tpu.models.bls12_381_jax import BLS12381Device, BLS12381MinPkDevice
 from handel_tpu.models.bn254 import BN254PublicKey, BN254Signature
 from handel_tpu.models.bn254_jax import BN254Device, _named
 from handel_tpu.ops import bls12_381_ref as bls
@@ -50,7 +59,7 @@ _accept = jax.jit(lambda: jnp.ones((1,), bool))
 
 
 class _BN254:
-    Device, limbs = BN254Device, 16
+    Device, limbs, key_group = BN254Device, 16, 2
     # 64 steps of 6u+2 and the two tail additions; 36 bits are set, and
     # only their steps add
     miller = (66, 38)
@@ -67,7 +76,7 @@ class _BN254:
 
 
 class _BLS12381:
-    Device, limbs = BLS12381Device, 24
+    Device, limbs, key_group = BLS12381Device, 24, 2
     # |z|: 63 steps, no tail addition; 5 bits set
     miller = (63, 5)
     acc_fp_muls = 4920  # 63 x (36 + 39) + 5 x 39
@@ -85,7 +94,23 @@ class _BLS12381:
         return out
 
 
-@pytest.fixture(params=[_BN254, _BLS12381], ids=["bn254", "bls12_381"])
+class _BLS12381MinPk(_BLS12381):
+    """The same pairing (63 steps, 5 additions) over keys in G1."""
+    Device, key_group = BLS12381MinPkDevice, 1
+    sig = MinPkSignature(bls.G2_GEN)
+
+    @staticmethod
+    def pubkeys(n):
+        pt = bls.g1_mul(bls.G1_GEN, random.Random(5).randrange(1, 1 << 20))
+        out = []
+        for _ in range(n):
+            out.append(MinPkPublicKey(pt))
+            pt = bls.g1_add(pt, bls.G1_GEN)
+        return out
+
+
+@pytest.fixture(params=[_BN254, _BLS12381, _BLS12381MinPk],
+                ids=["bn254", "bls12_381", "bls12_381_minpk"])
 def curve(request):
     return request.param
 
@@ -97,6 +122,16 @@ def _device(curve, n=N, **kw) -> BN254Device:
     dev._rlc_msm_kernel = lambda kind, miss_k, G: (lambda *args: ())
     dev._rlc_check_kernel = lambda G: (lambda *args: _accept())
     return dev
+
+
+def _no_rlc(curve, how) -> bool:
+    """True where `how` is the RLC path and the class has none (keys in
+    G1): the engine must refuse the option at construction."""
+    if how != "rlc" or curve.key_group == 2:
+        return False
+    with pytest.raises(ValueError, match='does not support batch_check="rlc"'):
+        curve.Device(curve.pubkeys(2), batch_size=C, batch_check="rlc")
+    return True
 
 
 def _requests(rng, curve, k=C):
@@ -128,13 +163,17 @@ def _launch(dev, how: str, reqs):
 
 @pytest.mark.parametrize("how", ["dispatch", "dispatch_multi", "rlc"])
 def test_stage_counters_add_up(curve, how):
+    if _no_rlc(curve, how):
+        return
     launches = 5
     dev = _device(curve, batch_check="rlc", rlc_rng=random.Random(1)) \
         if how == "rlc" else _device(curve)
     svc = BatchVerifierService(dev)  # values() only: never started
     # which field the process serves, without parsing a kernel's name
     assert svc.values()["fieldLimbs"] == curve.limbs == dev.field_limbs
-    assert "fieldLimbs" in svc.gauge_keys()
+    # ... and which group holds the registry keys
+    assert svc.values()["keyGroup"] == curve.key_group == dev.key_group
+    assert {"fieldLimbs", "keyGroup"} <= svc.gauge_keys()
     rng = random.Random(7)
     before = svc.values()
     for _ in range(launches):
@@ -222,6 +261,8 @@ def test_miller_step_counters_follow_the_loop_bits(curve, how):
     addition executes, read off the pairing that built the program: the
     loop adds on its set bits only, so the two differ by the zero bits (a
     program that computed both and selected would report them equal)."""
+    if _no_rlc(curve, how):
+        return
     dev = _device(curve, batch_check="rlc", rlc_rng=random.Random(1)) \
         if how == "rlc" else _device(curve)
     steps, adds = curve.miller
@@ -252,6 +293,8 @@ def test_miller_accumulator_multiplications_a_launch(curve, how):
     doubling, one line product an addition. A program that squared and
     multiplied the padded line by the general product would read
     54 x (2 x bits + additions): 8 964 / 7 074."""
+    if _no_rlc(curve, how):
+        return
     dev = _device(curve, batch_check="rlc", rlc_rng=random.Random(1)) \
         if how == "rlc" else _device(curve)
     steps, adds = curve.miller
@@ -271,6 +314,67 @@ def test_miller_accumulator_multiplications_a_launch(curve, how):
             curve.acc_fp_muls / steps, 2)
     dev.reset_host_counters()
     assert svc.values()["millerAccFpMuls"] == 0.0
+
+
+def test_agg_multiplications_are_the_lanes_handed_to_field_mul(curve):
+    """values()["aggFpMuls"] gains, a launch, the base-field multiplications
+    of the launch program's `agg` stage as the key group's cost attributes
+    add them up (ops/curve.py `add_fp_muls`, `sum_fp_muls`). Held here to
+    the lanes the stage REALLY hands to `Field.mul`, for every class of a
+    520-key registry (tracing only: nothing compiles): 12 a G1 addition, 42
+    a G2 one, so the G1-keyed class reads two sevenths of the G2-keyed."""
+    n = 520
+    dev = _device(curve, n)
+    assert dev.kg.add_fp_muls == (42 if curve.key_group == 2 else 12)
+    F = dev.curves.F
+    handed = []
+    mul = F.mul
+    F.mul = lambda a, b: (handed.append(a.shape[1]), mul(a, b))[1]
+    sds = lambda a: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), a)
+    dev._prefix_cache = jax.tree_util.tree_map(  # shapes only: no scan here
+        lambda a: jnp.zeros((*a.shape[:-1], n + 1), a.dtype),
+        (dev._reg_x, dev._reg_y, jnp.zeros((n,), bool)))
+    svc = BatchVerifierService(dev)  # values() only: never started
+    sig = curve.sig
+
+    def candidate(n_holes):
+        bs = BitSet(n)
+        bs.set_range(0, 260)
+        for i in range(1, 1 + n_holes):
+            bs.set(i, False)
+        return (bs, sig)
+
+    want = 0
+    for n_holes, (kind, miss_k) in {
+        2: ("range", 8), 30: ("range", 64), 100: ("range", 130),
+        200: ("dense", 0),
+    }.items():
+        plan = dev._pack_requests([candidate(n_holes)])
+        assert (plan.kind, plan.miss_k) == (kind, miss_k)
+        staged = dev._stage_plan(plan)
+        del handed[:]
+        if kind == "range":
+            jax.eval_shape(
+                partial(dev._range_aggregate, miss_k=miss_k),
+                *sds(staged[:4]), sds(dev._prefix), sds(dev._reg_x),
+                sds(dev._reg_y))
+        else:
+            jax.eval_shape(dev._dense_aggregate, sds(dev._reg_x),
+                           sds(dev._reg_y), sds(staged[0]), sds(staged[-1]))
+        assert dev._agg_fp_muls(plan) == sum(handed) > 0, (kind, miss_k)
+        want += sum(handed)
+        before = svc.values()["aggFpMuls"]
+        _launch(dev, "dispatch", [candidate(n_holes)])
+        assert svc.values()["aggFpMuls"] - before == dev._agg_fp_muls(plan)
+    assert svc.values()["aggFpMuls"] == want
+    # the wide class of the failing-committee cells, a launch of 128 lanes:
+    # 1 023 additions of the patch's tree and the two subtractions
+    big = type("Plan", (), {"kind": "range", "miss_k": 1024})
+    dev.batch_size = 128
+    assert dev._agg_fp_muls(big) == 1025 * 128 * dev.kg.add_fp_muls
+    dev.reset_host_counters()
+    assert svc.values()["aggFpMuls"] == 0.0
 
 
 def test_second_use_of_a_staging_set_waits_on_its_fence(curve):
@@ -448,19 +552,23 @@ def test_stage_clock_without_a_listener_calls_nothing():
 
 
 def test_jitted_programs_have_stable_names(curve):
-    dev = _device(curve, batch_check="rlc")
+    rlc = {"batch_check": "rlc"} if curve.key_group == 2 else {}
+    dev = _device(curve, **rlc)
     assert dev._kernel.__name__ == "verify_dense"
     assert dev._combine_kernel(4).__name__ == "combine4"
     assert dev._prefix_table_kernel().__name__ == "prefix_table"
-    real = curve.Device(curve.pubkeys(N), batch_size=C, batch_check="rlc")
-    assert real._rlc_check_kernel(2).__name__ == "rlc_check2"
+    real = curve.Device(curve.pubkeys(N), batch_size=C, **rlc)
     jitted = lambda fn: fn.__defaults__[0]  # the bank-injection wrappers
-    assert jitted(real._rlc_msm_kernel("dense", 0, 1)).__name__ == "rlc_msm_dense"
+    if rlc:
+        assert real._rlc_check_kernel(2).__name__ == "rlc_check2"
+        assert jitted(
+            real._rlc_msm_kernel("dense", 0, 1)).__name__ == "rlc_msm_dense"
+        assert jitted(
+            real._rlc_msm_kernel("range", 8, 2)).__name__ == "rlc_msm_range8"
     # the range classes build the prefix table first: its program too
     assert jitted(real._range_agg_kernel(8)).__name__ == "range_agg8"
     assert jitted(real._range_kernel(8)).__name__ == "verify_range8"
     assert jitted(real._range_kernel(64)).__name__ == "verify_range64"
-    assert jitted(real._rlc_msm_kernel("range", 8, 2)).__name__ == "rlc_msm_range8"
     # what the profiler's "XLA Modules" line will print
     fn = jax.jit(_named(lambda x: x + 1, "verify_range8"))
     assert "jit_verify_range8" in fn.lower(1.0).as_text()
