@@ -51,7 +51,7 @@ from handel_tpu.models.bn254 import (
 from handel_tpu.utils.breaker import CircuitBreaker
 from handel_tpu.ops import bn254_ref as bn
 from handel_tpu.ops.curve import BN254Curves
-from handel_tpu.ops.fp import device_platform
+from handel_tpu.ops.fp import device_platform, mul_step_cap
 from handel_tpu.ops.pairing import BN254Pairing
 
 # Device-input arrays for one launch, as the packer hands them to dispatch:
@@ -358,6 +358,12 @@ class BN254Device:
     def field_limbs(self) -> int:
         """16-bit limbs of the base field (the `fieldLimbs` gauge)."""
         return self.curves.F.nlimbs
+
+    @property
+    def fp_mul_step_lanes(self) -> int:
+        """The most lanes a step of the field's multiplication kernel
+        computes (the `fpMulStepLanes` gauge)."""
+        return mul_step_cap(self.field_limbs)
 
     def _put_bank(self, pubkeys, what: str):
         """One registry bank on the device: the keys' affine coordinates as

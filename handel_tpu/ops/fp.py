@@ -68,6 +68,7 @@ Correctness oracle: ops/bn254_ref.py; property tests in tests/test_fp_jax.py.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -78,7 +79,37 @@ LIMB_MASK = (1 << LIMB_BITS) - 1
 
 # lane-dimension granularity: uint32 tiles are (8, 128); tile batches to 128
 _LANE = 128
-_MAX_TILE_B = 2048
+# the constant of `mul_step_cap`'s fit: what 64 vector registers of 8 x 128
+# 32-bit words hold
+_VREG_WORDS = 64 * 8 * _LANE
+
+
+def mul_step_cap(nlimbs: int) -> int:
+    """The field's step: the most lanes one grid step of the multiplication
+    kernel computes, whatever the call's width (512 at 16 limbs, 256 at 24).
+
+    A FIT, not a model of the chip. It reads "the (7 x nlimbs + 1) rows the
+    unrolled body (`Field._mul_cols`) handles per lane come to no more words
+    than the vector register file", and it picks the step a sweep on a TPU
+    v5e read fastest at 16 and at 24 limbs (`scripts/fp_mul_sweep.py`). The
+    body does not fit the registers at those steps: the compiler spills at
+    every step the sweep tried, and what the step trades is the length of
+    its schedule a lane (`scripts/fp_mul_bundles.py` counts it, no chip
+    needed). Readings and what they establish: PERF.md section 6, PR 36.
+    Read both again before trusting it for a field of another limb count.
+    """
+    step = _LANE
+    while (7 * nlimbs + 1) * 2 * step <= _VREG_WORDS:
+        step *= 2
+    return step
+
+
+def mul_step(nlimbs: int, width: int) -> int:
+    """Lanes one grid step of `fp_mul_<nlimbs>x<width>` computes: the
+    field's step (`mul_step_cap`) where it divides the width, else the
+    widest power of two of lanes below it that does (9 984 = 39 x 256). The
+    width, a multiple of 128, only says how many steps there are."""
+    return math.gcd(width, mul_step_cap(nlimbs))
 
 
 def _int_to_limbs(x: int, nlimbs: int) -> np.ndarray:
@@ -585,12 +616,9 @@ class Field:
             padded = self.pad_batch(bsz)
             pad = lambda x: jnp.pad(x, ((0, 0), (0, padded - bsz)))
             return self._mul_pallas(pad(a), pad(b))[:, :bsz]
-        tile = min(_MAX_TILE_B, bsz)
-        while bsz % tile != 0:
-            tile //= 2
-        key = (bsz, tile)
-        fn = self._pallas_fns.get(key)
+        fn = self._pallas_fns.get(bsz)
         if fn is None:
+            step = mul_step(n, bsz)
 
             def kernel(a_ref, b_ref, o_ref):
                 o_ref[:] = self._mul_cols(a_ref[:], b_ref[:])
@@ -601,16 +629,16 @@ class Field:
                 # the multiplications of one phase from another's
                 name=f"fp_mul_{n}x{bsz}",
                 out_shape=jax.ShapeDtypeStruct((n, bsz), jnp.uint32),
-                grid=(bsz // tile,),
+                grid=(bsz // step,),
                 in_specs=[
-                    pl.BlockSpec((n, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
-                    pl.BlockSpec((n, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
+                    pl.BlockSpec((n, step), lambda i: (0, i), memory_space=pltpu.VMEM),
+                    pl.BlockSpec((n, step), lambda i: (0, i), memory_space=pltpu.VMEM),
                 ],
                 out_specs=pl.BlockSpec(
-                    (n, tile), lambda i: (0, i), memory_space=pltpu.VMEM
+                    (n, step), lambda i: (0, i), memory_space=pltpu.VMEM
                 ),
             )
-            self._pallas_fns[key] = fn
+            self._pallas_fns[bsz] = fn
         return fn(a, b)
 
     # -- derived ops --------------------------------------------------------

@@ -1139,6 +1139,7 @@ class BatchVerifierService:
             "devicesTotal",
             "devicesAvailable",
             "fieldLimbs",
+            "fpMulStepLanes",
             "keyGroup",
             "meshLanes",
             "meshLanesAvailable",

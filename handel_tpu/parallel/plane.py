@@ -333,6 +333,12 @@ class DevicePlane:
                 (getattr(l.engine, "field_limbs", 0) for l in self.lanes),
                 default=0,
             )),
+            # ... how many lanes a step of its multiplication kernel computes
+            # (ops/fp.py `mul_step_cap`; 0 for host stubs)
+            "fpMulStepLanes": float(max(
+                (getattr(l.engine, "fp_mul_step_lanes", 0) for l in self.lanes),
+                default=0,
+            )),
             # ... and which group their registry keys live in (1 or 2:
             # models/bn254_jax.py `key_group`; 0 for host stubs)
             "keyGroup": float(max(

@@ -8,11 +8,15 @@ chip's machine: run this there, after `benchmark/run.py --trace 1
 Takes the WHOLE executions of the launch program (`jit_verify_*` on the
 "XLA Modules" line) and, over the operations inside them ("XLA Ops"), prints
 one JSON line: per kernel name (`fp_mul_<limbs>x<lanes>`) the calls and the
-self milliseconds a launch and the microseconds a call, the executed
-`conditional`s and `while`s a launch, and the launch's time outside the
-kernels (the XLA glue). The second witness of ops/pairing.py's loop over the
-runs of its public bits: a 0-bit step leaves no `fp_mul_<limbs>x3072` call
-(the addition step's) in the trace.
+self milliseconds a launch, the microseconds a call, the nanoseconds a lane
+and, for the Montgomery multiplication, `step_by_this_checkout`: the lanes a
+step computes at that width by `ops/fp.py` `mul_step` of the checkout this
+script runs from. A trace does not record the kernel's grid, so for a trace
+another commit's program wrote that field is not the step the kernel ran
+with. Then the executed `conditional`s and `while`s a launch, and the
+launch's time outside the kernels (the XLA glue). The second witness of
+ops/pairing.py's loop over the runs of its public bits: a 0-bit step leaves
+no `fp_mul_<limbs>x3072` call (the addition step's) in the trace.
 """
 
 from __future__ import annotations
@@ -26,8 +30,19 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmark import trace_reduce  # noqa: E402
+from handel_tpu.ops.fp import mul_step  # noqa: E402
 
-_KERNEL = re.compile(r"%?((?:fp|rns)_mul_\d+x\d+)")
+_KERNEL = re.compile(r"%?((?:fp|rns)_mul_(\d+)x(\d+))")
+
+
+def per_lane(kernel: str, ns_per_call: float) -> dict:
+    """A kernel's time a lane, and the step this checkout's rule gives its
+    width (the trace does not say what step the traced program ran)."""
+    _, rows, lanes = _KERNEL.fullmatch(kernel).groups()
+    out = {"ns_per_lane": ns_per_call / int(lanes)}
+    if kernel.startswith("fp_"):  # `rns_mul_*` tiles by its own rule (ops/rns.py)
+        out["step_by_this_checkout"] = mul_step(int(rows), int(lanes))
+    return out
 
 
 def op_name(hlo: str) -> str:
@@ -56,7 +71,8 @@ def launch_counts(path: str, program: str = "jit_verify_") -> dict:
     n = len(whole)
     kernels = {
         k: {"calls": calls[k] / n, "ms": self_ns[k] / n / 1e6,
-            "us_per_call": self_ns[k] / calls[k] / 1e3}
+            "us_per_call": self_ns[k] / calls[k] / 1e3,
+            **per_lane(k, self_ns[k] / calls[k])}
         for k in sorted(calls, key=lambda k: -self_ns[k]) if _KERNEL.fullmatch(k)
     }
     kernel_ms = sum(v["ms"] for v in kernels.values())

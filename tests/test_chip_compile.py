@@ -85,14 +85,28 @@ def mosaic_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def assert_grid_follows_the_field(F, x):
+    """The kernel's grid is width / step and each block is one step of the
+    field (`fp.mul_step`): the width says how many steps, not how wide."""
+    (call,) = [e for e in jax.make_jaxpr(F.mul)(x, x).eqns
+               if e.primitive.name == "pallas_call"]
+    step = fp.mul_step(F.nlimbs, x.shape[1])
+    grid = call.params["grid_mapping"]
+    assert grid.grid == (x.shape[1] // step,)
+    assert {tuple(d.block_size for d in b.block_shape)
+            for b in grid.block_mappings} == {(F.nlimbs, step)}
+
+
 # lane widths of stacked Field.mul calls read out of the lowered full-width
 # launches: 128 = one per-lane mul, 6912 = the Fp12 mul stacked 54x (the
 # most frequent width in both launches), 9216 and 9984 = the Miller
 # accumulator's squaring (36x) and sparse line product (39x) over the 256
 # pairs, 13824 = the general Fp12 product over them (the widest mul of the
 # range launch), 4718592 = the dense launch's widest (4096 keys x 128 lanes
-# x 9 stacked muls of a G2 add)
-@pytest.mark.parametrize("width", [128, 6912, 9216, 9984, 13824, 4718592])
+# x 9 stacked muls of a G2 add), 589824 = the wide hole patch's first
+# stage in G2 (512 x 128 x 9: 1 152 steps of 512 lanes)
+@pytest.mark.parametrize(
+    "width", [128, 6912, 9216, 9984, 13824, 589824, 4718592])
 def test_cios_mul_bn254(shape, chip_choices, width):
     F = fp.Field(bn.P)
     assert F.use_pallas and F.nlimbs == 16
@@ -101,10 +115,12 @@ def test_cios_mul_bn254(shape, chip_choices, width):
     assert mosaic_calls(compiled) == 1
     # the kernel's name says operation and stacked width (profiler traces)
     assert f"%fp_mul_16x{width}" in compiled.as_text()
+    assert_grid_follows_the_field(F, x)
 
 
-# the same widths of the BLS12-381 range launch's pairing tail
-@pytest.mark.parametrize("width", [128, 6912, 9216, 9984, 13824])
+# the same widths of the BLS12-381 range launch's pairing tail, and the G1
+# patch's first stage (512 x 128 x 6 = 393216: 1 536 steps of 256 lanes)
+@pytest.mark.parametrize("width", [128, 6912, 9216, 9984, 13824, 393216])
 def test_cios_mul_bls12_381(shape, chip_choices, width):
     F = fp.Field(bls.P)
     assert F.use_pallas and F.nlimbs == 24
@@ -112,6 +128,7 @@ def test_cios_mul_bls12_381(shape, chip_choices, width):
     compiled = jax.jit(F.mul).lower(x, x).compile()
     assert mosaic_calls(compiled) == 1
     assert f"%fp_mul_24x{width}" in compiled.as_text()
+    assert_grid_follows_the_field(F, x)
 
 
 def test_rns_resident_mul(shape, chip_choices):
