@@ -16,6 +16,10 @@ What is compared is what the timed path produced: every verdict that
      launch), as many candidates verified on the device as clients were
      answered, as many engine launches as the service fetched, nothing
      compiled in the window, no request failed.
+  4. The launch classes: before the ramp, the program's class counters read
+     the warm launches on every class the traffic names and 0 on the
+     others; over the service's whole life no launch took a class that was
+     not warmed (a compile the cache may have hidden).
 
 Each number is printed beside its limit; `correct` is true only when every
 one is inside it.
@@ -137,3 +141,23 @@ def guarantees(stated: dict, counters: dict, answered: int,
         Check("failed_requests", failed, 0),
     ]
     return checks
+
+
+def warmed_classes(ladder: list[dict], named: list[str], counters: dict,
+                   per_class: int) -> list[Check]:
+    """Check 4 at set-up, one a class of the configuration's ladder: the
+    class counter after the warm launches and before the ramp."""
+    return [
+        Check(f"warm_launches_{cls['name']}", counters[cls["counter"]],
+              per_class if cls["name"] in named else 0, "eq")
+        for cls in ladder
+    ]
+
+
+def unwarmed_class_launches(ladder: list[dict], named: list[str],
+                            counters: dict) -> Check:
+    """Check 4 at the end: launches, over the service's life, of classes the
+    traffic does not name (they read 0 after the warm-up)."""
+    return Check("unwarmed_class_launches",
+                 sum(counters[cls["counter"]] for cls in ladder
+                     if cls["name"] not in named), 0)

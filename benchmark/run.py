@@ -7,9 +7,10 @@ One process. It refuses to run without the TPU and the chips the cell asks
 for (no CPU fallback), builds the registry, the keys and the request pool
 from `--seed` with the benchmark's own reference code, builds the program's
 device engine and `BatchVerifierService(fallback=None)` from what the
-configuration file states, warms the cell's one launch class through the
-engine's own dispatch/fetch, drives the service through a ramp and then the
-measured window, compares what was served with the plain reference, and
+configuration file states, warms every launch class the traffic file names
+through the engine's own dispatch/fetch and holds the program's class
+counters to it, drives the service through a ramp and then the measured
+window, compares what was served with the plain reference, and
 prints phase lines and then ONE result line (the contract's keys).
 
 `--trace 0` reports the cell's end-to-end metrics, `--trace 1` its
@@ -55,7 +56,7 @@ OUT_DIR = os.path.join(BENCH_DIR, "_out")
 # reading them costs about 0.7 s a megabyte of trace (measured, PR 25)
 TRACE_SECONDS = 0.5
 TRACE_SETTLE = 0.1        # ... and reduces it from this far in
-WARM_LAUNCHES = 3         # launches through the engine before the service
+WARM_LAUNCHES = 3         # launches of each class through an engine before the service
 REHEARSE = {"lanes": 4, "clients": 8, "pool_requests": 24, "forged_share": 0.1}
 GAP_SPANS = ("dispatch_pack", "launch_queued", "launch_fetched")
 
@@ -195,7 +196,7 @@ def build_requests(cell, ref, seed: int, lanes: int, rehearse: bool):
         failing=cfg["deployment"]["failing"], requests=len(pool),
         candidates=sum(len(r) for r in pool),
         forged=sum(c.forged for r in pool for c in r),
-        launch_class=tr["launch_class"], seconds=time.perf_counter() - t1)
+        launch_classes=tr["launch_classes"], seconds=time.perf_counter() - t1)
     return tr, points, pool, msg
 
 
@@ -235,30 +236,67 @@ def build_engines(cfg, pubkeys, lanes: int, chips: int):
     return plane, [lane.engine for lane in plane.lanes]
 
 
-def warm(engines, msg, reqs, pool, lanes, launch_class, meter):
-    """Compile and warm the cell's ONE launch class: whole launches of pool
-    candidates through each engine's own dispatch/fetch. Returns the launches
-    made and how many of their verdicts differ from the construction."""
+def warm_batches(flat, ladder, named, lanes: int):
+    """The warm launches of one engine: for every class of the configuration's
+    `ladder` that the traffic names, narrowest first, WARM_LAUNCHES whole
+    launches of the pool's candidates whose hull holes lie in that class's
+    interval, in pool order, cycling. `flat` is [(request, candidate)];
+    returns [(class name, k, [(request, candidate)] * lanes)]."""
+    unknown = sorted(set(named) - {cls["name"] for cls in ladder})
+    if unknown:
+        raise BenchFailure(
+            f"the traffic names launch class(es) {unknown}, which the "
+            "configuration's guarantees.launch_classes does not have")
+    out = []
+    for cls in ladder:
+        if cls["name"] not in named:
+            continue
+        lo, hi = cls["hull_holes"]
+        own = [rc for rc in flat if lo <= rc[1].hull_holes() <= hi]
+        if not own:
+            raise BenchFailure(
+                f"the traffic names launch class {cls['name']!r}, but the pool "
+                f"holds no candidate with {lo}..{hi} hull holes to warm it with")
+        out += [(cls["name"], k,
+                 [own[(k * lanes + j) % len(own)] for j in range(lanes)])
+                for k in range(WARM_LAUNCHES)]
+    return out
+
+
+def warm(engines, msg, reqs, pool, lanes, ladder, named, meter):
+    """Compile and warm every launch class the traffic names (`warm_batches`)
+    through each engine's own dispatch/fetch. Returns the launches made and
+    how many of their verdicts differ from the construction."""
     import jax
 
     flat = [(req, c) for rs, cs in zip(reqs, pool) for req, c in zip(rs, cs)]
+    batches = warm_batches(flat, ladder, named, lanes)
     launches = wrong = 0
-    for e in engines:
-        if launch_class.startswith("range") and hasattr(e, "_prefix"):
-            # the prefix table is built on the first range dispatch; build
-            # it here so that its scan is timed apart
-            t0 = time.perf_counter()
-            jax.block_until_ready(e._prefix)
-            say(phase="prefix_table", seconds=time.perf_counter() - t0,
-                **meter.take())
-        for k in range(WARM_LAUNCHES):
-            t0 = time.perf_counter()
-            batch = [flat[(k * lanes + j) % len(flat)] for j in range(lanes)]
-            got = e.fetch(e.dispatch(msg, [req for req, _ in batch]))
-            wrong += sum(g == c.forged for g, (_, c) in zip(got, batch))
-            launches += 1
-            say(phase=f"warm_launch_{k}", seconds=time.perf_counter() - t0,
-                **meter.take())
+    # the cyclic collector is off while the programs trace and lower: a full
+    # collection that lands inside a launch's lowering doubles it, and where
+    # it lands moves with every edit of the harness (PERF.md section 6, PR 37)
+    gc.disable()
+    try:
+        for e in engines:
+            if (any(name.startswith("range") for name in named)
+                    and hasattr(type(e), "_prefix")):
+                # the prefix table is built on the first range dispatch; build
+                # it here so that its scan is timed apart (`type(e)`: asking
+                # the engine itself would run the property, and build it
+                # untimed)
+                t0 = time.perf_counter()
+                jax.block_until_ready(e._prefix)
+                say(phase="prefix_table", seconds=time.perf_counter() - t0,
+                    **meter.take())
+            for name, k, batch in batches:
+                t0 = time.perf_counter()
+                got = e.fetch(e.dispatch(msg, [req for req, _ in batch]))
+                wrong += sum(g == c.forged for g, (_, c) in zip(got, batch))
+                launches += 1
+                say(phase=f"warm_launch_{name}_{k}",
+                    seconds=time.perf_counter() - t0, **meter.take())
+    finally:
+        gc.enable()
     return launches, wrong
 
 
@@ -339,14 +377,23 @@ def run(args) -> dict:
     target, engines = build_engines(cfg, pubkeys, lanes, cell.chips)
     say(phase="engines", engines=len(engines), lanes=lanes,
         scheme=cfg["scheme"], seconds=time.perf_counter() - t0, **meter.take())
+    ladder, named = cfg["guarantees"]["launch_classes"], tr["launch_classes"]
     warm_launches, warm_wrong = warm(
-        engines, msg, reqs, pool, lanes, tr["launch_class"], meter)
+        engines, msg, reqs, pool, lanes, ladder, named, meter)
 
     sink = SpanSink() if args.trace else None
     Service = resolve(cfg["program"]["service"])
     service = Service(target, fallback=None, recorder=sink,
                       **cfg.get("service_options", {}))
     tracer = Tracer(cell.name, args.rehearse) if args.trace else None
+    # what was warmed, by the program's own class counters: a wrong interval
+    # in a data file, or a ladder that moved, ends the run here and in words
+    warm_checks = correct.warmed_classes(
+        ladder, named, service.values(), WARM_LAUNCHES * len(engines))
+    if not all(c.ok for c in warm_checks):
+        raise BenchFailure("the engine did not warm the classes the traffic "
+                           "names: " + "; ".join(
+                               c.line() for c in warm_checks if not c.ok))
 
     def on_edge(edge: str) -> dict:
         return {"counters": dict(service.values()), "compile": meter.take()}
@@ -407,6 +454,7 @@ def run(args) -> dict:
         launches=delta(names["served_by_device"]["launches"]),
         candidates_verified=delta(names["served_by_device"]["candidates"]),
         dedup_hits=delta(names["dedup_hits"]),
+        launches_by_class={cls["name"]: delta(cls["counter"]) for cls in ladder},
         compile_events_in_window=window_compile["events"],
         compile_in_window=window_compile,
         gc_in_window=gc_meter.between(res.t0, res.t1),
@@ -426,6 +474,8 @@ def run(args) -> dict:
     )
     checks.append(correct.Check(
         "warmup_verdicts_differing_from_construction", warm_wrong, 0))
+    checks += warm_checks
+    checks.append(correct.unwarmed_class_launches(ladder, named, final))
     say(phase="reference", seconds=time.perf_counter() - t0, **info)
     for c in checks:
         print(c.line(), flush=True)
@@ -502,6 +552,8 @@ def run(args) -> dict:
     }
     if breakdown:
         result["breakdown"] = breakdown
+    # each number compared beside its limit, last in the line
+    result["compared"] = {c.name: [c.value, c.limit] for c in checks}
     return result, checks
 
 

@@ -1,7 +1,10 @@
 """The traffic generator: deterministic in the seed, the same work for every
-seed, and class-pure — every launch of cell 1 packs to range miss_k=8 and of
-cell 2 to dense, checked with the program's own `_pack_requests` on the CPU
-(dummy keys: packing reads bitsets and signature points only)."""
+seed, and class-pure in the four cells — every launch packs to the one class
+the traffic file names (range miss_k=8 in cells 1 and 3, range miss_k=1024 in
+cells 2 and 4) — while the mixed stream reaches three classes, which the
+warm-up's selection separates. Checked with the program's own
+`_pack_requests` on the CPU (dummy keys: packing reads bitsets and signature
+points only)."""
 
 import random
 from collections import Counter
@@ -13,15 +16,25 @@ import traffic as tg
 
 ORDER = 21888242871839275222246405745257275088548364400416034343698204186575808495617
 N_KEYS, LANES = 4096, 128
-CELLS = {
-    "handel4096-99thr.closed256": ("range", 8),
-    "handel4096-51thr-failing.closed256": ("dense", 0),
-}
+CELLS = [
+    "handel4096-99thr.closed256",
+    "handel4096-51thr-failing.closed256",
+    "bls12-381-4096.closed256",
+    "bls12-381-minpk-4096-failing.closed256",
+]
+MIXED = "closed256-mixed-levels"
 
 
-def make_pool(cell_name: str, seed: int, pool_requests: int = 256):
+def packs_to(class_name: str) -> tuple[str, int]:
+    """A ladder class's name as the packer's (kind, miss_k)."""
+    return ("dense", 0) if class_name == "dense" else (
+        "range", int(class_name[len("range"):]))
+
+
+def make_pool(cell_name: str, seed: int, pool_requests: int = 256, traffic=None):
     cell = spec.Cell(cell_name)
-    tr = dict(cell.traffic, pool_requests=pool_requests)
+    tr = dict(spec.load_traffic(traffic) if traffic else cell.traffic,
+              pool_requests=pool_requests)
     sks = [tg.stream(seed, tg.KEYS).randrange(1, ORDER) for _ in range(N_KEYS)]
     failing = tg.failing_ids(seed, N_KEYS, cell.config["deployment"]["failing"])
     return cell, tg.build_pool(tr, seed, sks, failing, ORDER)
@@ -64,33 +77,76 @@ def engine():
     return BN254Device([BN254PublicKey(bn.G2_GEN)] * N_KEYS, batch_size=LANES)
 
 
-@pytest.mark.parametrize("cell_name", CELLS)
-def test_class_pure(cell_name, engine):
-    """Whatever candidates the collector puts side by side, the launch packs
-    to the cell's one class: full launches, a partial one, a single one."""
+def request(c):
     from handel_tpu.core.bitset import BitSet
     from handel_tpu.models.bn254 import BN254Signature
     from handel_tpu.ops import bn254_ref as bn
 
+    bs = BitSet(N_KEYS)
+    bs.set_range(c.lo, c.lo + c.size)
+    for i in c.holes:
+        bs.set(i, False)
+    return bs, BN254Signature(bn.G1_GEN)
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_class_pure(cell_name, engine):
+    """Whatever candidates the collector puts side by side, the launch packs
+    to the cell's one class: full launches, a partial one, a single one.
+    (The packer is one for every scheme; the BN254 engine stands for all.)"""
     cell, pool = make_pool(cell_name, 31337)
-    kind, miss_k = CELLS[cell_name]
-    assert cell.traffic["launch_class"] == (f"range{miss_k}" if miss_k else "dense")
+    (name,) = cell.traffic["launch_classes"]
+    ladder = {c["name"]: c for c in cell.config["guarantees"]["launch_classes"]}
+    lo, hi = ladder[name]["hull_holes"]
     flat = [c for r in pool for c in r]
-    for c in flat:
-        holes = c.hull_holes()
-        assert holes <= 8 if kind == "range" else holes > engine.MISS_CAP
-
-    def request(c):
-        bs = BitSet(N_KEYS)
-        bs.set_range(c.lo, c.lo + c.size)
-        for i in c.holes:
-            bs.set(i, False)
-        return bs, BN254Signature(bn.G1_GEN)
-
+    assert all(lo <= c.hull_holes() <= hi for c in flat)
     rng = random.Random(1)
     for width in (LANES, LANES, LANES, 37, 1):
         plan = engine._pack_requests([request(c) for c in rng.sample(flat, width)])
-        assert (plan.kind, plan.miss_k) == (kind, miss_k)
+        assert (plan.kind, plan.miss_k) == packs_to(name)
+
+
+def test_ladder_is_the_engines(engine):
+    """The intervals the configurations state are the packer's thresholds."""
+    for cell_name in CELLS:
+        ladder = spec.Cell(cell_name).config["guarantees"]["launch_classes"]
+        assert [c["hull_holes"][0] for c in ladder] == [
+            0, *(c["hull_holes"][1] + 1 for c in ladder[:-1])]
+        assert ladder[-1]["hull_holes"][1] == N_KEYS - 1
+        for c in ladder:
+            for holes in c["hull_holes"]:
+                k = engine._patch_width(holes)
+                assert (("range", k) if k else ("dense", 0)) == packs_to(c["name"])
+
+
+def test_mixed_stream_classes(engine):
+    """The mixed traffic holds whole launches of each of its three classes;
+    a launch made by the warm-up's selection packs to the class it is made
+    for (full, partial, single), and a random launch of the stream packs
+    wide: one candidate over 64 holes decides for all 128 lanes."""
+    import run
+
+    cell, pool = make_pool("handel4096-51thr-failing.closed256", 31337,
+                           pool_requests=512, traffic=MIXED)
+    named = spec.load_traffic(MIXED)["launch_classes"]
+    assert named == ["range8", "range64", "range1024"]
+    flat = [(None, c) for r in pool for c in r]
+    batches = run.warm_batches(
+        flat, cell.config["guarantees"]["launch_classes"], named, LANES)
+    assert [(n, k) for n, k, _ in batches] == [
+        (n, k) for n in named for k in range(run.WARM_LAUNCHES)]
+    for name, k, batch in batches:
+        cands = [c for _, c in batch]
+        assert len({id(c) for c in cands}) == LANES  # a whole launch, no repeat
+        for width in (LANES, 37, 1):
+            plan = engine._pack_requests([request(c) for c in cands[:width]])
+            assert (plan.kind, plan.miss_k) == packs_to(name)
+    rng = random.Random(2)
+    everything = [c for _, c in flat]
+    for _ in range(5):
+        plan = engine._pack_requests(
+            [request(c) for c in rng.sample(everything, LANES)])
+        assert (plan.kind, plan.miss_k) == ("range", 1024)
 
 
 def test_arrival_clock():

@@ -27,12 +27,16 @@ Traffic file keys:
                            seeded ids absent (signers not yet aggregated);
                            {"rule": "failing", "max": h, "min": m}: the
                            configuration's failing ids are absent as well,
-                           and the hull keeps at least m holes
+                           and the hull keeps at least m holes (no "min":
+                           0); a drawn range with no live id is redrawn
   forged_share             share of candidates forged (at least one)
   dedup                    "fresh_scope": every request its own dedup scope;
                            "shared_scope": all clients share one
   pool_requests            distinct requests generated (replayed in order)
-  launch_class             what every launch packs to (tests check it)
+  launch_classes           the launch classes the mix's candidates reach, by
+                           names of the configuration's ladder
+                           (guarantees.launch_classes): run.py warms each of
+                           them and fails a launch of any other
   arrival                  open only: {"model": "poisson"|"burst",
                            "rate_rps", "burst_x", "burst_every_s",
                            "burst_len_s"}
@@ -66,8 +70,16 @@ class Candidate:
         return [i for i in range(self.lo, self.lo + self.size) if i not in gone]
 
     def hull_holes(self) -> int:
-        s = self.signers()
-        return (s[-1] - s[0] + 1) - len(s)
+        """Absent ids between the first and the last signer: the holes less
+        the runs of them at the range's two ends (`holes` is ascending)."""
+        n, first, last = len(self.holes), self.lo, self.lo + self.size - 1
+        head = 0
+        while head < n and self.holes[head] == first + head:
+            head += 1
+        tail = 0
+        while tail < n - head and self.holes[n - 1 - tail] == last - tail:
+            tail += 1
+        return n - head - tail
 
 
 def _cycle(values, n: int, rng: random.Random) -> list:
@@ -134,16 +146,17 @@ def build_pool(traffic: dict, seed: int, sks: list[int], failing: frozenset,
                 lo = rng.randrange(n_keys // size) * size
                 gone, live = range_split(lo, size)
                 # a level with few ranges runs out of fresh candidates at a
-                # given hole count: later attempts move the count on
-                extra = min((hole_counts[c] + attempt // 4) % (rule["max"] + 1),
-                            len(live) - 1)
+                # given hole count: later attempts move the count on. A
+                # range with no live id (a level-1 range whose one id fails)
+                # draws no hole and, having no signer, is redrawn below
+                extra = max(0, min(
+                    (hole_counts[c] + attempt // 4) % (rule["max"] + 1),
+                    len(live) - 1))
                 holes = tuple(sorted(gone + rng.sample(live, extra)))
-                signers = tuple(Candidate(lo, size, holes, False, 0).signers())
-                hull_holes = (
-                    signers[-1] - signers[0] + 1 - len(signers) if signers else 0
-                )
+                drawn = Candidate(lo, size, holes, False, 0)
+                signers = tuple(drawn.signers())
                 if (signers and signers not in seen
-                        and hull_holes >= rule.get("min", 0)):
+                        and drawn.hull_holes() >= rule.get("min", 0)):
                     break
             else:
                 raise ValueError(
