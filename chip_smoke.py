@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Chip smoke: the served BN254 verify path on one TPU v5e, end to end.
 
-    python chip_smoke.py            # one chip: range + dense launch classes
+    python chip_smoke.py            # one chip: two range classes + dense
     python chip_smoke.py --chips 4  # four pinned engines behind one service
 
 One process, the entry points a user calls, full width: a seeded 4096-key
@@ -11,6 +11,7 @@ fallback=None)`, and a few launch groups through `service.verify(...)`:
 
   * range class — contiguous partitioner level ranges with 0-8 offline
     holes (the prefix-table kernel, miss_k = 8);
+  * range64 class — the same ranges with 9-64 (miss_k = 64);
   * dense class — scattered signer sets with more holes in the hull than
     the widest range patch (n // 4; the masked registry tree-sum kernel);
 
@@ -18,7 +19,7 @@ each with ONE forged candidate that must come back False while the rest
 come back True, and every verdict compared with the host reference
 (`BN254Constructor.batch_verify`, models/bn254.py) on the same requests.
 Only the kernel classes that are driven get compiled (no `warmup()`), the
-two of them side by side.
+three of them side by side.
 
 `--chips 4` runs the fleet plane instead and nothing else: four engines,
 one pinned to each chip (`parallel/plane.py bn254_plane`), behind the same
@@ -147,17 +148,22 @@ def _group(rng, sks, signer_sets):
     return reqs, forged
 
 
-def range_group(rng, sks):
+def range_group(rng, sks, holes=(0, 8)):
     """LANES candidates shaped like Handel traffic: an aligned level range
-    of the binomial partitioner minus 0-8 offline members."""
+    of the binomial partitioner minus `holes[0]`..`holes[1]` offline members
+    (0-8: the `range8` launch class; 9-64: `range64`). A range's first and
+    last member always sign, so its hull holes are the members drawn."""
+    sizes = [N_KEYS >> l for l in range(1, 7)]  # 64 .. 2048 of 4096 signers
+    sizes = [size for size in sizes if size - 2 >= holes[0]]
     sets = []
     for _ in range(LANES):
-        size = N_KEYS >> rng.randrange(1, 7)  # 64 .. 2048 of 4096 signers
+        size = rng.choice(sizes)
         lo = rng.randrange(N_KEYS // size) * size
-        holes = set(
-            rng.sample(range(lo, lo + size), rng.randrange(0, min(9, size)))
-        )
-        sets.append([i for i in range(lo, lo + size) if i not in holes])
+        gone = set(rng.sample(
+            range(lo + 1, lo + size - 1),
+            rng.randrange(holes[0], min(holes[1], size - 2) + 1),
+        ))
+        sets.append([i for i in range(lo, lo + size) if i not in gone])
     return _group(rng, sks, sets)
 
 
@@ -357,7 +363,9 @@ def drive(chips: int, meter: CompileMeter) -> dict:
         seconds=time.perf_counter() - t0, **meter.take())
 
     if chips == 1:
-        groups = {"range": range_group(rng, sks), "dense": dense_group(rng, sks)}
+        groups = {"range": range_group(rng, sks),
+                  "range64": range_group(rng, sks, holes=(9, 64)),
+                  "dense": dense_group(rng, sks)}
         host = host_reference(pubkeys, groups)
         compile_classes(target, groups, host, meter)
         serve_groups = serve_one_chip

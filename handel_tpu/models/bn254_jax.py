@@ -1282,6 +1282,48 @@ class BN254Device:
             c >= 64, cls._U64_ONES, (np.uint64(1) << shift) - np.uint64(1)
         )
 
+    @staticmethod
+    def _hull(words):
+        """Per row of bitset words (k, W), uint64: its cardinality and its
+        hull [lo, hi), the first set bit and one past the last (0, 0 for an
+        empty row), as int64 — so `hi - lo - card` is the row's hole count.
+        No unpacking: the first and last nonzero word of a row, then a
+        trailing-zero / leading-bit scan of just those edge words. The one
+        place the packer (`_pack_into`) and `launch_class` read holes from."""
+        card = np.bitwise_count(words).sum(axis=1, dtype=np.int64)
+        wnz = words != 0
+        nonempty = wnz.any(axis=1)
+        W = words.shape[1]
+        rows = np.arange(words.shape[0])
+        fw = wnz.argmax(axis=1)
+        lw = (W - 1) - wnz[:, ::-1].argmax(axis=1)
+        wf = words[rows, fw]
+        tz = np.bitwise_count(  # trailing zeros: popcount((w & -w) - 1)
+            (wf & (~wf + np.uint64(1))) - np.uint64(1)
+        ).astype(np.int64)
+        v = words[rows, lw].copy()  # leading bit: smear right, popcount - 1
+        for s in (1, 2, 4, 8, 16, 32):
+            v |= v >> np.uint64(s)
+        msb = np.bitwise_count(v).astype(np.int64) - 1
+        lo = np.where(nonempty, fw * 64 + tz, 0)
+        hi = np.where(nonempty, lw * 64 + msb + 1, 0)  # one past last bit
+        return card, lo, hi
+
+    def launch_class(self, bitsets) -> list[int]:
+        """The launch class each candidate needs, from its bitset alone:
+        the narrowest patch width of `patch_widths` that holds its hull
+        holes, 0 = dense — what `_pack_into` gives a launch that holds only
+        that candidate. The service plans a launch for one class with it
+        (parallel/batch_verifier.py); the packer's largest-hole rule still
+        decides what a launch runs, so a candidate classed narrower than
+        its launch stays correct."""
+        if not len(bitsets):
+            return []
+        card, lo, hi = self._hull(np.stack([bs.words() for bs in bitsets]))
+        widths = np.asarray(self.patch_widths)
+        at = np.searchsorted(widths, hi - lo - card)  # first width >= holes
+        return np.append(widths, 0)[at].tolist()
+
     def _pack_requests(self, requests, seq: int | None = None) -> "LaunchPlan":
         """Vectorized launch packing: requests -> device-input arrays, as
         two timed stages of launch `seq`: the wait on the staging set's
@@ -1333,34 +1375,17 @@ class BN254Device:
             words[j, :] = bs.words()
             sig_pts.append(getattr(sig, "point", None))
 
-        card = np.bitwise_count(words).sum(axis=1, dtype=np.int64)
+        card, hull_lo, hull_hi = self._hull(words)
         if k:
             valid[:k] = (card[:k] > 0) & np.fromiter(
                 (p is not None for p in sig_pts), bool, count=k
             )
         words[~valid] = 0  # invalid lanes contribute nothing
-
-        # range bounds without unpacking: first/last nonzero word per lane,
-        # then a trailing-zero / leading-bit scan of just those edge words
-        wnz = words != 0
-        nonempty = wnz.any(axis=1)
         W = words.shape[1]
-        rows = np.arange(C)
-        fw = wnz.argmax(axis=1)
-        lw = (W - 1) - wnz[:, ::-1].argmax(axis=1)
-        wf = words[rows, fw]
-        tz = np.bitwise_count(  # trailing zeros: popcount((w & -w) - 1)
-            (wf & (~wf + np.uint64(1))) - np.uint64(1)
-        ).astype(np.int64)
-        v = words[rows, lw].copy()  # leading bit: smear right, popcount - 1
-        for s in (1, 2, 4, 8, 16, 32):
-            v |= v >> np.uint64(s)
-        msb = np.bitwise_count(v).astype(np.int64) - 1
         lo, hi = st.lo, st.hi
-        lo[:] = np.where(nonempty, fw * 64 + tz, 0)
-        hi[:] = np.where(nonempty, lw * 64 + msb + 1, 0)  # one past last bit
-        holes = (hi.astype(np.int64) - lo) - np.where(valid, card, 0)
-        max_holes = int(holes.max())
+        lo[:] = np.where(valid, hull_lo, 0)
+        hi[:] = np.where(valid, hull_hi, 0)
+        max_holes = int(np.where(valid, hull_hi - hull_lo - card, 0).max())
 
         # lanes with a point but an empty bitset stay masked placeholders,
         # like the old loop (valid gating covers both cases)

@@ -56,11 +56,20 @@ __all__ = ["BatchVerifierService", "CircuitBreaker", "DevicePlane"]
 # (core/crypto.py Constructor.batch_verify -> ops/bn254_ref math).
 FallbackVerifier = Callable[[bytes, Sequence[tuple[BitSet, object]]], list]
 
-# queued-request tuple layout (one flat tuple, future LAST — every consumer
-# below indexes it positionally):
-# (session, msg, pubkeys, bitset, sig, t_enqueued, fut); t_enqueued is the
-# trace-clock time of the push, what `queueWaitMs` is measured from
-_SESSION, _MSG, _PUBKEYS, _BITSET, _SIG, _T_ENQ, _FUT = range(7)
+# queued-request layout (one flat list, future LAST — every consumer below
+# indexes it positionally):
+# [session, msg, pubkeys, bitset, sig, t_enqueued, cls, fut]; t_enqueued is
+# the trace-clock time of the push, what `queueWaitMs` is measured from; cls
+# the launch class the candidate needs, as the engine names it
+# (`launch_class`: a patch width, 0 = dense), what the collector plans by —
+# None from the push until the collector classes it (`_classify`), the one
+# slot that is ever written
+_SESSION, _MSG, _PUBKEYS, _BITSET, _SIG, _T_ENQ, _CLASS, _FUT = range(8)
+
+
+def _width(cls: int):
+    """Sort key of a launch class, narrowest first (0, dense, is widest)."""
+    return (cls == 0, cls)
 
 
 class BatchVerifierService:
@@ -208,6 +217,11 @@ class BatchVerifierService:
         # not counted; neither is a group that failed over undispatched.
         self.queue_wait_ms = 0.0
         self.queue_wait_candidates = 0
+        # launch planning by class (`_take_launch`): candidates that rode a
+        # launch wider than their own class, and launches planned wider
+        # than the class named for them for want of candidates
+        self.class_rider_candidates = 0
+        self.class_widened_launches = 0
         # per-tenant counters (service plane labels)
         self.tenant_candidates: dict[str, int] = {}
         self.tenant_dedup_hits: dict[str, int] = {}
@@ -375,8 +389,10 @@ class BatchVerifierService:
                 futs.append(fut)
                 continue
             fut = loop.create_future()
+            # queued without a launch class (key None): the collector
+            # classes everything new in one call before it plans
             if not self.queue.push(
-                session, (session, msg, pubkeys, bs, sig, t_enq, fut)
+                session, [session, msg, pubkeys, bs, sig, t_enq, None, fut]
             ):
                 # per-tenant admission bound: the hot session absorbs its
                 # own refusal through the pipeline's requeue/retry budget
@@ -628,6 +644,74 @@ class BatchVerifierService:
             by_msg.setdefault(it[_MSG], []).append(it)
         return list(by_msg.values())
 
+    def _take_launch(self) -> list:
+        """Take the next launch off the tenant queue, planned for ONE
+        launch class (the engine's `launch_class`; what `verify` queued
+        since the last plan is classed first, `_classify`): the packer
+        gives a launch the class of its largest hole count, so one wide
+        candidate makes every lane pay the wide program.
+
+        The class is the one the tenant queue's turn ring names
+        (`turn_key`, service/fairness.py): the oldest candidate's of the
+        session whose turn it is. Sessions that hold no more than a quantum
+        each have their turns in the order they arrived, so for them it is
+        the OLDEST queued candidate's class: no class starves, and a
+        candidate waits for the launches ahead of it, as in one FIFO. A
+        session with a backlog names one launch a ring pass — alone in its
+        class it would otherwise own every launch until the backlog is
+        gone. The launch takes that class's candidates in the queue's
+        deficit-round-robin order, then fills lanes that would stay empty
+        with the oldest candidates of narrower classes (riders: correct in
+        a wider launch, and free). Where the candidates at or below the
+        class cannot fill the launch, it widens to the narrowest class at
+        or below which they can, and takes that class's candidates second.
+        A queue below one launch is taken whole. With one class queued —
+        every engine without classes, every class-pure stream — this is
+        `queue.take(batch_size)`.
+        """
+        q, lanes = self.queue, self.device.batch_size
+        q.rekey(None, self._classify)
+        if len(q) < lanes:
+            return q.take(lanes)
+        counts = q.counts()
+        order = sorted(counts, key=_width)  # narrowest first
+        named = q.turn_key()
+        at = order.index(named)
+        below = sum(counts[c] for c in order[:at])
+        for cls in order[at:]:
+            below += counts[cls]
+            if below >= lanes:
+                break
+        batch = q.take(lanes, named)
+        if cls != named:
+            self.class_widened_launches += 1
+            batch += q.take(lanes - len(batch), cls)
+        if len(batch) < lanes:
+            narrower = [c for c in order[:order.index(cls)] if c != named]
+            batch += q.take_oldest(lanes - len(batch), narrower)
+        return batch
+
+    def _classify(self, items) -> list[int]:
+        """The launch class of every candidate queued since the last plan,
+        in ONE vectorised call to the engine — here, on the collector's
+        thread, never the packer's, and a launch's worth at a time rather
+        than a request's (a call costs the same for 1 bitset as for 100) —
+        recorded on the items. A bitset the engine refuses (a wrong length)
+        gives everything asked with it the widest class, 0: the packer
+        refuses it at dispatch, as it always has."""
+        try:
+            classes = self.plane.launch_class([it[_BITSET] for it in items])
+        except ValueError:
+            classes = [0] * len(items)
+        for it, cls in zip(items, classes):
+            it[_CLASS] = cls
+        return classes
+
+    @staticmethod
+    def _planned_class(items) -> int:
+        """The class a launch group was planned for: its widest."""
+        return max((it[_CLASS] for it in items), key=_width)
+
     def _launch_call(self, lane: DeviceLane, items: list):
         """The device call for one launch group (runs in an executor)."""
         if hasattr(lane.engine, "dispatch_multi"):
@@ -711,7 +795,7 @@ class BatchVerifierService:
             # tenant queue where fairness, admission bounds and
             # forget_session() can still reach it
             lane = await self._acquire_lane()
-            batch = self.queue.take(self.device.batch_size)
+            batch = self._take_launch()
             if not batch:
                 continue
             # from here until every group is handed to a lane the batch
@@ -738,6 +822,10 @@ class BatchVerifierService:
                 # item is the same list object, so a drain double-fail is a
                 # no-op). No await between pick and put -> put_nowait is
                 # safe on the capacity-1 cell.
+                cls = self._planned_class(items)
+                self.class_rider_candidates += sum(
+                    it[_CLASS] != cls for it in items
+                )
                 target.dispatching = items
                 if self.rec is not None and self.rec.enabled:
                     # launch_queued span start (the dispatcher reads it when
@@ -754,6 +842,7 @@ class BatchVerifierService:
         args = {
             "lane": lane.index, "seq": seq, "n": len(items),
             "mode": "mesh" if lane.mesh else "lane",
+            "cls": self._planned_class(items),
         }
         sessions = sorted({it[_SESSION] for it in items if it[_SESSION]})
         if sessions:
@@ -1093,6 +1182,11 @@ class BatchVerifierService:
             # queue wait measured per candidate, push to lane hand-over
             "queueWaitMs": self.queue_wait_ms,
             "queueWaitCandidates": float(self.queue_wait_candidates),
+            # launch planning by class (`_take_launch`): candidates handed
+            # to a launch wider than their own class, and launches planned
+            # wider than the class named for them
+            "classRiderCandidates": float(self.class_rider_candidates),
+            "classWidenedLaunches": float(self.class_widened_launches),
             # resilience plane: worst lane state + fleet-summed counters
             "breakerState": max(
                 BREAKER_CODE[l.breaker.state] for l in self.plane.lanes

@@ -176,14 +176,19 @@ class DevicePlane:
     def __len__(self) -> int:
         return len(self.lanes)
 
+    def _throughput_engine(self):
+        """The engine that speaks for the THROUGHPUT lanes (they serve one
+        registry at one shape): the first that is no mesh lane's."""
+        for l in self.lanes:
+            if not l.mesh:
+                return l.engine
+        return self.lanes[0].engine
+
     @property
     def batch_size(self) -> int:
         # the THROUGHPUT batch width: a mesh lane's engine is typically a
         # small-batch shape and must not set the collector's drain size
-        for l in self.lanes:
-            if not l.mesh:
-                return l.engine.batch_size
-        return self.lanes[0].engine.batch_size
+        return self._throughput_engine().batch_size
 
     def add_lane(self, engine, breaker: CircuitBreaker | None = None,
                  mesh: bool = False) -> DeviceLane:
@@ -266,6 +271,14 @@ class DevicePlane:
 
     def inflight_launches(self) -> int:
         return sum(l.inflight() for l in self.lanes)
+
+    def launch_class(self, bitsets) -> list[int]:
+        """The launch class each candidate needs, as the throughput lanes'
+        engine names it (models/bn254_jax.py `launch_class`). An engine
+        without classes (the host stubs) has no such method: one class for
+        everything, and the service's planning by class is the identity."""
+        classify = getattr(self._throughput_engine(), "launch_class", None)
+        return classify(bitsets) if classify else [0] * len(bitsets)
 
     def host_cost(self) -> dict:
         """Per-launch host accounting SUMMED over the fleet's engines (the

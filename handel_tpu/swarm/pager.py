@@ -91,6 +91,8 @@ class PagedDevice:
         self.engine = engine
         self.pager = pager
         self.batch_size = engine.batch_size
+        if hasattr(engine, "launch_class"):  # an engine with launch classes
+            self.launch_class = engine.launch_class
 
     def dispatch_multi(self, items):
         touched: set[int] = set()

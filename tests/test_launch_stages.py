@@ -259,6 +259,48 @@ def test_class_counters_count_each_launch_once(curve, how):
     assert all(svc.values()[k] == 0.0 for k in CLASS_COUNTERS)
 
 
+_WIDE_DEVICES: dict = {}
+
+
+@pytest.mark.parametrize("holes", [0, 8, 9, 64, 65, 1024, 1025])
+def test_launch_class_is_the_class_the_packer_gives_the_candidate_alone(
+        curve, holes):
+    """`launch_class`, what the service plans launches by, and the packer
+    read a candidate's holes in one place (`_hull`): at every edge of the
+    4096-key ladder (8 | 64 | n // 4 | dense) the class named for a bitset
+    is the class `_pack_into` gives a launch that holds it alone, and the
+    class of a launch is that of its widest candidate — so no candidate is
+    ever verified by a narrower program than its holes need."""
+    n = 4096
+    dev = _WIDE_DEVICES.get(curve)
+    if dev is None:  # 16 real keys, repeated: only the packer runs
+        dev = _WIDE_DEVICES[curve] = curve.Device(
+            curve.pubkeys(16) * (n // 16), batch_size=C)
+    assert dev.patch_widths == (8, 64, 1024)
+
+    def candidate(lo, n_holes):
+        bs = BitSet(n)
+        bs.set_range(lo, lo + n_holes + 2)
+        for i in range(lo + 1, lo + 1 + n_holes):
+            bs.set(i, False)
+        return bs
+
+    def packed(bitsets):
+        plan = dev._pack_requests([(bs, curve.sig) for bs in bitsets])
+        return plan.miss_k if plan.kind == "range" else 0
+
+    # across a word boundary, at the registry's start and at its end
+    own = [candidate(lo, holes) for lo in (0, 61, n - holes - 2)]
+    named = dev.launch_class(own)
+    assert named == [packed([bs]) for bs in own]
+    assert len(set(named)) == 1
+    assert named[0] == next((k for k in (8, 64, 1024) if holes <= k), 0)
+    # beside narrower candidates the launch still takes this one's class
+    assert packed([candidate(7, 0), own[1], candidate(130, 3)]) == named[0]
+    assert dev.launch_class([]) == [] and dev.launch_class(
+        [BitSet(n)]) == [8]  # an empty bitset: no hull, no hole
+
+
 @pytest.mark.parametrize("how", ["dispatch", "dispatch_multi", "rlc"])
 def test_miller_step_counters_follow_the_loop_bits(curve, how):
     """Per launch, values() gains the Miller loop's steps and those whose
