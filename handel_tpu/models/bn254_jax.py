@@ -51,7 +51,7 @@ from handel_tpu.models.bn254 import (
 from handel_tpu.utils.breaker import CircuitBreaker
 from handel_tpu.ops import bn254_ref as bn
 from handel_tpu.ops.curve import BN254Curves
-from handel_tpu.ops.fp import device_platform, mul_step_cap
+from handel_tpu.ops.fp import MUL_ROW_SUBLANES, MUL_STEP_LANES, device_platform
 from handel_tpu.ops.pairing import BN254Pairing
 
 # Device-input arrays for one launch, as the packer hands them to dispatch:
@@ -361,9 +361,15 @@ class BN254Device:
 
     @property
     def fp_mul_step_lanes(self) -> int:
-        """The most lanes a step of the field's multiplication kernel
+        """The most lanes a pass of the multiplication kernel's body
         computes (the `fpMulStepLanes` gauge)."""
-        return mul_step_cap(self.field_limbs)
+        return MUL_STEP_LANES
+
+    @property
+    def fp_mul_row_sublanes(self) -> int:
+        """Sublanes of a vector register a limb row of that kernel fills at
+        its widest calls (the `fpMulRowSublanes` gauge)."""
+        return MUL_ROW_SUBLANES
 
     def _put_bank(self, pubkeys, what: str):
         """One registry bank on the device: the keys' affine coordinates as

@@ -8,14 +8,16 @@ TPU-friendly kernels. It is the risk item called out in SURVEY.md §7 hard part
 Design:
 
   * **Limbs-major layout.** An Fp element batch is a uint32 array of shape
-    (NLIMBS, B): limb index in the sublane dimension, batch in the lane
-    dimension. Every limb operation is then a full-width (B,) vector op on the
-    VPU — with batch-last, a 16-limb element would occupy 16/128 lanes.
+    (NLIMBS, B): limb index first, batch last, so every limb operation is a
+    vector op over the batch — with batch-last, a 16-limb element would
+    occupy 16/128 lanes. Inside the multiplication kernel a limb ROW is
+    handled as (8, 128) tiles of the batch, a whole vector register each
+    (`mul_tile`): as a (B,) row it would use one sublane in eight.
   * **16-bit limbs in uint32 lanes.** Limb products fit uint32 exactly (no
     mul-high needed) and anti-diagonal column sums of split lo/hi halves stay
     < 2^23, so carries are propagated lazily once per multiplication.
   * **Montgomery multiplication** (radix 2^16, CIOS-style column interleave)
-    as one fused Pallas kernel: inputs stream HBM->VMEM in (NLIMBS, TILE_B)
+    as one fused Pallas kernel: inputs stream HBM->VMEM in (NLIMBS, block)
     blocks, all ~n^2 limb products and column sums happen in VMEM/registers.
     Its speed on the chip is the benchmark's to say (`fp_mul.mac_rate`,
     PERF.md section 3). The naive XLA graph this replaces materializes
@@ -68,7 +70,6 @@ Correctness oracle: ops/bn254_ref.py; property tests in tests/test_fp_jax.py.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -77,39 +78,34 @@ import numpy as np
 LIMB_BITS = 16
 LIMB_MASK = (1 << LIMB_BITS) - 1
 
-# lane-dimension granularity: uint32 tiles are (8, 128); tile batches to 128
+# a uint32 vector register is a tile of 8 sublanes x 128 lanes; batches are
+# tiled to 128 lanes
 _LANE = 128
-# the constant of `mul_step_cap`'s fit: what 64 vector registers of 8 x 128
-# 32-bit words hold
-_VREG_WORDS = 64 * 8 * _LANE
+# sublanes a limb row of the multiplication kernel fills where the call has
+# that many, and the lanes a pass of its body then computes
+MUL_ROW_SUBLANES = 8
+MUL_STEP_LANES = MUL_ROW_SUBLANES * _LANE
+# passes a grid step's block holds at most: what scripts/fp_mul_sweep.py read
+# fastest, or within 1 % of it, from 4 608 lanes up in both fields
+_MUL_BLOCK_STEPS = 4
 
 
-def mul_step_cap(nlimbs: int) -> int:
-    """The field's step: the most lanes one grid step of the multiplication
-    kernel computes, whatever the call's width (512 at 16 limbs, 256 at 24).
+def mul_tile(width: int) -> tuple[int, int]:
+    """How `fp_mul_<limbs>x<width>` walks its lanes, from the width alone
+    (a multiple of 128): `(sublanes, block)`.
 
-    A FIT, not a model of the chip. It reads "the (7 x nlimbs + 1) rows the
-    unrolled body (`Field._mul_cols`) handles per lane come to no more words
-    than the vector register file", and it picks the step a sweep on a TPU
-    v5e read fastest at 16 and at 24 limbs (`scripts/fp_mul_sweep.py`). The
-    body does not fit the registers at those steps: the compiler spills at
-    every step the sweep tried, and what the step trades is the length of
-    its schedule a lane (`scripts/fp_mul_bundles.py` counts it, no chip
-    needed). Readings and what they establish: PERF.md section 6, PR 36.
-    Read both again before trusting it for a field of another limb count.
-    """
-    step = _LANE
-    while (7 * nlimbs + 1) * 2 * step <= _VREG_WORDS:
-        step *= 2
-    return step
-
-
-def mul_step(nlimbs: int, width: int) -> int:
-    """Lanes one grid step of `fp_mul_<nlimbs>x<width>` computes: the
-    field's step (`mul_step_cap`) where it divides the width, else the
-    widest power of two of lanes below it that does (9 984 = 39 x 256). The
-    width, a multiple of 128, only says how many steps there are."""
-    return math.gcd(width, mul_step_cap(nlimbs))
+    A pass of the kernel's body (`Field._mul_cols`) computes limb rows that
+    are `(sublanes, 128)` tiles: 8 sublanes, a whole vector register a row,
+    wherever the call has that many (a 128-lane call has one, and its rows
+    stay `(128,)`). A grid step moves `block` lanes, `_MUL_BLOCK_STEPS`
+    passes' worth or the call's whole width rounded up to passes, whichever
+    is less, and a loop in the kernel walks them. Where the block does not
+    divide the width the last block is partial, and the loop stops after the
+    last pass that holds lanes of the call. Readings: PERF.md section 6,
+    PR 39."""
+    sublanes = min(width // _LANE, MUL_ROW_SUBLANES)
+    step = sublanes * _LANE
+    return sublanes, min(-(-width // step), _MUL_BLOCK_STEPS) * step
 
 
 def _int_to_limbs(x: int, nlimbs: int) -> np.ndarray:
@@ -379,11 +375,12 @@ class Field:
 
     def _mul_cols(self, a, b):
         """Full schoolbook product + interleaved Montgomery reduction on
-        limbs-major operands; returns canonical (nlimbs, B) limbs.
+        limbs-major operands; returns canonical limbs in the operands' shape.
 
         Column magnitudes stay < 2^23 (<= 2n 16-bit terms per column plus
         reduction contributions), so a single lazy carry pass at the end
-        suffices. Statically unrolled: no data-dependent control flow.
+        suffices. Statically unrolled: no data-dependent control flow. A
+        row (`a[i]`) may have any shape: (B,), or the kernel's (S, 128).
         """
         n = self.nlimbs
         zero = jnp.zeros_like(a[0])
@@ -480,7 +477,8 @@ class Field:
     # carry recurrence. ~10 elementwise/reduction ops per add, no data
     # movement, fuses into one kernel on every backend. Requires nlimbs < 32.
     # The unrolled per-limb forms are kept for the Pallas kernel body, where
-    # Mosaic wants straight-line register code.
+    # Mosaic wants straight-line register code; they index rows with `a[i]`
+    # and take a row of any shape, so the kernel hands them (8, 128) tiles.
 
     @property
     def _bit_weights(self):
@@ -602,6 +600,10 @@ class Field:
         return self.mul(a, a)
 
     def _mul_pallas(self, a, b):
+        """`_mul_cols` as the Mosaic call `fp_mul_<limbs>x<lanes>` over 2-D
+        `u32[limbs, lanes]` operands: a grid over blocks of lanes, each
+        computed in passes whose limb rows are register tiles (`mul_tile`
+        says how many sublanes and how wide a block, from the width)."""
         from jax.experimental import pallas as pl
         from jax.experimental.pallas import tpu as pltpu
 
@@ -618,25 +620,43 @@ class Field:
             return self._mul_pallas(pad(a), pad(b))[:, :bsz]
         fn = self._pallas_fns.get(bsz)
         if fn is None:
-            step = mul_step(n, bsz)
+            sublanes, block = mul_tile(bsz)
+            step = sublanes * _LANE
+            # a limb row as a (sublanes, 128) tile fills its registers; as a
+            # (step,) row it would take one sublane of each
+            tile = (n, sublanes, _LANE) if sublanes > 1 else (n, _LANE)
 
             def kernel(a_ref, b_ref, o_ref):
-                o_ref[:] = self._mul_cols(a_ref[:], b_ref[:])
+                def rows(at):
+                    out = self._mul_cols(
+                        a_ref[:, at].reshape(tile), b_ref[:, at].reshape(tile))
+                    o_ref[:, at] = out.reshape(n, step)
 
+                if block == step:
+                    rows(slice(None))
+                    return
+
+                def one(k, carry):
+                    rows(pl.ds(pl.multiple_of(k * step, step), step))
+                    return carry
+
+                steps = block // step
+                if bsz % block:  # the last block is partial
+                    left = pl.cdiv(bsz, step) - pl.program_id(0) * steps
+                    steps = jnp.minimum(steps, left)
+                jax.lax.fori_loop(0, steps, one, 0)
+
+            spec = pl.BlockSpec(
+                (n, block), lambda i: (0, i), memory_space=pltpu.VMEM)
             fn = pl.pallas_call(
                 kernel,
                 # operation and stacked width, so a profiler trace tells
                 # the multiplications of one phase from another's
                 name=f"fp_mul_{n}x{bsz}",
                 out_shape=jax.ShapeDtypeStruct((n, bsz), jnp.uint32),
-                grid=(bsz // step,),
-                in_specs=[
-                    pl.BlockSpec((n, step), lambda i: (0, i), memory_space=pltpu.VMEM),
-                    pl.BlockSpec((n, step), lambda i: (0, i), memory_space=pltpu.VMEM),
-                ],
-                out_specs=pl.BlockSpec(
-                    (n, step), lambda i: (0, i), memory_space=pltpu.VMEM
-                ),
+                grid=(pl.cdiv(bsz, block),),
+                in_specs=[spec, spec],
+                out_specs=spec,
             )
             self._pallas_fns[bsz] = fn
         return fn(a, b)

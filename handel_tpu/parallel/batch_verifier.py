@@ -1234,6 +1234,7 @@ class BatchVerifierService:
             "devicesAvailable",
             "fieldLimbs",
             "fpMulStepLanes",
+            "fpMulRowSublanes",
             "keyGroup",
             "meshLanes",
             "meshLanesAvailable",

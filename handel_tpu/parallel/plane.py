@@ -346,10 +346,15 @@ class DevicePlane:
                 (getattr(l.engine, "field_limbs", 0) for l in self.lanes),
                 default=0,
             )),
-            # ... how many lanes a step of its multiplication kernel computes
-            # (ops/fp.py `mul_step_cap`; 0 for host stubs)
+            # ... how many lanes a pass of its multiplication kernel's body
+            # computes and how many sublanes of a register a limb row fills
+            # there (ops/fp.py `mul_tile`; 0 for host stubs)
             "fpMulStepLanes": float(max(
                 (getattr(l.engine, "fp_mul_step_lanes", 0) for l in self.lanes),
+                default=0,
+            )),
+            "fpMulRowSublanes": float(max(
+                (getattr(l.engine, "fp_mul_row_sublanes", 0) for l in self.lanes),
                 default=0,
             )),
             # ... and which group their registry keys live in (1 or 2:

@@ -1,24 +1,30 @@
 #!/usr/bin/env python3
-"""`Field.mul`'s kernel alone, swept over how many lanes one step computes.
+"""`Field.mul`'s kernel alone, swept over the form of a limb row, the lanes
+a pass of the body computes and the lanes a grid step moves.
 
     python scripts/fp_mul_sweep.py [--limbs 16 24] [--out chiprun_out/fp_mul_sweep.json]
 
-The table behind `ops/fp.py` `mul_step`: for each field (16 limbs BN254, 24
+The table behind `ops/fp.py` `mul_tile`: for each field (16 limbs BN254, 24
 BLS12-381) and each stacked width the launch programs contain, the Montgomery
-multiplication's body (`Field._mul_cols`) is built into a kernel of its own for
-every (block, step) that divides the width —
+multiplication's body (`Field._mul_cols`) is built into a kernel of its own
+(`kernel_call`) in these forms —
 
-  * form A, block == step: one grid step computes `step` lanes;
-  * form B, block > step: the pipeline moves a block of up to 2 048 lanes
-    and a `fori_loop` computes it in slices of `step` lanes;
+  * `flat`, the form `Field.mul` had up to PR 38: a limb row is `(step,)`,
+    one sublane of each register it takes, at that rule's step (512 lanes at
+    16 limbs, 256 at 24, or the widest power of two under it that divides);
+  * `tiled`: a limb row is a `(step // 128, 128)` tile, reshaped inside the
+    kernel. At 8 sublanes (a whole register a row) with blocks of 1, 2, 4, 8
+    and 16 passes and the whole width as one block, the last block partial
+    where the width is no multiple; and at the sublane counts that divide
+    a width 8 does not (6, 13, 18), a pass a grid step;
 
-and the row marked `shipped` is the form `Field.mul` itself takes, at the step
-`mul_step` gives. Every kernel runs 16 times under the profiler (two calls of a
+and the row marked `shipped` is the form `Field.mul` itself takes
+(`mul_tile`). Every kernel runs 16 times under the profiler (two calls of a
 jitted chain of eight); a row is the median device time of its kernel's
 events, in ns a lane, beside the host's clock over 20 more calls. Every form
-must give `Field.mul`'s limbs exactly. The dense class's widest call
-(`fp_mul_16x4718592`, no benchmark cell) is timed at the shipped step and at
-a 2 048-lane block only.
+and `Field.mul` itself must give the limbs of the plain XLA form
+(`Field._mul_cols_vec`) exactly. The dense class's widest call
+(`fp_mul_16x4718592`, no benchmark cell) is timed in three forms only.
 
 Without a TPU it measures nothing and exits 1. `--tiny` is the CPU rehearsal
 of the script itself: the first field of `--limbs` at one small width,
@@ -29,6 +35,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import statistics
 import sys
@@ -51,53 +58,81 @@ from handel_tpu.ops import bn254_ref as bn  # noqa: E402
 from handel_tpu.ops import fp  # noqa: E402
 
 CHAIN = 8
-STEPS = (128, 256, 512, 1024, 2048)
-# widths of the launch programs: the cyclotomic squarings (2 304), a patch
-# stage of three blocks of 2 048 (6 144), the Miller squaring (9 216) and line
-# product (9 984), and the patch's first stage in G1 at 24 limbs / G2 at 16
+# the flat form's step up to PR 38 (`mul_step_cap`), cut to a power of two
+# that divides the width
+FLAT_STEP = {16: 512, 24: 256}
+# widths of the launch programs: the tail's stacked products from 1 536 lanes
+# (12 registers a row) to the Miller squaring (9 216) and line product
+# (9 984 = 78 x 128), and stages of the wide patch in G2 at 16 limbs / G1 at 24
 DENSE = 4718592  # the dense sum's first stage: 4096 keys x 128 lanes x 9
-WIDTHS = {16: (2304, 6144, 9216, 9984, 589824, DENSE), 24: (2304, 6144, 9216, 9984, 196608)}
+WIDTHS = {
+    16: (256, 1536, 2304, 3072, 4608, 6912, 9216, 9984, 147456, 589824, DENSE),
+    24: (256, 1536, 2304, 3840, 6912, 9216, 9984, 49152, 196608),
+}
 PRIMES = {16: bn.P, 24: bls.P}
 
 
-def kernel_call(F, width: int, block: int, step: int, name: str, interpret: bool):
+def kernel_call(F, width: int, block: int, step: int, name: str, interpret: bool,
+                tiled: bool = False):
+    """`Field._mul_cols` as a kernel of its own: the pipeline moves `block`
+    lanes a grid step, the body computes them `step` lanes at a time. Flat
+    (the form up to PR 38): a limb row is `(step,)`, one sublane of each
+    register it takes. `tiled`: a limb row is a `(step // 128, 128)` tile,
+    reshaped inside the kernel. A width the block does not divide ends in a
+    partial block; the loop then stops at the last step that holds lanes."""
     n = F.nlimbs
+    tile = (n, step // fp._LANE, fp._LANE)
+
+    def body(a, b):
+        if not tiled:
+            return F._mul_cols(a, b)
+        return F._mul_cols(a.reshape(tile), b.reshape(tile)).reshape(n, step)
 
     def kernel(a_ref, b_ref, o_ref):
         if block == step:
-            o_ref[:] = F._mul_cols(a_ref[:], b_ref[:])
+            o_ref[:] = body(a_ref[:], b_ref[:])
             return
 
         def one(k, carry):
             at = pl.ds(pl.multiple_of(k * step, step), step)
-            o_ref[:, at] = F._mul_cols(a_ref[:, at], b_ref[:, at])
+            o_ref[:, at] = body(a_ref[:, at], b_ref[:, at])
             return carry
 
-        lax.fori_loop(0, block // step, one, 0)
+        steps = block // step
+        if width % block:
+            left = pl.cdiv(width, step) - pl.program_id(0) * steps
+            steps = jnp.minimum(steps, left)
+        lax.fori_loop(0, steps, one, 0)
 
     spec = pl.BlockSpec((n, block), lambda i: (0, i), memory_space=pltpu.VMEM)
     return pl.pallas_call(
         kernel,
         name=name,
         out_shape=jax.ShapeDtypeStruct((n, width), jnp.uint32),
-        grid=(width // block,),
+        grid=(pl.cdiv(width, block),),
         in_specs=[spec, spec],
         out_specs=spec,
         interpret=interpret,
     )
 
 
-def forms(width: int):
-    """(block, step) of every form the width admits."""
-    blocks = [s for s in STEPS if width % s == 0]
+def forms(nlimbs: int, width: int):
+    """(block, step, tiled) of every form timed at this width."""
+    flat = math.gcd(width, FLAT_STEP[nlimbs])
+    yield flat, flat, False
+    sublanes, shipped = fp.mul_tile(width)
+    step = sublanes * fp._LANE
+    passes = -(-width // step)
     if width == DENSE:
-        yield from ((s, s) for s in (512, 2048))
+        yield from ((k * step, step, True) for k in (4, 16))
         return
-    for s in blocks:
-        yield s, s
-    if blocks[-1] >= 1024:
-        for s in blocks[:-1]:
-            yield blocks[-1], s
+    # the whole width as one block where it is at most 16 passes (VMEM)
+    blocks = {min(k, passes) * step for k in (1, 2, 4, 8, 16)}
+    for block in sorted(blocks | {shipped}):
+        yield block, step, True
+    for rows in (6, 13, 18):
+        if width % step and width % (rows * fp._LANE) == 0:
+            yield rows * fp._LANE, rows * fp._LANE, True
 
 
 def main() -> int:
@@ -114,25 +149,35 @@ def main() -> int:
                           "error": "no TPU: this sweep measures nothing on another backend"}))
         return 1
     rng = np.random.default_rng(36)
-    rows, chains = [], []
+    # wrong: the calls at which `Field.mul` itself differs from the plain form
+    rows, chains, wrong = [], [], []
     for n in args.limbs[:1] if args.tiny else args.limbs:
         F = fp.Field(PRIMES[n])
         assert F.nlimbs == n
-        for width in ((1024,) if args.tiny else WIDTHS[n]):
+        for width in ((2304,) if args.tiny else WIDTHS[n]):
             a, b = (
                 jnp.asarray(rng.integers(0, 1 << 16, (n, width), dtype=np.uint32))
                 for _ in range(2)
             )
-            want = np.asarray(F.mul(a, b))
-            for block, step in forms(width):
-                name = f"sweep_{n}x{width}_b{block}_s{step}"
-                mul = kernel_call(F, width, block, step, name, not on_chip)
+            # the plain XLA form holds (limbs, limbs, lanes) words: by slices
+            vec, cut = jax.jit(F._mul_cols_vec), min(width, 49152)
+            want = np.concatenate(
+                [np.asarray(vec(a[:, i : i + cut], b[:, i : i + cut]))
+                 for i in range(0, width, cut)], axis=1)
+            sublanes, shipped_block = fp.mul_tile(width)
+            shipped = (shipped_block, sublanes * fp._LANE, True)
+            if on_chip and not np.array_equal(np.asarray(F.mul(a, b)), want):
+                wrong.append(f"fp_mul_{n}x{width}")
+            for block, step, tiled in forms(n, width):
+                name = f"sweep_{n}x{width}_{'t' if tiled else 'f'}_b{block}_s{step}"
+                mul = kernel_call(F, width, block, step, name, not on_chip, tiled)
 
                 same = bool(np.array_equal(np.asarray(mul(a, b)), want))
                 row = {
-                    "limbs": n, "width": width, "block": block, "step": step,
-                    "form": "A" if block == step else "B", "kernel": name,
-                    "shipped": block == step == fp.mul_step(n, width),
+                    "limbs": n, "width": width, "rows": "tiled" if tiled else "flat",
+                    "sublanes": step // fp._LANE if tiled else 1,
+                    "block": block, "step": step, "kernel": name,
+                    "shipped": (block, step, tiled) == shipped,
                     "same_limbs": same,
                 }
                 rows.append(row)
@@ -186,13 +231,14 @@ def main() -> int:
         else:  # say what the trace called its operations instead
             row["events"] = len(ns)
             row["seen"] = sorted(took)[:40]
-    result = {"device": device, "chain": CHAIN, "rows": rows}
+    result = {"device": device, "chain": CHAIN, "field_mul_differs": wrong, "rows": rows}
     with open(args.out, "w") as f:
         json.dump(result, f, indent=1)
     for row in rows:
         print(json.dumps(row))
-    ok = all(r["same_limbs"] and "ns_per_lane" in r for r in rows)
-    print(json.dumps({"device": device, "rows": len(rows), "timed": True, "ok": ok}))
+    ok = not wrong and all(r["same_limbs"] and "ns_per_lane" in r for r in rows)
+    print(json.dumps({"device": device, "rows": len(rows), "timed": True,
+                      "field_mul_differs": wrong, "ok": ok}))
     return 0 if ok else 1
 
 

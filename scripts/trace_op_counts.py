@@ -9,11 +9,12 @@ Takes the WHOLE executions of the launch program (`jit_verify_*` on the
 "XLA Modules" line) and, over the operations inside them ("XLA Ops"), prints
 one JSON line: per kernel name (`fp_mul_<limbs>x<lanes>`) the calls and the
 self milliseconds a launch, the microseconds a call, the nanoseconds a lane
-and, for the Montgomery multiplication, `step_by_this_checkout`: the lanes a
-step computes at that width by `ops/fp.py` `mul_step` of the checkout this
-script runs from. A trace does not record the kernel's grid, so for a trace
-another commit's program wrote that field is not the step the kernel ran
-with. Then the executed `conditional`s and `while`s a launch, and the
+and, for the Montgomery multiplication, `sublanes_by_this_checkout` and
+`block_by_this_checkout`: the sublanes of a register a limb row fills (S) and
+the lanes a grid step moves at that width by `ops/fp.py` `mul_tile` of the
+checkout this script runs from. A trace does not record the kernel's grid,
+so for a trace another commit's program wrote they are not what the kernel
+ran with. Then the executed `conditional`s and `while`s a launch, and the
 launch's time outside the kernels (the XLA glue). The second witness of
 ops/pairing.py's loop over the runs of its public bits: a 0-bit step leaves
 no `fp_mul_<limbs>x3072` call (the addition step's) in the trace.
@@ -30,18 +31,18 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmark import trace_reduce  # noqa: E402
-from handel_tpu.ops.fp import mul_step  # noqa: E402
+from handel_tpu.ops.fp import mul_tile  # noqa: E402
 
 _KERNEL = re.compile(r"%?((?:fp|rns)_mul_(\d+)x(\d+))")
 
 
 def per_lane(kernel: str, ns_per_call: float) -> dict:
-    """A kernel's time a lane, and the step this checkout's rule gives its
-    width (the trace does not say what step the traced program ran)."""
-    _, rows, lanes = _KERNEL.fullmatch(kernel).groups()
+    """A kernel's time a lane, and the tile this checkout's rule gives its
+    width (the trace does not say how the traced program walked it)."""
+    _, _, lanes = _KERNEL.fullmatch(kernel).groups()
     out = {"ns_per_lane": ns_per_call / int(lanes)}
     if kernel.startswith("fp_"):  # `rns_mul_*` tiles by its own rule (ops/rns.py)
-        out["step_by_this_checkout"] = mul_step(int(rows), int(lanes))
+        out["sublanes_by_this_checkout"], out["block_by_this_checkout"] = mul_tile(int(lanes))
     return out
 
 

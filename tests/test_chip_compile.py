@@ -85,16 +85,17 @@ def mosaic_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
-def assert_grid_follows_the_field(F, x):
-    """The kernel's grid is width / step and each block is one step of the
-    field (`fp.mul_step`): the width says how many steps, not how wide."""
+def assert_grid_follows_the_width(F, x):
+    """The kernel's grid and block are what `fp.mul_tile` reads off the
+    width: full blocks of whole passes and, where they do not divide it, a
+    partial one at the end."""
     (call,) = [e for e in jax.make_jaxpr(F.mul)(x, x).eqns
                if e.primitive.name == "pallas_call"]
-    step = fp.mul_step(F.nlimbs, x.shape[1])
+    _, block = fp.mul_tile(x.shape[1])
     grid = call.params["grid_mapping"]
-    assert grid.grid == (x.shape[1] // step,)
+    assert grid.grid == (-(-x.shape[1] // block),)
     assert {tuple(d.block_size for d in b.block_shape)
-            for b in grid.block_mappings} == {(F.nlimbs, step)}
+            for b in grid.block_mappings} == {(F.nlimbs, block)}
 
 
 # lane widths of stacked Field.mul calls read out of the lowered full-width
@@ -104,9 +105,10 @@ def assert_grid_follows_the_field(F, x):
 # pairs, 13824 = the general Fp12 product over them (the widest mul of the
 # range launch), 4718592 = the dense launch's widest (4096 keys x 128 lanes
 # x 9 stacked muls of a G2 add), 589824 = the wide hole patch's first
-# stage in G2 (512 x 128 x 9: 1 152 steps of 512 lanes)
+# stage in G2 (512 x 128 x 9: 576 passes of 1 024 lanes), 256 and 2304 =
+# a call of two sublanes and one whose last pass is a quarter full
 @pytest.mark.parametrize(
-    "width", [128, 6912, 9216, 9984, 13824, 589824, 4718592])
+    "width", [128, 256, 2304, 6912, 9216, 9984, 13824, 589824, 4718592])
 def test_cios_mul_bn254(shape, chip_choices, width):
     F = fp.Field(bn.P)
     assert F.use_pallas and F.nlimbs == 16
@@ -115,12 +117,12 @@ def test_cios_mul_bn254(shape, chip_choices, width):
     assert mosaic_calls(compiled) == 1
     # the kernel's name says operation and stacked width (profiler traces)
     assert f"%fp_mul_16x{width}" in compiled.as_text()
-    assert_grid_follows_the_field(F, x)
+    assert_grid_follows_the_width(F, x)
 
 
 # the same widths of the BLS12-381 range launch's pairing tail, and the G1
-# patch's first stage (512 x 128 x 6 = 393216: 1 536 steps of 256 lanes)
-@pytest.mark.parametrize("width", [128, 6912, 9216, 9984, 13824, 393216])
+# patch's first stage (512 x 128 x 6 = 393216: 384 passes of 1 024 lanes)
+@pytest.mark.parametrize("width", [128, 256, 2304, 6912, 9216, 9984, 13824, 393216])
 def test_cios_mul_bls12_381(shape, chip_choices, width):
     F = fp.Field(bls.P)
     assert F.use_pallas and F.nlimbs == 24
@@ -128,7 +130,7 @@ def test_cios_mul_bls12_381(shape, chip_choices, width):
     compiled = jax.jit(F.mul).lower(x, x).compile()
     assert mosaic_calls(compiled) == 1
     assert f"%fp_mul_24x{width}" in compiled.as_text()
-    assert_grid_follows_the_field(F, x)
+    assert_grid_follows_the_width(F, x)
 
 
 def test_rns_resident_mul(shape, chip_choices):

@@ -60,7 +60,6 @@ _accept = jax.jit(lambda: jnp.ones((1,), bool))
 
 class _BN254:
     Device, limbs, key_group = BN254Device, 16, 2
-    mul_step = 512  # lanes a step of `fp_mul_16x<lanes>` computes
     # 64 steps of 6u+2 and the two tail additions; 36 bits are set, and
     # only their steps add
     miller = (66, 38)
@@ -78,7 +77,6 @@ class _BN254:
 
 class _BLS12381:
     Device, limbs, key_group = BLS12381Device, 24, 2
-    mul_step = 256  # ... and of `fp_mul_24x<lanes>`
     # |z|: 63 steps, no tail addition; 5 bits set
     miller = (63, 5)
     acc_fp_muls = 4920  # 63 x (36 + 39) + 5 x 39
@@ -175,9 +173,12 @@ def test_stage_counters_add_up(curve, how):
     assert svc.values()["fieldLimbs"] == curve.limbs == dev.field_limbs
     # ... and which group holds the registry keys
     assert svc.values()["keyGroup"] == curve.key_group == dev.key_group
-    # ... and how many lanes a step of its multiplication kernel computes
-    assert svc.values()["fpMulStepLanes"] == curve.mul_step == dev.fp_mul_step_lanes
-    assert {"fieldLimbs", "keyGroup", "fpMulStepLanes"} <= svc.gauge_keys()
+    # ... and which form of the multiplication kernel it compiled: a limb row
+    # fills the 8 sublanes of a register, 1 024 lanes a pass, in every field
+    assert svc.values()["fpMulRowSublanes"] == 8 == dev.fp_mul_row_sublanes
+    assert svc.values()["fpMulStepLanes"] == 1024 == dev.fp_mul_step_lanes
+    assert {"fieldLimbs", "keyGroup", "fpMulStepLanes",
+            "fpMulRowSublanes"} <= svc.gauge_keys()
     rng = random.Random(7)
     before = svc.values()
     for _ in range(launches):
