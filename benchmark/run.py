@@ -37,6 +37,7 @@ import os
 import shutil
 import statistics
 import sys
+from collections import Counter
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -102,21 +103,77 @@ class GcMeter:
         return {"by_generation": out, "longest_ms": 1e3 * longest}
 
 
-def stalls(done_times, t0: float, t1: float) -> dict:
+LONG_COLLECTION_S = 0.020  # a collection this long is looked for in the stalls
+
+
+def stalls(done_times, t0: float, t1: float, collections=()) -> dict:
     """Where a rate below the steady one went: verdicts come back in bursts,
     one a launch; the steady interval is the median between bursts, and a
-    STALL is an interval over 1.5 times that. From the records, after the
-    window; costs the window nothing."""
+    STALL is an interval over 1.5 times that. `collections` are the
+    collector's `(start, seconds, generation)` events: a stall that one of
+    LONG_COLLECTION_S or more overlaps is counted under `with_collection`.
+    From the records, after the window; costs the window nothing."""
     ts = sorted(t for t in done_times if t0 <= t <= t1)
     starts = [b for a, b in zip(ts, ts[1:]) if b - a > 0.005]
     gaps = [b - a for a, b in zip(starts, starts[1:])]
     if len(gaps) < 3:
         return {"bursts": len(starts)}
     steady = statistics.median(gaps)
-    late = [g for g in gaps if g > 1.5 * steady]
+    long = [(t, t + s) for t, s, _ in collections if s >= LONG_COLLECTION_S]
+    late = [(a, b) for a, b in zip(starts, starts[1:]) if b - a > 1.5 * steady]
+    under = [(a, b) for a, b in late if any(c < b and d > a for c, d in long)]
+    lost = lambda pairs: 1e3 * sum(b - a - steady for a, b in pairs)
     return {"bursts": len(starts), "steady_interval_ms": 1e3 * steady,
-            "stalls": len(late), "stalled_ms": 1e3 * sum(g - steady for g in late),
-            "longest_interval_ms": 1e3 * max(gaps)}
+            "stalls": len(late), "stalled_ms": lost(late),
+            "longest_interval_ms": 1e3 * max(gaps),
+            "stalls_with_collection": len(under),
+            "stalled_ms_with_collection": lost(under)}
+
+
+def slices(records, t0: float, t1: float, step: float = 10.0,
+           collections=()) -> list[dict]:
+    """The window as consecutive pieces of `step` seconds (the last takes the
+    remainder): what each completed, the latency of the requests issued in
+    it, and its `stalls`. One long run then reads as many short windows with
+    one set-up. From the records, after the window; a window under two steps
+    gives none. The pieces' candidates, requests and latency samples add up
+    to the window's."""
+    n = int((t1 - t0) / step)
+    if n < 2:
+        return []
+    piece = lambda t: min(n - 1, int((t - t0) / step))
+    done = [[] for _ in range(n)]
+    lat = [[] for _ in range(n)]
+    cands = [0] * n
+    for r in records:
+        if r.verdicts is None:
+            continue
+        if t0 <= r.done <= t1:
+            k = piece(r.done)
+            done[k].append(r.done)
+            cands[k] += len(r.verdicts)
+        if t0 <= r.due < t1:
+            lat[piece(r.due)].append(r.done - r.due)
+    out = []
+    for k in range(n):
+        a, b = t0 + k * step, (t0 + (k + 1) * step if k < n - 1 else t1)
+        ls = sorted(lat[k])
+        completion = stalls(done[k], a, b, collections)
+        out.append({
+            "from_s": a - t0, "seconds": b - a,
+            "requests_completed": len(done[k]),
+            "candidates_completed": cands[k],
+            "candidates_per_s": cands[k] / (b - a),
+            "latency_samples": len(ls),
+            "p50_ms": 1e3 * statistics.median(ls) if ls else None,
+            "p95_ms": 1e3 * percentile(ls, 0.95) if ls else None,
+            "launches": completion["bursts"],
+            "completion": completion,
+            "collections": sum(a <= t < b for t, _, _ in collections),
+            "longest_collection_ms": 1e3 * max(
+                (s for t, s, _ in collections if a <= t < b), default=0.0),
+        })
+    return out
 
 
 def percentile(sorted_vals, q: float) -> float:
@@ -191,12 +248,19 @@ def build_requests(cell, ref, seed: int, lanes: int, rehearse: bool):
     t0 = time.perf_counter()
     ref.load()  # builds the reference library, once per checkout
     t1 = time.perf_counter()
-    points, pool, msg = tg.make_pool(cfg, tr, seed, ref)
+    stats: dict = {}
+    points, pool, msg = tg.make_pool(cfg, tr, seed, ref, stats)
+    ladder = cfg["guarantees"]["launch_classes"]
+    by_class = Counter(
+        tg.class_of(c.hull_holes(), ladder) for r in pool for c in r)
     say(phase="pool", reference_build_s=t1 - t0, keys=len(points),
         failing=cfg["deployment"]["failing"], requests=len(pool),
         candidates=sum(len(r) for r in pool),
         forged=sum(c.forged for r in pool for c in r),
-        launch_classes=tr["launch_classes"], seconds=time.perf_counter() - t1)
+        launch_classes=tr["launch_classes"],
+        candidates_by_class={cls["name"]: by_class[i]
+                             for i, cls in enumerate(ladder)},
+        seconds=time.perf_counter() - t1, **stats)
     return tr, points, pool, msg
 
 
@@ -307,6 +371,7 @@ class Tracer:
         self.rehearsal = rehearsal
         self.dir = os.path.join(OUT_DIR, "trace", workload)
         self.t_started_epoch = None
+        self.stop_s = None  # what ending the session and writing it took
 
     def start(self):
         import jax
@@ -329,18 +394,22 @@ class Tracer:
         import jax
         from jax._src import profiler as jp
 
-        state = getattr(jp, "_profile_state", None)
-        session = getattr(state, "profile_session", None)
-        if session is None or not hasattr(session, "stop"):
-            jax.profiler.stop_trace()
-            return
-        with state.lock:
-            data = session.stop()
-            state.reset()
-        out = os.path.join(self.dir, "plugins", "profile", "run")
-        os.makedirs(out, exist_ok=True)
-        with open(os.path.join(out, "bench.xplane.pb"), "wb") as f:
-            f.write(data)
+        t0 = time.perf_counter()
+        try:
+            state = getattr(jp, "_profile_state", None)
+            session = getattr(state, "profile_session", None)
+            if session is None or not hasattr(session, "stop"):
+                jax.profiler.stop_trace()
+                return
+            with state.lock:
+                data = session.stop()
+                state.reset()
+            out = os.path.join(self.dir, "plugins", "profile", "run")
+            os.makedirs(out, exist_ok=True)
+            with open(os.path.join(out, "bench.xplane.pb"), "wb") as f:
+                f.write(data)
+        finally:
+            self.stop_s = time.perf_counter() - t0
 
     def reduce(self, t1_epoch: float):
         """Reduce [start + settle, window end], placed on the trace's clock
@@ -447,6 +516,11 @@ def run(args) -> dict:
     window_compile = res.marks["t1"]["compile"]
     names = cfg["guarantees"]  # the program's counter names
     delta = lambda key: c1[key] - c0[key]
+    cut = {}  # the window in pieces: `slices` (10 s), `slices_<n>s`
+    for step in args.slice_seconds:
+        pieces = slices(res.records, res.t0, res.t1, step, gc_meter.events)
+        if pieces:
+            cut["slices" if step == 10 else f"slices_{step:g}s"] = pieces
     say(phase="window", seconds=window_s, setup_s=setup_s,
         latency_samples=len(latencies), attempted=len(sample), failed=failed,
         requests_completed_in_window=len(done_in_window),
@@ -458,8 +532,9 @@ def run(args) -> dict:
         compile_events_in_window=window_compile["events"],
         compile_in_window=window_compile,
         gc_in_window=gc_meter.between(res.t0, res.t1),
-        completion=stalls([r.done for r in res.records], res.t0, res.t1),
-        errors=sorted({r.error for r in res.records if r.error})[:3])
+        completion=stalls([r.done for r in res.records], res.t0, res.t1,
+                          gc_meter.events),
+        errors=sorted({r.error for r in res.records if r.error})[:3], **cut)
 
     # -- correct: the served answers against the reference -------------------
     t0 = time.perf_counter()
@@ -496,6 +571,7 @@ def run(args) -> dict:
         for m in cell.end_to_end():
             metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
     else:
+        t_read = time.perf_counter()
         red, path, to_epoch = tracer.reduce(res.t1_epoch)
         device["busy_s"], device["window_s"] = red.busy_s, red.window_s
         ctx = ReaderContext(cell, lanes, res, latencies, sink, red)
@@ -524,7 +600,8 @@ def run(args) -> dict:
                 [t for t in ends if t < tracer.t_started_epoch - 0.5]),
             launch_interval_ms_under_trace=cadence(
                 [t for t in ends if t >= tracer.t_started_epoch]),
-            xplane_bytes=os.path.getsize(path), busy_s=red.busy_s,
+            xplane_bytes=os.path.getsize(path), session_stop_s=tracer.stop_s,
+            reduce_and_read_s=time.perf_counter() - t_read, busy_s=red.busy_s,
             window_s=red.window_s, spans=len(sink.spans),
             dropped_after_s=None if red.dropped_from_ns is None
             else (red.dropped_from_ns - red.t0_ns) / 1e9,
@@ -566,6 +643,10 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="sandbox only: no TPU needed, cut sizes, no metric, "
                          "correct false")
+    ap.add_argument("--slice-seconds", type=float, nargs="+", default=[10.0],
+                    help="the `window` line's `slices`: the window cut into "
+                         "pieces this long (10: `slices`; others: "
+                         "`slices_<n>s`), for a long run read as many windows")
     ap.add_argument("--benchmark", default="",
                     help="another BENCHMARK.json (tests: a cell that is not "
                          "in the committed one)")

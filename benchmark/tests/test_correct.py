@@ -16,7 +16,8 @@ import pytest
 import control
 import spec
 
-CELLS = ["handel4096-99thr.closed256", "handel4096-51thr-failing.closed256"]
+CELLS = ["handel4096-99thr.closed256", "handel4096-51thr-failing.closed256",
+         "handel4096-51thr-failing.mixed-levels"]  # the last: a pool held to its classes
 
 
 @pytest.mark.parametrize("cell_name", CELLS)
@@ -41,7 +42,7 @@ def rehearse(capsys, cell_name="handel4096-99thr.closed256"):
 
     args = argparse.Namespace(
         workload=cell_name, seed=2**31 + 3, seconds=3.0, trace=0,
-        rehearse=True, benchmark="", trace_summary="",
+        rehearse=True, benchmark="", trace_summary="", slice_seconds=[10.0],
     )
     result, checks = run.run(args)
     lines = [l for l in capsys.readouterr().out.splitlines() if l.startswith("{")]

@@ -49,6 +49,26 @@ def test_gap_labels():
         {"dispatch_pack": 0.3, "launch_fetched": 0.3, "none": 0.1})
 
 
+def test_execution_cut_by_the_session_is_not_whole():
+    """The profiler cuts the launch that is running when its session ends to
+    the session: 40 of a 100-long execution. It is no whole execution, and
+    counts as 0.4 of one, not as 1 (PERF.md section 7, request 1)."""
+    ops = [(k * 100 + 1, k * 100 + 99, "op") for k in range(3)] + [(301, 340, "op")]
+    mods = [(k * 100, k * 100 + 100, "jit_verify_range8") for k in range(3)] + [
+        (300, 340, "jit_verify_range8"), (10, 12, "jit_other")]
+    red = tr.reduce_loaded(tr.Loaded("made", [("/device:TPU:0", ops, mods)], None, None),
+                           0, 340)
+    plane = red.planes[0]
+    assert plane.modules["jit_verify_range8"] == (3, 300)
+    assert plane.executions["jit_verify_range8"] == pytest.approx(3.4)
+    assert plane.modules["jit_other"] == (1, 2)  # alone, it is its own longest
+    # the interval's own edge still cuts by the share inside, as before
+    half = tr.reduce_loaded(tr.Loaded("made", [("/device:TPU:0", ops, mods)], None, None),
+                            50, 340).planes[0]
+    assert half.executions["jit_verify_range8"] == pytest.approx(2.9)
+    assert half.modules["jit_verify_range8"] == (2, 200)
+
+
 @pytest.fixture(scope="module")
 def recorded(tmp_path_factory):
     src = os.path.join(DATA, "chip_small.xplane.pb.gz")

@@ -9,7 +9,11 @@ device plane (`/device:TPU:<n>`):
             children on the same line cover, summed per name
   modules   executions of whole programs (line "XLA Modules"), per name:
             the whole ones with their time, and all of them counted by the
-            share of each that lies inside the interval
+            share of each that lies inside the interval. An execution that
+            was running when the profiler's session began or ended is in
+            the trace as a SHORTER event, cut to the session: one under
+            WHOLE_SHARE of its program's longest is no whole execution, and
+            counts by what lies inside over the longest one's length
   gaps      the idle intervals between operations
 
 all cut to a sub-interval [t0_ns, t1_ns] of the trace when one is given.
@@ -28,6 +32,7 @@ import re
 from dataclasses import dataclass, field
 from types import SimpleNamespace
 
+WHOLE_SHARE = 0.9  # of the program's longest execution: shorter was cut short
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 ANCHOR = "bench_anchor"
@@ -219,6 +224,14 @@ def load_trace(path: str, rehearsal: bool = False) -> Loaded:
     return Loaded(path, device, anchor, dropped)
 
 
+def _longest(mods) -> dict:
+    """Per program, the length of its longest execution in the trace."""
+    out: dict[str, float] = {}
+    for s, e, mod in mods:
+        out[mod] = max(out.get(mod, 0.0), e - s)
+    return out
+
+
 def reduce_loaded(loaded: Loaded, t0_ns: float | None = None,
                   t1_ns: float | None = None) -> Reduction:
     """Reduce a loaded trace over [t0_ns, t1_ns]; without them, from the
@@ -243,12 +256,15 @@ def reduce_loaded(loaded: Loaded, t0_ns: float | None = None,
         red = PlaneReduction(name)
         red.busy_ns, red.gaps = _union_and_gaps(ops, t0, t1)
         red.self_ns, red.total_ns, red.count = _self_times(ops)
+        whole_ns = _longest(mods)
         for s, e, mod in mods:
             inside = min(e, t1) - max(s, t0)
             if inside <= 0 or e <= s:
                 continue
-            red.executions[mod] = red.executions.get(mod, 0.0) + inside / (e - s)
-            if t0 <= s and e <= t1:  # a whole execution
+            cut_short = e - s < WHOLE_SHARE * whole_ns[mod]
+            red.executions[mod] = red.executions.get(mod, 0.0) + inside / (
+                whole_ns[mod] if cut_short else e - s)
+            if t0 <= s and e <= t1 and not cut_short:  # a whole execution
                 n, ns = red.modules.get(mod, (0, 0.0))
                 red.modules[mod] = (n + 1, ns + (e - s))
         planes.append(red)
