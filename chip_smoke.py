@@ -22,8 +22,10 @@ Only the kernel classes that are driven get compiled (no `warmup()`), the
 three of them side by side.
 
 `--chips 4` runs the fleet plane instead and nothing else: four engines,
-one pinned to each chip (`parallel/plane.py bn254_plane`), behind the same
-service, range class only.
+one pinned to each chip (`parallel/plane.py scheme_plane`: one prefix table
+and one traced, compiled program a class for the plane, copied and loaded
+chip to chip), behind the same service, range class only; `done` says how
+each chip came by its executables (`programs`).
 
 Fails — non-zero exit, `"ok": false` on the last line — when the platform
 is not `tpu`, the Pallas field kernel is off, the native host library did
@@ -262,8 +264,9 @@ async def serve_one_chip(service, pubkeys, groups, host, meter) -> int:
 
 
 async def serve_fleet(service, pubkeys, groups, host, meter) -> int:
-    """All groups in flight at once, twice: the first wave compiles one
-    executable per chip, the second is the steady fleet wall."""
+    """All groups in flight at once, twice: the first wave compiles the
+    class on the chip that asks first and loads it on the others, the
+    second is the steady fleet wall."""
     launches = 0
     for wave in ("cold", "steady"):
         t0 = time.perf_counter()
@@ -332,7 +335,7 @@ def drive(chips: int, meter: CompileMeter) -> dict:
     from handel_tpu.models.bn254_jax import BN254Device
     from handel_tpu.ops.fp import default_pow_window
     from handel_tpu.parallel.batch_verifier import BatchVerifierService
-    from handel_tpu.parallel.plane import bn254_plane
+    from handel_tpu.parallel.plane import scheme_plane
 
     rng = random.Random(SEED)
     sks, pubkeys = build_registry(rng)
@@ -342,7 +345,8 @@ def drive(chips: int, meter: CompileMeter) -> dict:
         engines = [BN254Device(pubkeys, batch_size=LANES)]
         target = engines[0]
     else:
-        target = bn254_plane(pubkeys, devices=chips, batch_size=LANES)
+        target = scheme_plane(pubkeys, devices=chips, batch_size=LANES,
+                              scheme="bn254-jax")
         engines = [lane.engine for lane in target.lanes]
     F = engines[0].curves.F
     say(phase="engines", engines=len(engines), registry=engines[0].n,
@@ -354,11 +358,14 @@ def drive(chips: int, meter: CompileMeter) -> dict:
         raise SmokeFailure(f"engine registries share a device: {placed}")
 
     # the prefix table is built on the first range dispatch; build it here
-    # so its scan is timed apart from the launch classes (one thread per
-    # engine: each chip compiles its own copy, and XLA compiles overlap)
+    # so its scan is timed apart from the launch classes (a plane scans on
+    # its first chip and copies the table to the others)
     t0 = time.perf_counter()
-    with ThreadPoolExecutor(len(engines)) as pool:
-        list(pool.map(lambda e: jax.block_until_ready(e._prefix), engines))
+    for e in engines:
+        jax.block_until_ready(e._prefix)
+    tables = [sorted(d.id for d in e._prefix[2].devices()) for e in engines]
+    if tables != placed:
+        raise SmokeFailure(f"prefix tables on {tables}, registries on {placed}")
     say(phase="prefix_table", registry_devices=placed,
         seconds=time.perf_counter() - t0, **meter.take())
 
@@ -386,7 +393,10 @@ def drive(chips: int, meter: CompileMeter) -> dict:
     lane_launches = [lane.launches for lane in service.plane.lanes]
     if min(lane_launches) < 1:
         raise SmokeFailure(f"a lane never launched: {lane_launches}")
+    v = service.values()
     return {"counters": counters, "lane_launches": lane_launches,
+            "programs": {"compiles": v["programCompiles"],
+                         "loads": v["programLoads"]},
             "use_pallas": F.use_pallas}
 
 

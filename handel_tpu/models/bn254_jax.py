@@ -26,9 +26,11 @@ across calls.
 
 from __future__ import annotations
 
+import io
 import random
+import threading
 import time
-from collections import namedtuple
+from collections import Counter, namedtuple
 from functools import partial
 from typing import Sequence
 
@@ -84,6 +86,118 @@ def _named(fn, name: str):
 
     call.__name__ = call.__qualname__ = name
     return call
+
+
+class PlanePrograms:
+    """The launch programs the pinned engines of ONE plane share
+    (parallel/plane.py `scheme_plane`): its lanes serve one registry at one
+    shape, so whatever does not depend on the chip is done once.
+
+    `jit(key, build)` keeps one `jax.jit` object a launch class: the class is
+    TRACED once for the plane, whichever lane asks first (the bank and the
+    prefix table are arguments, never closures). `run` executes it on a
+    lane's chip through an executable held per (class, shapes, chip): the
+    first chip to ask lowers and compiles it (or loads it from the compile
+    cache), and it is serialised; every other chip LOADS those bytes with
+    its own device assignment. JAX's compile cache cannot do that: its key
+    carries the device assignment off the GPU (jax/_src/cache_key.py), so
+    chip k would miss on what chip 0 wrote. Where the runtime refuses the
+    bytes on another chip, that chip compiles for itself (what every lane
+    did before there was a plane) and `compiles` says so.
+
+    `compiles` / `loads` count executables by how a chip came to hold them.
+    A program is serialised when a SECOND chip asks for it (a plane of one
+    never pays for that), and the bytes stay on the host for a lane
+    attached later. A program's trace closes over the engine that asked
+    first, which so lives as long as the plane's programs do.
+    """
+
+    def __init__(self):
+        self._jitted: dict = {}
+        self._loaded: dict = {}       # (key, shapes, chip) -> jax.stages.Compiled
+        # (key, shapes) -> the first chip's executable, then (once a second
+        # chip asks) its bytes and trees, or None where it cannot travel
+        self._portable: dict = {}
+        self._lock = threading.Lock()
+        self.compiles: Counter = Counter()  # by class key
+        self.loads: Counter = Counter()
+
+    def jit(self, key, build):
+        if key not in self._jitted:
+            self._jitted.setdefault(key, build())
+        return self._jitted[key]
+
+    def run(self, key, device, *args):
+        shapes = tuple(a.shape for a in jax.tree_util.tree_leaves(args))
+        exe = self._loaded.get((key, shapes, device))
+        if exe is None:  # one dispatcher a lane: no two ask for one chip
+            exe = self._loaded[key, shapes, device] = self._executable(
+                key, shapes, device, args)
+        return exe(*args)
+
+    def _executable(self, key, shapes, device, args):
+        from jax.experimental import serialize_executable as se
+
+        # one chip at a time: the others wait for the first one's bytes
+        # (and XLA:CPU loses functions of executables loaded side by side)
+        with self._lock:
+            if (key, shapes) not in self._portable:
+                exe = self._portable[key, shapes] = self._compile(key, args)
+                return exe
+            first = self._portable[key, shapes]
+            try:
+                if isinstance(first, jax.stages.Compiled):
+                    first = self._portable[key, shapes] = se.serialize(first)
+                if first is not None:
+                    exe = _load_on(se, first, device)
+                    # a runtime may load what it cannot run: find out here,
+                    # on copies made through the host (a launch's own inputs
+                    # are donated, and a device copy would compile)
+                    jax.block_until_ready(exe(*jax.device_put(
+                        _tree(np.asarray, args), device)))
+                    self.loads[key] += 1
+                    return exe
+            except Exception as e:  # this runtime binds a program to a chip
+                DEFAULT_LOGGER.warn("plane_program_not_portable", key, e)
+                self._portable[key, shapes] = None
+            return self._compile(key, args)
+
+    def _compile(self, key, args):
+        exe = self._jitted[key].lower(*args).compile()
+        self.compiles[key] += 1
+        return exe
+
+
+def _load_on(se, blob, device):
+    """`serialize_executable.deserialize_and_load` onto ANOTHER chip than the
+    one that compiled: that function looks the pickled devices up by id, so
+    it only loads where it compiled. Here every device of the one-chip
+    program reads as `device`, and the runtime is handed that chip's
+    device assignment with the bytes."""
+    from jax._src import compiler
+
+    serialized, in_tree, out_tree = blob
+
+    class Onto(se._JaxPjrtUnpickler):
+        def persistent_load(self, pid):
+            if pid[0] == "device":
+                return device
+            if pid[0] == "exec":
+                return self.backend.deserialize_executable(
+                    pid[1], executable_devices=self.execution_devices,
+                    compile_options=compiler.get_compile_options(
+                        num_replicas=1, num_partitions=1,
+                        device_assignment=np.array([[device.id]]),
+                    ),
+                )
+            return super().persistent_load(pid)
+
+    unloaded, args_info, no_kwargs = Onto(
+        io.BytesIO(serialized), device.client, [device]).load()
+    return jax.stages.Compiled(
+        unloaded.load(), [], in_tree.unflatten(args_info), out_tree,
+        no_kwargs=no_kwargs,
+    )
 
 
 class _StagingSet:
@@ -175,6 +289,7 @@ class BN254Device:
         rns_resident: bool | None = None,
         batch_check: str = "per_candidate",
         rlc_rng: random.Random | None = None,
+        plane_of: "BN254Device | None" = None,
     ):
         # batch_check selects the launch contract: "per_candidate" = one
         # pairing-check lane pair per candidate (2C Miller loops, C final
@@ -226,7 +341,31 @@ class BN254Device:
         # steady-state launches perform no implicit host→device transfer
         # of registry/prefix data (pinned by tests/test_device_residency.py
         # under jax.transfer_guard)
-        self._reg_x, self._reg_y = self._put_bank(registry_pubkeys, "registry")
+        # ... once a PLANE: `plane_of` is an engine of the same plane, built
+        # from the same keys on another chip. Its bank is copied chip to
+        # chip (the keys are converted on the host once), its prefix table
+        # likewise when a range launch first asks (`_prefix`), and the two
+        # run the same launch programs (`PlanePrograms`). A pinned engine
+        # alone is a plane of one.
+        if plane_of is not None and (
+            type(plane_of) is not type(self) or plane_of.n != self.n
+            or plane_of.batch_size != batch_size or jax_device is None
+        ):
+            raise ValueError(
+                "plane_of wants a pinned engine of the same class, registry "
+                "size and batch size"
+            )
+        self._plane_of = plane_of
+        self.programs = (
+            plane_of.programs if plane_of is not None
+            else PlanePrograms() if jax_device is not None else None
+        )
+        if plane_of is not None:
+            self._reg_x, self._reg_y = _tree(
+                self._dput, (plane_of._reg_x, plane_of._reg_y))
+        else:
+            self._reg_x, self._reg_y = self._put_bank(
+                registry_pubkeys, "registry")
         # multi-chip plane (SURVEY.md §5.7): registry shards over the mesh
         # for the masked key segment-sum, candidate lanes shard for the
         # pairing check. Same host entry points — `_dispatch_one` routes to
@@ -307,12 +446,9 @@ class BN254Device:
         # ones per launch. Gated off the CPU client, where device buffers
         # can ALIAS the host staging arrays — donating an aliased buffer
         # would let XLA scribble over our staging memory.
-        donate = device_platform() != "cpu"
-        self._kernel = jax.jit(
-            _named(self._verify_batch, "verify_dense"),
-            donate_argnums=(2, 3, 4, 7) if donate else (),
-        )
-        self._donate = donate
+        self._donate = device_platform() != "cpu"
+        self._kernel = self._program(
+            "verify_dense", self._verify_batch, donate=(2, 3, 4, 7))
         self._range_kernels: dict[int, callable] = {}
         self._combine_kernels: dict[int, callable] = {}
         # RLC launch-class kernels: the MSM/aggregation stage keyed by
@@ -406,6 +542,19 @@ class BN254Device:
         ms = self.stage_clock.ms
         return ms["stage"] + ms["enqueue"]
 
+    def _program(self, name: str, fn, donate=()):
+        """`fn` as the launch program `name` (jit names the executable
+        `jit_<name>`), with the per-launch inputs `donate` handed over where
+        the platform allows: this engine's own `jax.jit`, or, for an engine
+        pinned to a chip, its plane's one program of that name run on this
+        chip (`PlanePrograms`)."""
+        build = lambda: jax.jit(
+            _named(fn, name), donate_argnums=donate if self._donate else ())
+        if self.programs is None:
+            return build()
+        self.programs.jit(name, build)
+        return partial(self.programs.run, name, self.jax_device)
+
     @property
     def _prefix(self):
         if self._prefix_cache is None:
@@ -414,7 +563,13 @@ class BN254Device:
             # host)
             if not trace_state_clean():
                 raise RuntimeError("prefix table must be built outside jit")
-            self._prefix_cache = self._build_prefix()
+            src = self._plane_of
+            if src is not None and src.epoch == self.epoch == 0:
+                # both still serve the bank they were built with, which is
+                # one bank: the scan runs on ONE chip of the plane
+                self._prefix_cache = _tree(self._dput, src._prefix)
+            else:
+                self._prefix_cache = self._build_prefix()
         return self._prefix_cache
 
     def _build_prefix(self, reg_x=None, reg_y=None):
@@ -541,12 +696,12 @@ class BN254Device:
         checks = self.pairing.pairing_check(p, q, lane_mask, C)
         return checks & ok_lane
 
-    def _unpack_words(self, words32, valid):
+    def _unpack_words(self, words32, valid, n: int):
         """(C, 2W) uint32 bitset words -> (N*C,) block-major candidate mask,
         entirely on device: a gather + shift per registry index replaces the
         host-side (N, C) mask materialization the dense path used to stage
         and transfer (~N*C bytes/launch; the words are N/8 bytes)."""
-        idx = jnp.arange(self.n)
+        idx = jnp.arange(n)
         w = words32[:, idx // 32]  # (C, N) on-device gather
         bits = ((w >> (idx % 32).astype(jnp.uint32)) & jnp.uint32(1)) != 0
         bits = bits & valid[:, None]  # invalid lanes contribute nothing
@@ -572,10 +727,13 @@ class BN254Device:
         masked by the bitset words, tree-summed."""
         C = self.batch_size
         kg = self.kg
-        mask = self._unpack_words(words32, valid)
+        # the registry's size as the bank handed in has it, not `self.n`: a
+        # plane's lanes run one program, traced by whichever asked first
+        n = jax.tree_util.tree_leaves(reg_x)[0].shape[1]
+        mask = self._unpack_words(words32, valid, n)
         tile = lambda a: jnp.repeat(a, C, axis=1)  # (L, N) -> (L, N*C)
         P2 = kg.from_affine(_tree(tile, reg_x), _tree(tile, reg_y))
-        return kg.masked_sum(P2, mask, self.n)
+        return kg.masked_sum(P2, mask, n)
 
     def _gather_prefix(self, prefix, idx):
         """(C,) int32 -> projective key-group batch from the prefix table."""
@@ -655,14 +813,12 @@ class BN254Device:
         _ = self._prefix
         fn = self._range_agg_kernels.get(miss_k)
         if fn is None:
-            jitted = jax.jit(
-                _named(
-                    partial(self._range_aggregate, miss_k=miss_k),
-                    f"range_agg{miss_k}",
-                ),
-                # donate only the per-launch staging inputs; the bank args
-                # (4, 5, 6) are device residents and must survive launches
-                donate_argnums=(0, 1, 2, 3) if self._donate else (),
+            # donate only the per-launch staging inputs; the bank args
+            # (4, 5, 6) are device residents and must survive launches
+            jitted = self._program(
+                f"range_agg{miss_k}",
+                partial(self._range_aggregate, miss_k=miss_k),
+                donate=(0, 1, 2, 3),
             )
 
             def fn(lo, hi, miss_idx, miss_ok, _jitted=jitted):
@@ -708,12 +864,10 @@ class BN254Device:
             # donate every per-launch staging input; h_x/h_y (args 6, 7) are
             # the cached H(m) and the bank args (9, 10, 11) are the
             # device-resident prefix/registry — all must survive launches
-            jitted = jax.jit(
-                _named(
-                    partial(self._verify_batch_range, miss_k=miss_k),
-                    f"verify_range{miss_k}",
-                ),
-                donate_argnums=(0, 1, 2, 3, 4, 5, 8) if self._donate else (),
+            jitted = self._program(
+                f"verify_range{miss_k}",
+                partial(self._verify_batch_range, miss_k=miss_k),
+                donate=(0, 1, 2, 3, 4, 5, 8),
             )
 
             # same bank-injection wrapper as _range_agg_kernel: callers keep
@@ -1691,8 +1845,11 @@ class BN254JaxConstructor(BN254Constructor):
         self._device: BN254Device | None = None
         self._device_for: int | None = None
 
-    def prepare(self, pubkeys: Sequence[BN254PublicKey]) -> BN254Device:
-        self._device = self.Device(
+    def new_device(self, pubkeys, **placement) -> BN254Device:
+        """An engine of this scheme over `pubkeys` with the constructor's
+        options; `placement` is where it runs (`jax_device`, `plane_of`:
+        parallel/plane.py pins one to each chip)."""
+        return self.Device(
             pubkeys,
             batch_size=self.batch_size,
             curves=self.curves,
@@ -1700,7 +1857,11 @@ class BN254JaxConstructor(BN254Constructor):
             rns_resident=self.rns_resident,
             batch_check=self.batch_check,
             rlc_rng=self._rlc_rng,
+            **placement,
         )
+
+    def prepare(self, pubkeys: Sequence[BN254PublicKey]) -> BN254Device:
+        self._device = self.new_device(pubkeys)
         if self.warmup:
             # compile all reachable kernels NOW, at scheme construction, so
             # no verification round stalls on a mid-run XLA compile

@@ -222,6 +222,11 @@ class BatchVerifierService:
         # than the class named for them for want of candidates
         self.class_rider_candidates = 0
         self.class_widened_launches = 0
+        # lane wait (`_acquire_lane`): how long a planned launch waited for
+        # a free lane, over every launch planned; a lane free at once is
+        # counted and not timed
+        self.lane_wait_ms = 0.0
+        self.lane_wait_launches = 0
         # per-tenant counters (service plane labels)
         self.tenant_candidates: dict[str, int] = {}
         self.tenant_dedup_hits: dict[str, int] = {}
@@ -767,12 +772,17 @@ class BatchVerifierService:
         failover (the single-chip breaker-open behavior, fleet-wide; a
         healthy mesh lane does not keep bulk groups alive, they don't fit
         its launch shape)."""
-        while True:
-            lane = self.plane.pick()
-            if lane is not None or not self.plane.throughput_pool():
-                return lane
+        self.lane_wait_launches += 1
+        lane = self.plane.pick()
+        if lane is not None:
+            return lane
+        t0 = trace_now()
+        while lane is None and self.plane.throughput_pool():
             self._free.clear()
             await self._free.wait()
+            lane = self.plane.pick()
+        self.lane_wait_ms += 1e3 * (trace_now() - t0)
+        return lane
 
     async def _collector(self) -> None:
         while True:
@@ -1187,6 +1197,11 @@ class BatchVerifierService:
             # wider than the class named for them
             "classRiderCandidates": float(self.class_rider_candidates),
             "classWidenedLaunches": float(self.class_widened_launches),
+            # how long planned launches waited for a free lane, and how
+            # many were planned (`_acquire_lane`): with every lane occupied
+            # the plane, not the queue, is what a candidate waits for
+            "laneWaitMs": self.lane_wait_ms,
+            "laneWaitLaunches": float(self.lane_wait_launches),
             # resilience plane: worst lane state + fleet-summed counters
             "breakerState": max(
                 BREAKER_CODE[l.breaker.state] for l in self.plane.lanes

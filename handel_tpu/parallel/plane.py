@@ -20,7 +20,7 @@ device 0 only), and `labeled_values()` exposes one row per device for the
 
 This module must import neither jax nor the service driver at module level
 — fake-crypto simulations construct planes of host stubs in processes that
-never touch jax. The jax-backed builder (`bn254_plane`) imports lazily.
+never touch jax. The jax-backed builder (`scheme_plane`) imports lazily.
 """
 
 from __future__ import annotations
@@ -30,7 +30,9 @@ import asyncio
 from handel_tpu.core.trace import LAUNCH_CLASSES, LAUNCH_STAGES
 from handel_tpu.utils.breaker import CircuitBreaker
 
-__all__ = ["DeviceLane", "DevicePlane", "bn254_plane", "host_plane"]
+__all__ = [
+    "DeviceLane", "DevicePlane", "bn254_plane", "host_plane", "scheme_plane",
+]
 
 #: breaker state -> exposition value (shared with BatchVerifierService)
 BREAKER_CODE = {"closed": 0.0, "half-open": 0.5, "open": 1.0}
@@ -363,6 +365,11 @@ class DevicePlane:
                 (getattr(l.engine, "key_group", 0) for l in self.lanes),
                 default=0,
             )),
+            # executables the lanes' shared launch programs hold, by how
+            # a chip came by them (models/bn254_jax.py `PlanePrograms`):
+            # compiled there (or read from the compile cache), or loaded
+            # from another chip's; 0 for host stubs and unpinned engines
+            **self._program_counts(),
             "schedPicks": float(self.sched_picks),
             "schedIdleViolations": float(self.idle_violations),
             "lanesAdded": float(self.lanes_added),
@@ -375,6 +382,16 @@ class DevicePlane:
             )),
             "meshPicks": float(self.mesh_picks),
             "meshLaunches": float(sum(l.launches for l in mesh)),
+        }
+
+    def _program_counts(self) -> dict[str, float]:
+        sets = {
+            id(p): p for l in self.lanes
+            if (p := getattr(l.engine, "programs", None)) is not None
+        }.values()
+        return {
+            "programCompiles": float(sum(p.compiles.total() for p in sets)),
+            "programLoads": float(sum(p.loads.total() for p in sets)),
         }
 
     def labeled_values(self) -> dict[str, dict[str, float]]:
@@ -403,32 +420,49 @@ def host_plane(constructor, devices: int, batch_size: int = 64,
     ])
 
 
-def bn254_plane(registry_pubkeys, devices: int, batch_size: int = 16,
-                curves=None, warmup: bool = False) -> DevicePlane:
-    """A plane of K BN254 engines, one pinned to each visible jax device.
-    Each engine commits the registry to ITS chip once at startup (the
-    single-chip resident-registry pattern, per device). Warmup is off by
-    default: pairing-tail compiles are minutes each — smokes drive the
-    aggregation stage only, exactly like scripts/launch_smoke.py."""
+def scheme_plane(registry_pubkeys, devices: int, batch_size: int = 16, *,
+                 scheme: str, **device_options) -> DevicePlane:
+    """A plane of K engines of the device scheme `scheme` (a name of
+    models/registry.py: `bn254-jax`, `bls12-381-jax`, `bls12-381-minpk-jax`),
+    one pinned to each of the first K visible jax devices, with the
+    scheme's own options (`device_options`: what its constructor takes). A
+    configuration names this function as its `program.plane` and the scheme
+    among its `device_options` (benchmark/README.md).
+
+    The lanes serve one registry at one shape, so set-up does once what is
+    the same on every chip: the keys are converted on the host once and
+    the bank copied chip to chip, the prefix table is computed on the first
+    chip and copied, and each launch class is traced once and, where the
+    runtime lets a compiled program be loaded on another chip, lowered and
+    compiled once (models/bn254_jax.py `plane_of`, `PlanePrograms`).
+    Nothing is warmed here: a pairing class is minutes of compiling, and
+    which classes a deployment reaches is its traffic's to say."""
     import jax
 
-    from handel_tpu.models.bn254_jax import BN254Device
-    from handel_tpu.ops.curve import BN254Curves
+    from handel_tpu.models.registry import is_device_scheme, new_scheme
 
+    if not is_device_scheme(scheme):
+        raise ValueError(f"{scheme!r} is no device scheme: a plane pins "
+                         "device engines to chips")
     devs = jax.devices()
     if devices > len(devs):
         raise ValueError(
             f"requested {devices} devices but only {len(devs)} visible "
             "(set XLA_FLAGS=--xla_force_host_platform_device_count=N)"
         )
-    shared = curves or BN254Curves()
-    engines = []
-    for i in range(max(1, devices)):
-        eng = BN254Device(
-            registry_pubkeys, batch_size=batch_size, curves=shared,
-            jax_device=devs[i],
-        )
-        if warmup:
-            eng.warmup()
-        engines.append(eng)
-    return DevicePlane(engines)
+    cons = new_scheme(
+        scheme, batch_size=batch_size, warmup=False, **device_options
+    ).constructor
+    first = cons.new_device(registry_pubkeys, jax_device=devs[0])
+    return DevicePlane([first] + [
+        cons.new_device(registry_pubkeys, jax_device=dev, plane_of=first)
+        for dev in devs[1:max(1, devices)]
+    ])
+
+
+def bn254_plane(registry_pubkeys, devices: int, batch_size: int = 16,
+                **device_options) -> DevicePlane:
+    """`scheme_plane` for `bn254-jax` (the smokes' plane, and the name the
+    one-chip BN254 configurations carry)."""
+    return scheme_plane(registry_pubkeys, devices, batch_size,
+                        scheme="bn254-jax", **device_options)

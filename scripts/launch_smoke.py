@@ -129,7 +129,7 @@ def main() -> int:
     for k in DEVICE_COUNTS:
         if k <= 1:
             continue  # the single-device loop above IS the k=1 phase
-        plane = bn254_plane(pks, k, batch_size=C, curves=device.curves)
+        plane = bn254_plane(pks, k, batch_size=C)
         t1 = time.perf_counter()
         fleet_checked = 0
         for lane in plane.lanes:

@@ -17,6 +17,7 @@ transfer discipline proven here covers them.
 """
 
 import random
+from functools import partial
 
 import jax
 import numpy as np
@@ -129,7 +130,7 @@ def test_unpack_words_matches_host_mask(device):
     """The dense kernel's on-device word unpack reproduces the host mask
     the old packer materialized, for random bitsets."""
     rng = random.Random(17)
-    unpack = jax.jit(device._unpack_words)
+    unpack = jax.jit(partial(device._unpack_words, n=N))
     for _ in range(5):
         words = np.zeros((C, (N + 63) // 64), np.uint64)
         valid = np.zeros((C,), bool)
